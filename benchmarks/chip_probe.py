@@ -1,0 +1,300 @@
+#!/usr/bin/env python3
+"""Chip probe: what chip_smoke.py cannot see from outside the server.
+
+One process on the chip(s), each check compared with numpy:
+
+- every Pallas kernel compiles under the installed jax — ``densify_pallas``
+  (the default sparse-upload leg on a TPU) at 256 slices for each bucketed
+  group width and the candidate-block form, the count/TopN kernels behind
+  ``PILOSA_TPU_PALLAS=1``, and the ``shard_map`` builders in
+  ``parallel/mesh.py`` that wrap them or plain XLA bodies;
+- a leaf slab uploaded by the executor's own path is one shard per device
+  of the mesh, and ``memory_stats()["bytes_in_use"]`` grows on every device.
+
+Run it through the chip tool from the repo root:
+
+    chiprun --chips 1 -- python3 benchmarks/chip_probe.py
+    chiprun --chips 4 -- python3 benchmarks/chip_probe.py
+
+The full record goes to ``chiprun_out/probe_<n>chip.json``; the last line
+of stdout is ``{"ok": ..., "failed": [...]}``. Exit 1 when a check failed
+or the backend is not a TPU (off the chip Pallas only runs interpreted,
+which proves nothing about the compiler).
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+import tempfile
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from pilosa_tpu.ops import packed  # noqa: E402
+from pilosa_tpu.ops import pallas_kernels as pk  # noqa: E402
+from pilosa_tpu.parallel import mesh as mesh_mod  # noqa: E402
+
+W = packed.WORDS_PER_SLICE
+SLICES = 256
+
+
+def popc(a) -> int:
+    return int(np.bitwise_count(a).sum())
+
+
+def require(ok, what="answer differs from numpy's") -> None:
+    """A check that holds under ``python -O`` too."""
+    if not ok:
+        raise RuntimeError(str(what)[:400])
+
+
+def densify_case(mesh, rng, g_target: int, block_rows: int | None = None):
+    """Sparse rows with up to ``g_target`` set words per 128-word group,
+    bucketed as the upload path does, densified on the device."""
+    n_rows = SLICES if block_rows is None else SLICES * block_rows
+    subs = W // 128
+    pairs = []
+    for _ in range(n_rows):
+        groups = rng.choice(subs, size=int(rng.integers(1, 64)),
+                            replace=False)
+        idx = np.sort(np.concatenate([
+            g * 128 + rng.choice(128, size=int(rng.integers(
+                1, g_target + 1)), replace=False)
+            for g in groups])).astype(np.int32)
+        val = rng.integers(1, 1 << 32, size=len(idx), dtype=np.uint32)
+        pairs.append((idx, val))
+    use_sparse, plan = packed.sparse_gate(pairs, W)
+    lanes, vals = packed.bucket_prepared(pairs, subs, plan=plan)
+    if block_rows is not None:
+        shape = (SLICES, block_rows) + lanes.shape[1:]
+        lanes, vals = lanes.reshape(shape), vals.reshape(shape)
+    want = packed.densify_host(pairs, W)
+    t0 = time.perf_counter()
+    got = mesh_mod.densify_sharded(mesh, lanes, vals)
+    got.block_until_ready()
+    t1 = time.perf_counter()
+    mesh_mod.densify_sharded(mesh, lanes, vals).block_until_ready()
+    t2 = time.perf_counter()
+    require((np.asarray(got).reshape(want.shape) == want).all())
+    return {"G": int(plan[0]), "sparseGate": bool(use_sparse),
+            "shape": list(lanes.shape), "firstS": round(t1 - t0, 3),
+            "secondS": round(t2 - t1, 4)}
+
+
+def pallas_kernels(rng) -> dict:
+    """name -> check, for the count/TopN kernels off the default path."""
+    a = rng.integers(0, 1 << 32, size=(16, 1 << 15), dtype=np.uint32)
+    b = rng.integers(0, 1 << 32, size=(16, 1 << 15), dtype=np.uint32)
+    x = rng.integers(0, 1 << 32, size=(1 << 22,), dtype=np.uint32)
+    y = rng.integers(0, 1 << 32, size=(1 << 22,), dtype=np.uint32)
+    leaves = rng.integers(0, 1 << 32, size=(3, 64, W), dtype=np.uint32)
+    rows = rng.integers(0, 1 << 32, size=(64, 10, W), dtype=np.uint32)
+    expr = ("andnot", ("or", ("leaf", 0), ("leaf", 1)), ("leaf", 2))
+
+    def op_count_rows():
+        got = np.asarray(pk.op_count_rows_pallas(
+            "and", jnp.asarray(a), jnp.asarray(b)))
+        require((got == np.bitwise_count(a & b).sum(axis=1)).all())
+
+    def op_count_long_row():
+        got = int(pk.op_count_rows_pallas(
+            "and", jnp.asarray(x), jnp.asarray(y)))
+        require(got == popc(x & y))
+
+    def expr_count_rows():
+        got = np.asarray(pk.expr_count_rows_pallas(
+            expr, jnp.asarray(leaves)))
+        want = np.bitwise_count(
+            (leaves[0] | leaves[1]) & ~leaves[2]).sum(axis=1)
+        require((got == want).all())
+
+    def topn_block_count():
+        got = np.asarray(pk.topn_block_count_pallas(
+            ("leaf", 0), jnp.asarray(rows), jnp.asarray(leaves[:1])))
+        want = np.bitwise_count(rows & leaves[0][:, None, :]).sum(axis=2)
+        require((got == want).all())
+        got = np.asarray(pk.topn_block_count_pallas(
+            None, jnp.asarray(rows), jnp.asarray(leaves[:0])))
+        require((got == np.bitwise_count(rows).sum(axis=2)).all())
+
+    return {"pallas_op_count_rows": op_count_rows,
+            "pallas_op_count_long_row": op_count_long_row,
+            "pallas_expr_count_rows": expr_count_rows,
+            "pallas_topn_block_count": topn_block_count}
+
+
+def shard_map_builders(mesh, rng) -> dict:
+    """name -> check, for mesh.py's builders: the first seven take their
+    Pallas bodies under PILOSA_TPU_PALLAS=1 (set by the caller around
+    them), the last three are plain XLA."""
+    leaves = rng.integers(0, 1 << 32, size=(3, 64, W), dtype=np.uint32)
+    rows = rng.integers(0, 1 << 32, size=(64, 10, W), dtype=np.uint32)
+    expr = ("andnot", ("or", ("leaf", 0), ("leaf", 1)), ("leaf", 2))
+    la = [mesh_mod.shard_slices(mesh, leaves[i]) for i in range(3)]
+    ra = mesh_mod.shard_slices(mesh, rows)
+    want_expr = popc((leaves[0] | leaves[1]) & ~leaves[2])
+    want_topn = np.bitwise_count(
+        rows & leaves[0][:, None, :]).sum(axis=(0, 2)).tolist()
+    src = ("leaf", 0)
+
+    def same(got, want):
+        require(got == want, (str(got)[:180], str(want)[:180]))
+
+    def topn_counts():
+        vals, _ = mesh_mod.topn_counts(mesh, "and", ra, la[0], 3)
+        same(list(vals), sorted(want_topn, reverse=True)[:3])
+
+    def query_step():
+        n_i, n_u, _, _ = mesh_mod.query_step(mesh, la[0], la[1], ra, 3)
+        same((n_i, n_u), (popc(leaves[0] & leaves[1]),
+                          popc(leaves[0] | leaves[1])))
+
+    return {
+        "pallas:count_expr_sharded": lambda: same(
+            mesh_mod.count_expr_sharded(mesh, expr, la), want_expr),
+        "pallas:count_exprs_sharded": lambda: same(
+            mesh_mod.count_exprs_sharded(mesh, (expr, src), la),
+            [want_expr, popc(leaves[0])]),
+        "pallas:count_expr_stream": lambda: same(
+            mesh_mod.count_expr(mesh, expr, leaves), want_expr),
+        "pallas:topn_exact_sharded": lambda: same(
+            mesh_mod.topn_exact_sharded(mesh, src, ra, la[:1]), want_topn),
+        "pallas:topn_filtered_sharded": lambda: same(
+            mesh_mod.topn_filtered_sharded(mesh, src, ra, la[:1],
+                                           threshold=2), want_topn),
+        "pallas:topn_exact_stream": lambda: same(
+            mesh_mod.topn_exact(mesh, src, rows, leaves[:1]), want_topn),
+        "pallas:topn_filtered_stream": lambda: same(
+            mesh_mod.topn_exact(mesh, src, rows, leaves[:1], threshold=2),
+            want_topn),
+        "xla:count_op": lambda: same(
+            mesh_mod.count_op(mesh, "and", la[0], la[1]),
+            popc(leaves[0] & leaves[1])),
+        "xla:topn_counts": topn_counts,
+        "xla:query_step": query_step,
+    }
+
+
+def layout_and_hbm(n_dev: int) -> dict:
+    """Two 256-slice leaf slabs through the executor's own upload path:
+    where their shards are, and what each device's allocator says."""
+    from pilosa_tpu import SLICE_WIDTH
+    from pilosa_tpu.executor import Executor
+    from pilosa_tpu.models.holder import Holder
+    from pilosa_tpu.parallel import programs, residency
+
+    def in_use():
+        return [(d.memory_stats() or {}).get("bytes_in_use", 0)
+                for d in jax.devices()]
+
+    before = in_use()
+    rng = np.random.default_rng(5)
+    with tempfile.TemporaryDirectory(prefix="chip_probe_") as tmp:
+        holder = Holder(tmp)
+        holder.open()
+        frame = holder.create_index("i").create_frame("f")
+        cols = [np.unique(rng.integers(0, SLICES * SLICE_WIDTH,
+                                       size=3_000_000, dtype=np.uint64))
+                for _ in range(2)]
+        for row, c in enumerate(cols):
+            frame.import_bits(np.full(len(c), row, np.uint64), c)
+        ex = Executor(holder, host="h")
+        ex._cost_model_enabled = False      # the device leg, not the router
+        got = ex.execute("i", 'Count(Intersect(Bitmap(frame="f", rowID=0),'
+                              ' Bitmap(frame="f", rowID=1)))')
+        want = len(np.intersect1d(cols[0], cols[1]))
+        require(got == [want], (got, want))
+        require(ex.device_fallbacks == 0, "device fallback")
+        cache = residency.device_cache()
+        slabs = [{"shape": list(arr.shape),
+                  "shards": [{"device": s.device.id,
+                              "shape": list(s.data.shape)}
+                             for s in arr.addressable_shards]}
+                 for arr in list(cache._lru.values())]
+        after = in_use()
+        snapshot = cache.snapshot()
+        ex.close()
+        holder.close()
+    growth = [a - b for a, b in zip(after, before)]
+    require(len(slabs) == 2, slabs)
+    for slab in slabs:
+        require(sorted(s["device"] for s in slab["shards"]) == sorted(
+            d.id for d in jax.devices()), slab)
+        require({tuple(s["shape"]) for s in slab["shards"]} == {
+            (slab["shape"][0] // n_dev, W)}, slab)
+    require(all(g > 0 for g in growth), growth)
+    return {"answer": got[0],
+            "bucket": programs.slice_bucket(SLICES, n_dev),
+            "slabs": slabs, "hbmBytesInUseBefore": before,
+            "hbmBytesInUseAfter": after, "hbmGrowth": growth,
+            "perDeviceBytes": snapshot["perDeviceBytes"]}
+
+
+def main() -> int:
+    dev = jax.devices()[0]
+    out: dict = {"device": {"platform": dev.platform,
+                            "kind": dev.device_kind,
+                            "count": len(jax.devices())},
+                 "jax": jax.__version__, "checks": {}}
+    if dev.platform != "tpu":
+        sys.stderr.write(f"chip_probe: backend is {dev.platform!r}, not"
+                         " 'tpu': nothing here compiles for a chip\n")
+        return 1
+    mesh = mesh_mod.make_mesh()
+    n_dev = mesh.shape[mesh_mod.AXIS_SLICES]
+    out["mesh"] = dict(mesh.shape)
+    rng = np.random.default_rng(0)
+
+    checks: dict = {}
+    for g in (1, 2, 4, 8, 16, 32):
+        checks[f"densify_leaf_G{g}"] = (
+            lambda g=g: densify_case(mesh, rng, g))
+    checks["densify_block_R10_G8"] = lambda: densify_case(
+        mesh, rng, 8, block_rows=10)
+    checks.update(pallas_kernels(rng))
+    builders = shard_map_builders(mesh, rng)
+    checks.update(builders)
+    checks["layout_and_hbm"] = lambda: layout_and_hbm(n_dev)
+
+    failed = []
+    for name, fn in checks.items():
+        # Every check runs and is recorded, the refused ones by name: a
+        # kernel the compiler refuses is a finding (ROADMAP D3 deletes it
+        # with its builder), and the exit code says that there was one.
+        pallas = name.startswith("pallas:")
+        if pallas:
+            os.environ["PILOSA_TPU_PALLAS"] = "1"
+        t0 = time.perf_counter()
+        try:
+            rec = {"ok": True, "info": fn()}
+        except Exception as e:  # noqa: BLE001 - recorded, and exit 1
+            rec = {"ok": False,
+                   "error": f"{type(e).__name__}: {e}"[:1500]}
+            failed.append(name)
+        finally:
+            if pallas:
+                del os.environ["PILOSA_TPU_PALLAS"]
+        rec["seconds"] = round(time.perf_counter() - t0, 3)
+        out["checks"][name] = rec
+        print(name, json.dumps(rec)[:400], flush=True)
+    out["compileStats"] = mesh_mod.compile_stats()
+
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    path = os.path.join(ROOT, "chiprun_out",
+                        f"probe_{len(jax.devices())}chip.json")
+    with open(path, "w") as f:
+        json.dump(out, f, indent=1)
+    print(json.dumps({"ok": not failed, "failed": failed,
+                      "device": out["device"]}))
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,7 +1,7 @@
 """Longevity soak: a 2-node gossip cluster under continuous mixed load.
 
 Not a pytest (it runs for minutes by design) — a reproducible harness
-whose results land in RESULTS.md. It exercises, at once, the surfaces
+that prints its own PASS/FAIL verdict. It exercises, at once, the surfaces
 that only misbehave over time: WAL growth + snapshotting under a write
 storm (MAX_OP_N forced low -> snapshot storms), anti-entropy sweeps
 against live writes, gossip probes across BOTH a mid-soak clean restart

@@ -7,7 +7,7 @@ bump the generation, reopen mints a fresh uid, and stale entries
 simply stop being referenced. That staleness contract was *local* —
 a slice owned by another node had an invisible generation, so
 ownership-gated paths fell back to the slow fan-out the moment a
-query touched a remote slice (ROADMAP item 3 / VERDICT r5 #4).
+query touched a remote slice.
 
 This module makes generations a *cluster-wide* fact:
 

@@ -4,8 +4,8 @@
  * build through a single CPython-extension call — where the previous
  * architecture either paid ~15-25 us of interpreted numpy per op or a
  * ctypes boundary whose per-call overhead was measured a loss at
- * container sizes (storage/native.py rationale; VERDICT r5 #1 names
- * ctypes the blocker and a real C-API extension the fix).
+ * container sizes (storage/native.py rationale): ctypes is the
+ * blocker and a real C-API extension the fix.
  *
  * This is NOT a parallel data structure: the functions operate on the
  * live pilosa_tpu.storage.roaring.Bitmap object graph (keys list,

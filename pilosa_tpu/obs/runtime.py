@@ -9,8 +9,8 @@ increment site:
   (parallel.residency.device_cache);
 - XLA compile cache: program-cache hits/misses, programs built, and
   wall seconds spent in first-call trace+compile
-  (parallel.mesh.compile_stats — the counters that answer VERDICT
-  weak #2's "is the cache hitting, does anything warm it");
+  (parallel.mesh.compile_stats — the counters that answer "is the
+  cache hitting, does anything warm it");
 - roaring container op counts by container kind
   (storage.roaring.op_counts), plus the live container mix — counts
   and resident bytes by kind (array/bitmap/run) aggregated from each
@@ -42,8 +42,9 @@ _build_info: Optional[dict] = None
 
 
 def build_info() -> dict:
-    """Build identity: package version, python, jax version, and the
-    jax backend platform — the value block behind the
+    """Build identity: package version, python, jax version, the jax
+    backend platform with its device kind and count, and whether the
+    two native host libraries built — the value block behind the
     ``pilosa_build_info`` gauge and the ``build`` block in /status.
     The jax fields read from the ALREADY-IMPORTED module only: a bare
     handler serving /status must not pay (or fail) a jax import, and
@@ -55,21 +56,30 @@ def build_info() -> dict:
     from .. import __version__
     jax_mod = sys.modules.get("jax")
     jax_version = getattr(jax_mod, "__version__", "") if jax_mod else ""
-    backend = ""
+    backend = device_kind = ""
+    device_count = 0
     if jax_mod is not None:
         try:
             backend = jax_mod.default_backend()
+            devices = jax_mod.devices()
+            device_kind = devices[0].device_kind
+            device_count = len(devices)
         except Exception:  # noqa: BLE001 - backend init can fail off-TPU
             backend = "unavailable"
-    info = {"version": __version__,
-            "python": platform.python_version(),
-            "jax": jax_version or "unloaded",
-            "backend": backend or "unloaded"}
+    labels = {"version": __version__,
+              "python": platform.python_version(),
+              "jax": jax_version or "unloaded",
+              "backend": backend or "unloaded"}
+    info = dict(labels)
     # Publish (and cache) only once jax is actually loaded: an early
     # /status on a bare handler must neither freeze "unloaded" for the
     # process nor leave a second, stale build_info series behind.
     if jax_mod is not None:
-        obs_metrics.BUILD_INFO.labels(**info).set(1)
+        from ..storage import native, native_ext
+        info.update(deviceKind=device_kind, deviceCount=device_count,
+                    native=native.available(),
+                    nativeExt=native_ext.available())
+        obs_metrics.BUILD_INFO.labels(**labels).set(1)
         _build_info = info
     return info
 

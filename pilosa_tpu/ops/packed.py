@@ -105,8 +105,7 @@ def unpack_to_bitmap(words: np.ndarray, base_word: int = 0) -> Bitmap:
     become zero-copy u64 views of the fetched array and only sparse
     ones expand to value arrays — the expand-every-position
     ``from_sorted`` path cost ~8 B/bit plus a full re-merge, which was
-    most of the device materialize leg's repack time (VERDICT r4 item
-    5). Requires container alignment (base_word and len multiples of
+    most of the device materialize leg's repack time. Requires container alignment (base_word and len multiples of
     2048), which every device block satisfies; anything else falls
     back to the general path."""
     from ..storage.roaring import (ARRAY_MAX_SIZE, Container,

@@ -72,17 +72,7 @@ def initialize_from_env() -> bool:
     # configured before the first backend touch.
     cpu_devs = os.environ.get("PILOSA_TPU_DIST_CPU_DEVICES")
     if cpu_devs:
-        try:
-            jax.config.update("jax_num_cpu_devices", int(cpu_devs))
-        except AttributeError:
-            # Pre-0.5 jax has no jax_num_cpu_devices option; the
-            # XLA_FLAGS env equivalent works as long as the backend is
-            # untouched, which this env-contract path guarantees.
-            flag = (f"--xla_force_host_platform_device_count="
-                    f"{int(cpu_devs)}")
-            prior = os.environ.get("XLA_FLAGS", "")
-            if "xla_force_host_platform_device_count" not in prior:
-                os.environ["XLA_FLAGS"] = f"{prior} {flag}".strip()
+        jax.config.update("jax_num_cpu_devices", int(cpu_devs))
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coord,

@@ -8,8 +8,7 @@ so device state is an explicit, *budgeted* cache:
 - ``DeviceBlockCache`` — a process-wide LRU over device-resident packed
   blocks with an HBM byte budget (PILOSA_TPU_HBM_BUDGET_MB). Entries
   are the executor's mesh leaf blocks (one [slices, words] slab per
-  PQL leaf row), the mesh TopN candidate blocks, and each fragment's
-  single-device candidate blocks. The hot entries are exactly the rank
+  PQL leaf row) and the mesh TopN candidate blocks. The hot entries are exactly the rank
   cache's top rows — LRU over query use keeps that working set pinned
   while bounded eviction stops 50k-rows × many-fragments from
   exceeding HBM (SURVEY §7 hard part 2).
@@ -125,9 +124,20 @@ class DeviceBlockCache:
 
     def snapshot(self) -> dict:
         with self._mu:
+            # Where the resident bytes really are: summed over every
+            # entry's shards, by device id. Slabs sharded over the slice
+            # axis give each device usedBytes / n; a slab that landed
+            # whole on one device, or replicated on all, shows here.
+            per_device: dict[str, int] = {}
+            for arr in self._lru.values():
+                for shard in arr.addressable_shards:
+                    dev = str(shard.device.id)
+                    per_device[dev] = (per_device.get(dev, 0)
+                                       + shard.data.nbytes)
             return {"entries": len(self._lru),
                     "usedBytes": self.used_bytes,
                     "budgetBytes": self.budget_bytes,
+                    "perDeviceBytes": per_device,
                     "hits": self.hits, "misses": self.misses,
                     "evictions": self.evictions}
 
@@ -231,8 +241,9 @@ def candidate_block(mesh, key: tuple, frags: list,
 
 
 class DeviceRowCache:
-    """Per-fragment residency state: host packed-row LRU + the device
-    block handle into the shared ``DeviceBlockCache``."""
+    """Per-fragment residency state: host packed-row LRU + the
+    (uid, generation) pair that keys this fragment's entries in the
+    shared ``DeviceBlockCache``."""
 
     def __init__(self, max_rows: int = DEFAULT_MAX_ROWS):
         self.max_rows = max_rows
@@ -282,13 +293,3 @@ class DeviceRowCache:
     def invalidate_all(self) -> None:
         self._host_rows.clear()
         self.generation += 1
-
-    # -- row blocks (TopN candidates), budgeted in the shared cache
-
-    def block(self, storage, row_ids: tuple[int, ...]) -> jax.Array:
-        """Stacked u32[n, 32768] device matrix for the given rows, held
-        in the process-wide budgeted cache keyed by this fragment's
-        (uid, generation) + the id tuple."""
-        key = ("fragblock", self.uid, self.generation, row_ids)
-        return device_cache().get_or_build(
-            key, lambda: jax.device_put(packed.pack_rows(storage, row_ids)))

@@ -1,29 +1,10 @@
 """Wire types (protobuf). `from pilosa_tpu.proto import internal_pb2`.
 
-The generated module is regenerated from internal.proto on demand if protoc
-is available and the source is newer; the checked-in generated file is the
-fallback so runtime protoc is not required.
+``internal_pb2.py`` is committed generated code and is the source at run
+time: importing this package never runs ``protoc``. After editing
+``internal.proto`` regenerate it by hand:
+``protoc --python_out=pilosa_tpu/proto -Ipilosa_tpu/proto
+pilosa_tpu/proto/internal.proto``.
 """
 
-import os
-import subprocess
-
-_DIR = os.path.dirname(__file__)
-_SRC = os.path.join(_DIR, "internal.proto")
-_GEN = os.path.join(_DIR, "internal_pb2.py")
-
-
-def _regen_if_stale():
-    try:
-        if (not os.path.exists(_GEN)
-                or os.path.getmtime(_GEN) < os.path.getmtime(_SRC)):
-            subprocess.run(
-                ["protoc", f"--python_out={_DIR}", f"-I{_DIR}", _SRC],
-                check=True, capture_output=True)
-    except Exception:
-        pass  # fall back to whatever generated module exists
-
-
-_regen_if_stale()
-
-from . import internal_pb2  # noqa: E402
+from . import internal_pb2  # noqa: F401

@@ -590,6 +590,9 @@ class Handler:
         if model is not None:
             snap["costModel"] = {"syncS": model.cal.sync_s,
                                  "hostBps": model.cal.host_bps,
+                                 "uploadBps": model.cal.upload_bps,
+                                 "packBps": model.cal.pack_bps,
+                                 "deviceBps": model.cal.device_bps,
                                  "margin": model.margin,
                                  "drift": model.drift_snapshot()}
         return Response.json(snap)
@@ -2068,9 +2071,8 @@ class Handler:
                 req.content_type == rawimport.CONTENT_TYPE
                 and req.accept == rawimport.CONTENT_TYPE):
             raise HTTPError(406, "Not acceptable")
-        # Per-stage instrumentation (VERDICT r5 weak #3: "decode and
-        # apply serialize" was prose — now the decode-vs-apply split is
-        # a recorded histogram plus cost fields on the response).
+        # Per-stage instrumentation: the decode-vs-apply split is a
+        # recorded histogram plus cost fields on the response.
         import time as time_mod
         decode_t0 = time_mod.perf_counter()
         wire_bytes = 0

@@ -5,8 +5,7 @@ The reference serves each connection on a goroutine with net/http
 per-request overhead. The stdlib wsgiref server this replaces spoke
 HTTP/1.0 (a fresh TCP connection AND a fresh thread per request) and
 parsed requests through several Python layers — measured at ~1 K
-requests/s, a 27× mismatch against the storage engine behind it
-(benchmarks/RESULTS.md round 4, VERDICT r4 item 2).
+requests/s, a 27× mismatch against the storage engine behind it.
 
 Design:
 - thread per CONNECTION (goroutine analogue), keep-alive by default,

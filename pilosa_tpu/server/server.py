@@ -320,16 +320,10 @@ class Server:
         from ..parallel import multihost, pod as pod_mod
         multihost.initialize_from_env()
 
-        # Persistent XLA compile cache, defaulted UNDER THE DATA DIR so
-        # a restarted server re-reads its own compiled programs from
-        # disk instead of re-paying the multi-second trace+compile
-        # (VERDICT weak #2: the cache existed but nothing armed it off
-        # TPU, so every fresh process compiled from scratch). Armed
-        # before any device use; PILOSA_TPU_COMPILE_CACHE still
-        # overrides (=0 disables).
+        # Persistent XLA compile cache (mesh.arm_compile_cache owns the
+        # one rule for where it lives), armed before any device use.
         from ..parallel import mesh as mesh_mod
-        mesh_mod.arm_compile_cache(
-            os.path.join(self.holder.path, ".xla-cache"))
+        mesh_mod.arm_compile_cache()
 
         self.holder.open()
         # Placement-epoch durability (cluster.resize): a node that

@@ -944,8 +944,7 @@ class Fragment:
         Returns the sorted changed positions (row*SLICE_WIDTH +
         slice-local col) so callers can map per-op results; its length
         is the newly-set-bit count. The per-op ``set_bit`` stays as the
-        single-op fallback (fragment.go:369-459; batching rationale:
-        VERDICT r4 item 1)."""
+        single-op fallback (fragment.go:369-459)."""
         return self._mutate_batch(row_ids, column_ids, set=True)
 
     def clear_bits(self, row_ids, column_ids) -> np.ndarray:
@@ -1317,7 +1316,7 @@ class Fragment:
         # of already-set bits are no-ops, exactly like op replay), bulk
         # apply, then a commit barrier. The sync snapshot the vintage
         # import contract paid per request (serialize whole fragment +
-        # fsync, ~100 ms/slice — THE wire-import bound, VERDICT r5 #3)
+        # fsync, ~100 ms/slice — THE wire-import bound)
         # moves to the MAX_OP_N async cadence; reopen replays the
         # records through the vectorized op-log lane instead. Imports
         # too large to sensibly hold as op records keep the vintage

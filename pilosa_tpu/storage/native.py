@@ -3,8 +3,10 @@ with pure-numpy fallbacks.
 
 Mirrors the reference's build-tag dispatch between assembly and generic Go
 popcount (roaring/assembly_asm.go / assembly_generic.go): the native library
-is built on first use with g++ and cached next to the source; if the toolchain
-is unavailable every entry point falls back to vectorized numpy.
+is built on first use with g++ and cached under ``utils.cache_dir()``; if the
+build fails every entry point falls back to vectorized numpy, the failure is
+logged as an error and ``available()`` (the ``/status`` ``build.native`` flag)
+says so.
 """
 
 from __future__ import annotations
@@ -26,15 +28,17 @@ _load_failed = False
 
 
 def _so_path() -> str:
-    # Cache keyed by source content hash in a per-machine dir: the binary is
-    # -march=native, so a committed or stale .so from another host could
-    # SIGILL. Never ship the artifact, always rebuild per (machine, source).
+    # Keyed by source content hash AND machine: the binary is
+    # -march=native, so a stale .so from another source revision, or one
+    # built on another CPU and copied here with the checkout, must never
+    # load (SIGILL). Never ship the artifact, always rebuild per
+    # (machine, source).
     with open(_SRC, "rb") as f:
         digest = hashlib.sha256(f.read()).hexdigest()[:16]
-    from ..utils import cache_dir
+    from ..utils import cache_dir, machine_tag
     cache = cache_dir()
     os.makedirs(cache, exist_ok=True)
-    return os.path.join(cache, f"libbitops-{digest}.so")
+    return os.path.join(cache, f"libbitops-{digest}-{machine_tag()}.so")
 
 
 def _load():
@@ -55,9 +59,23 @@ def _load():
             lib = ctypes.CDLL(so)
             _declare(lib)
             _lib = lib
-        except Exception:
+        except Exception as e:  # noqa: BLE001 - numpy paths still answer
             _load_failed = True
+            _log_build_failure("libbitops", e)
         return _lib
+
+
+def _log_build_failure(what: str, exc: Exception) -> None:
+    """A native library that did not build leaves the slow numpy/Python
+    paths serving: say so once, loudly, with the compiler's own words
+    (/status ``build.native``/``build.nativeExt`` carry the outcome)."""
+    import logging
+    detail = getattr(exc, "stderr", b"") or b""
+    logging.getLogger("pilosa_tpu.native").error(
+        "native library %s failed to build or load (%s: %s); serving"
+        " from the pure-Python fallback\n%s", what,
+        type(exc).__name__, exc,
+        detail.decode("utf-8", "replace")[-2000:])
 
 
 def _declare(lib):

@@ -1,16 +1,17 @@
 """Builder/loader for the real CPython extension (native/fastmutate.c).
 
 The per-op mutate hot path needs a compiled crossing with no ctypes
-per-call floor (VERDICT r5 #1): this module compiles
+per-call floor: this module compiles
 ``pilosa_tpu/native/fastmutate.c`` against the running interpreter's
 headers + numpy's C API on first use, caches the .so keyed by source
-hash (same per-machine scheme as storage.native), and loads it as a
+hash and machine (same scheme as storage.native), and loads it as a
 genuine extension module. Everything degrades gracefully:
 
 - ``PILOSA_TPU_NATIVE_EXT=0`` — escape hatch, never build or load;
-- no toolchain / headers / build failure — silently fall back (the
-  pure-Python mutate paths are the permanent fallback, and the
-  extension itself bails per-op on anything unusual);
+- no toolchain / headers / build failure — fall back to the
+  pure-Python mutate paths (the extension itself bails per-op on
+  anything unusual), log the compiler's error once and report
+  ``available() == False`` (``/status`` ``build.nativeExt``);
 - big-endian hosts — disabled (the extension builds little-endian wire
   records and reads ``<u2``/``<u4``/``<u8`` buffers as host ints).
 
@@ -41,16 +42,17 @@ _lock = threading.Lock()
 
 
 def _so_path() -> str:
-    # Keyed by source hash + interpreter tag: the module links against
-    # this exact CPython ABI, and -march=native makes it per-machine
-    # (same rationale as storage.native._so_path).
+    # Keyed by source hash + interpreter tag + machine: the module links
+    # against this exact CPython ABI, and -march=native makes it
+    # per-machine (same rationale as storage.native._so_path).
     with open(_SRC, "rb") as f:
         digest = hashlib.sha256(f.read()).hexdigest()[:16]
     tag = sysconfig.get_config_var("SOABI") or "abi"
-    from ..utils import cache_dir
+    from ..utils import cache_dir, machine_tag
     cache = cache_dir()
     os.makedirs(cache, exist_ok=True)
-    return os.path.join(cache, f"{_MOD_NAME}-{digest}-{tag}.so")
+    return os.path.join(
+        cache, f"{_MOD_NAME}-{digest}-{tag}-{machine_tag()}.so")
 
 
 def _build(so: str) -> None:
@@ -87,8 +89,10 @@ def load():
                 mod = importlib.util.module_from_spec(spec)
                 loader.exec_module(mod)
                 EXT = mod
-        except Exception:
+        except Exception as e:  # noqa: BLE001 - Python mutate paths serve
             EXT = None
+            from .native import _log_build_failure
+            _log_build_failure(_MOD_NAME, e)
         _tried = True
         return EXT
 

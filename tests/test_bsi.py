@@ -518,19 +518,6 @@ class TestDeviceCircuit:
             assert (gotbits == fn(vals, 21)).all(), op
 
 
-def _has_shard_map() -> bool:
-    import jax
-    if hasattr(jax, "shard_map"):
-        return True
-    try:
-        from jax.experimental.shard_map import shard_map  # noqa: F401
-        return True
-    except ImportError:
-        return False
-
-
-@pytest.mark.skipif(not _has_shard_map(),
-                    reason="no shard_map in this jax")
 class TestMeshBSI:
     def test_bsi_range_sharded_matches_host(self):
         from pilosa_tpu.ops import kernels

@@ -159,7 +159,11 @@ class TestOffline:
     def test_inspect(self, server):
         setup_schema(server)
         server.holder.frame("i", "f").set_bit("standard", 0, 5)
-        path = server.holder.fragment("i", "f", "standard", 0).path
+        frag = server.holder.fragment("i", "f", "standard", 0)
+        # inspect reads the FILE: wait for the group-commit flusher to
+        # put the op there (a direct holder write returns before it).
+        frag.wal_barrier()
+        path = frag.path
         rc, out, _ = run(["inspect", path])
         assert rc == 0
         assert "Containers: 1" in out

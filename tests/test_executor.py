@@ -92,6 +92,22 @@ class TestBitmapCalls:
         res = executor.execute("i", "Union(Bitmap(rowID=1), Bitmap(rowID=2))")
         assert list(res[0].bits()) == [3, 5]
 
+    def test_same_fold_over_other_rows_is_not_a_cache_hit(self, holder,
+                                                          executor):
+        """The materialized-result cache keys on the expression SHAPE
+        and the fragments' generations; two Unions of the same shape
+        over different rows of one frame share both, so the rows must
+        be in the key too (chip_smoke.py's reference comparison caught
+        Union(2,3) answering with Union(0,1)'s bits)."""
+        for row in range(4):
+            must_set(holder, "i", "general", row, 10 + row)
+        first = executor.execute(
+            "i", "Union(Bitmap(rowID=0), Bitmap(rowID=1))")
+        other = executor.execute(
+            "i", "Union(Bitmap(rowID=2), Bitmap(rowID=3))")
+        assert list(first[0].bits()) == [10, 11]
+        assert list(other[0].bits()) == [12, 13]
+
     def test_difference(self, holder, executor):
         for col in (1, 2, 3):
             must_set(holder, "i", "general", 1, col)
@@ -662,7 +678,7 @@ class TestDeviceTopNPath:
             assert fast.execute("i", q) == slow.execute("i", q), q
 
     def test_topn_all_option_combinations_match_host(self, holder):
-        """VERDICT r1 item 7: threshold>1, Tanimoto, and attr filters
+        """threshold>1, Tanimoto, and attr filters
         must run the device path with per-slice pruning semantics
         identical to the per-slice host path, at ≥8 slices."""
         self._fill(holder, slices=8)
@@ -703,9 +719,11 @@ class TestDeviceTopNPath:
         through the chunked filtered program, staying exact."""
         self._fill(holder, slices=8)
         from pilosa_tpu.parallel import mesh as mesh_mod
-        # Shrink the device-block budget so the 8-slice candidate block
-        # exceeds it → the executor takes the streaming branch, and the
-        # stream itself row-chunks.
+        # Shrink the resident-block bound so the 8-slice candidate block
+        # exceeds it → the executor takes the streaming branch — and the
+        # per-dispatch budget, so the stream itself row-chunks.
+        monkeypatch.setattr(Executor, "_topn_resident_bytes",
+                            staticmethod(lambda: 1 << 20))
         monkeypatch.setattr(mesh_mod, "TOPN_BLOCK_BYTES", 1 << 20)
         fast = Executor(holder, host="local", use_mesh=True,
                         mesh_min_slices=1)

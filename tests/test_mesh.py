@@ -94,35 +94,62 @@ class TestQueryStep:
 
 
 class TestCompileCache:
-    def test_arm_respects_disable_and_override(self, monkeypatch,
-                                               tmp_path):
-        from pilosa_tpu.parallel import mesh as mesh_mod
+    def test_one_rule_for_the_directory(self, monkeypatch, tmp_path,
+                                        caplog):
+        """JAX_COMPILATION_CACHE_DIR set: jax already has the
+        directory and arm_compile_cache leaves it alone. Unset: the one
+        fixed directory inside the checkout. First call wins."""
+        import os
+
         import jax
+
+        from pilosa_tpu.parallel import mesh as mesh_mod
+        from pilosa_tpu.utils import cache_dir
+        prior_armed = mesh_mod._compile_cache_dir
         prior_dir = jax.config.jax_compilation_cache_dir
         prior_min = jax.config.jax_persistent_cache_min_compile_time_secs
         try:
-            # disabled: config untouched
+            # env set: config untouched, the env's directory reported
             monkeypatch.setattr(mesh_mod, "_compile_cache_armed", False)
-            monkeypatch.setenv("PILOSA_TPU_COMPILE_CACHE", "0")
-            mesh_mod._arm_compile_cache()
-            assert (jax.config.jax_compilation_cache_dir
-                    == prior_dir)
-            # explicit dir: set + created (even off-TPU — explicit
-            # opt-in overrides the platform gate)
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                               str(tmp_path / "from-env"))
+            jax.config.update("jax_compilation_cache_dir", "sentinel")
+            assert mesh_mod.arm_compile_cache() == str(
+                tmp_path / "from-env")
+            assert jax.config.jax_compilation_cache_dir == "sentinel"
+            # env unset: <checkout>/.cache/xla, created
             monkeypatch.setattr(mesh_mod, "_compile_cache_armed", False)
-            target = str(tmp_path / "xla")
-            monkeypatch.setenv("PILOSA_TPU_COMPILE_CACHE", target)
-            mesh_mod._arm_compile_cache()
-            assert jax.config.jax_compilation_cache_dir == target
-            import os
-            assert os.path.isdir(target)
-            # idempotent: second call is a no-op even with env changed
-            monkeypatch.setenv("PILOSA_TPU_COMPILE_CACHE", "0")
-            mesh_mod._arm_compile_cache()
-            assert jax.config.jax_compilation_cache_dir == target
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+            want = cache_dir("xla")
+            repo = os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__)))
+            assert want == os.path.join(repo, ".cache", "xla")
+            assert mesh_mod.arm_compile_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+            assert os.path.isdir(want)
+            # idempotent: a later call is a no-op even with env changed
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+            assert mesh_mod.arm_compile_cache() == want
+            # env unset and the default not creatable (a read-only
+            # install): no cache, said on the log and in the stats —
+            # not an exception out of Server.open
+            monkeypatch.setattr(mesh_mod, "_compile_cache_armed", False)
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+            (tmp_path / "file").write_text("")
+            monkeypatch.setattr(
+                "pilosa_tpu.utils.cache_dir",
+                lambda *parts: str(tmp_path.joinpath("file", *parts)))
+            jax.config.update("jax_compilation_cache_dir", "sentinel")
+            with caplog.at_level("WARNING", logger="pilosa_tpu.mesh"):
+                assert mesh_mod.arm_compile_cache() is None
+            assert "JAX_COMPILATION_CACHE_DIR" in caplog.text
+            assert jax.config.jax_compilation_cache_dir == "sentinel"
+            assert mesh_mod.compile_stats()["persistentCacheDir"] is None
         finally:
+            monkeypatch.undo()
+            mesh_mod._compile_cache_dir = prior_armed
             # jax.config is process-global: restore so later tests are
-            # order-independent (review finding).
+            # order-independent.
             jax.config.update("jax_compilation_cache_dir", prior_dir)
             jax.config.update(
                 "jax_persistent_cache_min_compile_time_secs",

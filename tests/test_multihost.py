@@ -89,7 +89,6 @@ class TestDistributedBootstrap:
         """jax.distributed.initialize + pod count, isolated subprocess."""
         code = """
 import jax
-jax.config.update("jax_platforms", "cpu")
 import numpy as np
 import sys
 sys.path.insert(0, %r)

@@ -51,27 +51,32 @@ class TestDeviceBlockCache:
         snap = c.snapshot()
         assert snap["entries"] == 1 and snap["usedBytes"] == 1024
         assert snap["misses"] == 1
+        # an unsharded array sits whole on one device, and shows so
+        assert list(snap["perDeviceBytes"].values()) == [1024]
 
 
 class TestFragmentResidency:
-    def test_block_cached_and_generation_invalidates(self, tmp_path):
+    def test_write_bumps_generation(self, tmp_path):
+        """Device blocks are keyed by (uid, generation): a write must
+        bump the generation so every cached slab of this fragment stops
+        being referenced (TestExecutorResidency checks the slabs)."""
         from pilosa_tpu.storage.fragment import Fragment
         frag = Fragment(str(tmp_path / "frag"), "i", "f", "standard", 0)
         frag.open()
         try:
-            for r in range(4):
-                for col in range(r + 1):
-                    frag.set_bit(r, col)
-            cache = residency.device_cache()
-            m0 = cache.misses
-            b1 = frag.device.block(frag.storage, (0, 1, 2, 3))
-            b2 = frag.device.block(frag.storage, (0, 1, 2, 3))
-            assert b1 is b2
-            assert cache.misses == m0 + 1
-            frag.set_bit(0, 100)  # bumps generation
-            b3 = frag.device.block(frag.storage, (0, 1, 2, 3))
-            assert b3 is not b1
-            assert np.asarray(b3)[0].sum() != np.asarray(b1)[0].sum()
+            from pilosa_tpu.ops.packed import WORDS_PER_SLICE
+
+            def packed_row():
+                return frag.pack_row(
+                    0, np.zeros(WORDS_PER_SLICE, dtype=np.uint32))
+
+            frag.set_bit(0, 1)
+            g0 = frag.device.generation
+            before = packed_row()
+            frag.set_bit(0, 100)
+            assert frag.device.generation > g0
+            # the host packed-row cache was invalidated with it
+            assert packed_row().sum() != before.sum()
         finally:
             frag.close()
 
@@ -117,6 +122,25 @@ class TestExecutorResidency:
         assert again == first == 8 * 2  # rows 1∩2 share 2 cols/slice
         assert cache.misses == misses_after_first  # no re-upload
         assert ex.device_fallbacks == 0
+
+    def test_slabs_are_spread_over_every_device(self, holder_exec):
+        """Leaf slabs and candidate blocks are sharded over the slice
+        axis: the snapshot shows an equal share on each device of the
+        mesh, adding up to usedBytes (nothing whole on device 0,
+        nothing replicated). chip_smoke.py's verdict reads this."""
+        import jax
+        holder, ex = holder_exec
+        cache = residency.device_cache()
+        cache.clear()
+        ex.execute("i", "Count(Intersect(Bitmap(frame=f, rowID=1),"
+                        " Bitmap(frame=f, rowID=2)))")
+        ex.execute("i", "TopN(Bitmap(frame=f, rowID=1), frame=f,"
+                        " ids=[1, 2])")
+        snap = cache.snapshot()
+        per = snap["perDeviceBytes"]
+        assert len(per) == len(jax.devices()) > 1
+        assert set(per.values()) == {snap["usedBytes"] // len(per)}
+        assert sum(per.values()) == snap["usedBytes"] > 0
 
     def test_repeat_topn_reuses_device_blocks(self, holder_exec):
         holder, ex = holder_exec

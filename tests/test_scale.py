@@ -1,7 +1,7 @@
 """1 B-column scale smoke: 1024 slices (1024 × 2^20 = 2^30 columns)
 through the mesh programs and the executor, asserting the chunk guards
-actually execute and results stay exact (VERDICT r1 item 9 — so the
-first real pod run is not the first time the chunking runs at scale).
+actually execute and results stay exact (so the first real pod run is
+not the first time the chunking runs at scale).
 
 The real constants trigger for TopN at this size: a 1024-slice
 candidate block is 128 MB per row, so TOPN_BLOCK_BYTES (256 MB) forces

@@ -215,10 +215,14 @@ def test_cancel_releases_coordinator_and_reaches_peer(cluster):
         os.kill(procs[1].pid, signal.SIGCONT)
         t.join(timeout=15)
     # After the peer resumes, its leg (which it buffered while
-    # stopped) must drain without leaking a registry entry.
+    # stopped) must drain without leaking a registry entry. The
+    # registry is also empty BEFORE the resumed peer reads that leg
+    # off its socket, so one empty reading proves nothing: wait for
+    # two in a row.
     deadline = time.monotonic() + 20
-    while time.monotonic() < deadline:
-        if not _get_json(host_b, "/debug/queries")["queries"]:
-            break
+    empty = 0
+    while time.monotonic() < deadline and empty < 2:
+        empty = (0 if _get_json(host_b, "/debug/queries")["queries"]
+                 else empty + 1)
         time.sleep(0.3)
-    assert _get_json(host_b, "/debug/queries")["queries"] == []
+    assert empty == 2, _get_json(host_b, "/debug/queries")["queries"]
