@@ -40,7 +40,6 @@ from .errors import (TIME_FORMAT, FrameNotFoundError, IndexNotFoundError,
                      QueryRequiredError, SliceUnavailableError)
 from .obs import accounting as obs_accounting
 from .obs import metrics as obs_metrics
-from .obs import trace as obs_trace
 from .plan import planner as plan_planner
 from .plan import record as plan_record
 from .plan import store as plan_store
@@ -79,16 +78,6 @@ MIN_THRESHOLD = 1
 
 _WRITE_CALLS = ("SetBit", "ClearBit", "SetFieldValue", "SetRowAttrs",
                 "SetColumnAttrs")
-
-
-def _ctx_span(ctx, name: str, **tags):
-    """A span on ``ctx``'s trace, or the shared no-op when the query
-    is untraced (the default): the fan-out layers instrument through
-    this so an untraced query allocates no Span objects."""
-    trace = getattr(ctx, "trace", None) if ctx is not None else None
-    if trace is None:
-        return obs_trace.NOP_SPAN
-    return trace.span(name, **tags)
 
 
 @dataclass
@@ -499,8 +488,9 @@ class Executor:
         # never a query failure — the original tree executes.
         plan_rec = None
         if needs and slices:
-            query, plan_rec = self._maybe_plan(index, query, slices,
-                                               opt)
+            with sched_context.stage("plan"):
+                query, plan_rec = self._maybe_plan(index, query, slices,
+                                                   opt)
 
         # Coordinator hot-query result cache (cluster.generations):
         # repeated read queries over a distributed slice set serve at
@@ -1948,6 +1938,13 @@ class Executor:
         return len(slices), self._TOPN_HOST_BLOCK_BYTES
 
     def _compile_device_expr(self, index: str, c: Call, leaves: list):
+        """``_compile_expr`` under the ``route`` stage: with
+        _cold_leaves, _device_pays and _leaf_device_array, everything
+        that decides where a call runs and finds its operands."""
+        with sched_context.stage("route"):
+            return self._compile_expr(index, c, leaves)
+
+    def _compile_expr(self, index: str, c: Call, leaves: list):
         """Compile a pure bitmap call tree into a mesh.count_expr tree.
 
         Supported: Bitmap leaves (standard or inverse) and Range (an
@@ -1990,7 +1987,7 @@ class Executor:
         op = self._DEVICE_FOLD_OPS.get(c.name)
         if op is None or not c.children:
             return None
-        parts = [self._compile_device_expr(index, ch, leaves)
+        parts = [self._compile_expr(index, ch, leaves)
                  for ch in c.children]
         if any(p is None for p in parts):
             return None
@@ -2196,13 +2193,16 @@ class Executor:
             mesh = self._mesh_or_none()  # backend init only past threshold
             if mesh is None:
                 return NotImplemented
-            cold = self._cold_leaves(mesh, index, leaves, slices)
-            if not self._device_pays(mesh, len(leaves), len(slices),
-                                     cold_rows=cold, note=note):
-                return NotImplemented  # calibrated: host clearly faster
-            shard, budget = self._count_budget(slices)
-            if self._leaf_block_bytes(len(leaves), shard) > budget:
-                return NotImplemented  # oversized leaf set: host path
+            # One ``route`` entry for the whole decision (the callees'
+            # own route stages collapse into it).
+            with sched_context.stage("route"):
+                cold = self._cold_leaves(mesh, index, leaves, slices)
+                if not self._device_pays(mesh, len(leaves), len(slices),
+                                         cold_rows=cold, note=note):
+                    return NotImplemented  # calibrated: host faster
+                shard, budget = self._count_budget(slices)
+                if self._leaf_block_bytes(len(leaves), shard) > budget:
+                    return NotImplemented  # oversized leaf set: host
             from .parallel import mesh as mesh_mod
             try:
                 def run():
@@ -2210,9 +2210,10 @@ class Executor:
                             mesh.shape[mesh_mod.AXIS_SLICES]):
                         # Residency fast path: leaf slabs stay device-
                         # resident across queries (budgeted HBM cache).
-                        arrs = [self._leaf_device_array(
-                            mesh, index, leaf, tuple(slices))
-                            for leaf in leaves]
+                        with sched_context.stage("route"):
+                            arrs = [self._leaf_device_array(
+                                mesh, index, leaf, tuple(slices))
+                                for leaf in leaves]
                         return mesh_mod.count_expr_sharded(mesh, expr,
                                                            arrs)
                     block = self._pack_leaf_block(index, leaves, slices)
@@ -2263,6 +2264,13 @@ class Executor:
         for the whole tree, so pricing the host on the deduplicated
         bytes over-charged the mesh leg exactly when fusion helps
         most."""
+        with sched_context.stage("route"):
+            return self._device_pays_priced(
+                mesh, n_rows, n_slices, cold_rows, note, streaming,
+                host_rows)
+
+    def _device_pays_priced(self, mesh, n_rows, n_slices, cold_rows,
+                            note, streaming, host_rows) -> bool:
         if not self.calibrate(mesh):
             return True
         from .ops.packed import WORDS_PER_SLICE
@@ -2331,9 +2339,10 @@ class Executor:
         from .parallel.residency import device_cache
         cache = device_cache()
         t = tuple(slices)
-        return sum(1 for leaf in leaves
-                   if not cache.contains(
-                       self._leaf_cache_key(mesh, index, leaf, t)))
+        with sched_context.stage("route"):
+            return sum(1 for leaf in leaves
+                       if not cache.contains(
+                           self._leaf_cache_key(mesh, index, leaf, t)))
 
     def _pack_leaf_block(self, index: str, leaves: list[tuple],
                          slices: list[int]) -> np.ndarray:
@@ -2362,10 +2371,11 @@ class Executor:
         upload instead of re-packing + re-transferring per query."""
         from .parallel import residency
         frame, view, row_id = leaf
-        frags = [self.holder.fragment(index, frame, view, s)
-                 for s in slices]
-        key = self._leaf_cache_key(mesh, index, leaf, slices)
-        return residency.leaf_slab(mesh, key, frags, row_id)
+        with sched_context.stage("route"):  # a miss nests pack, upload
+            frags = [self.holder.fragment(index, frame, view, s)
+                     for s in slices]
+            key = self._leaf_cache_key(mesh, index, leaf, slices)
+            return residency.leaf_slab(mesh, key, frags, row_id)
 
     # -- TopN (executor.go:271-396) ------------------------------------------
 
@@ -2672,8 +2682,8 @@ class Executor:
         if row_ids or c.children or (field and filters) or tanimoto > 0:
             return None
         try:
-            with _ctx_span(opt.ctx, "topn_pushdown",
-                           slices=len(slices)):
+            with sched_context.span("topn_pushdown",
+                                    slices=len(slices)):
                 legs = self._topn_pushdown_gather(index, c, slices, opt)
                 merged = self._topn_pushdown_merge(index, c, legs, opt)
         except (QueryDeadlineError, QueryCancelledError):
@@ -2784,9 +2794,10 @@ class Executor:
                             # Tail sampling: a failover leg is keep-
                             # worthy evidence (obs.sampler "breaker").
                             ctx.note_flag("failover")
-                        with _ctx_span(ctx, "failover", peer=node.host,
-                                       slices=len(group),
-                                       error=type(e).__name__):
+                        with sched_context.span(
+                                "failover", peer=node.host,
+                                slices=len(group),
+                                error=type(e).__name__):
                             pass
                         try:
                             submit(nodes, group)
@@ -2823,9 +2834,9 @@ class Executor:
             if opt.ctx is not None:
                 opt.ctx.check()
             if node.host == self.host:
-                with _ctx_span(opt.ctx, "leg",
-                               host=node.host or "local",
-                               slices=len(group)):
+                with sched_context.stage("leg",
+                                         host=node.host or "local",
+                                         slices=len(group)):
                     return self._topn_to_dict(
                         self._topn_exact_partial(index, c_pd, group,
                                                  opt))
@@ -3732,8 +3743,9 @@ class Executor:
             kwargs["gens_out"] = gens_out
         t0 = time.perf_counter()
         try:
-            with _ctx_span(ctx, "rpc", peer=node.host,
-                           slices=len(slices) if slices else 0):
+            with sched_context.span(
+                    "rpc", peer=node.host,
+                    slices=len(slices) if slices else 0):
                 if ctx is not None and getattr(self.client,
                                                "deadline_aware", False):
                     # The peer inherits the REMAINING budget (not the
@@ -4111,8 +4123,8 @@ class Executor:
         # One span covers the whole fan-out INCLUDING the reduce/merge
         # of completed legs (per-leg detail comes from the leg/rpc
         # spans recorded inside _mapper_node).
-        span = _ctx_span(ctx, "map_reduce", call=c.name,
-                         slices=len(slices))
+        span = sched_context.span("map_reduce", call=c.name,
+                                  slices=len(slices))
         span.__enter__()
         try:
             submit(nodes, slices)
@@ -4124,10 +4136,15 @@ class Executor:
                     # Deadline-driven cancellation: wake periodically
                     # so an expiry or DELETE-cancel interrupts the
                     # fan-out even while every leg is still running.
+                    # The legs run on pool threads (their stages are
+                    # this query's ``offThread``); this thread's wait
+                    # for them is a stage of its own, so ``execute``
+                    # stays executor time and not the legs' time.
                     ctx.check()
-                    done, _ = wait(list(futures),
-                                   timeout=self._CTX_POLL_S,
-                                   return_when=FIRST_COMPLETED)
+                    with ctx.stage("legs_wait"):
+                        done, _ = wait(list(futures),
+                                       timeout=self._CTX_POLL_S,
+                                       return_when=FIRST_COMPLETED)
                 for fut in done:
                     node, node_slices = futures.pop(fut)
                     try:
@@ -4166,18 +4183,19 @@ class Executor:
                             # Tail sampling: a failover leg is keep-
                             # worthy evidence (obs.sampler "breaker").
                             ctx.note_flag("failover")
-                        with _ctx_span(ctx, "failover", peer=node.host,
-                                       slices=len(node_slices),
-                                       error=type(e).__name__):
+                        with sched_context.span(
+                                "failover", peer=node.host,
+                                slices=len(node_slices),
+                                error=type(e).__name__):
                             pass
                         try:
                             submit(nodes, node_slices)
                         except SliceUnavailableError:
                             raise e
                         continue
-                    with _ctx_span(ctx, "merge",
-                                   host=(node.host if node is not None
-                                         else "double-read")):
+                    with sched_context.stage(
+                            "merge", host=(node.host if node is not None
+                                           else "double-read")):
                         result = reduce_fn(result, r)
                     processed += len(node_slices)
         finally:
@@ -4209,8 +4227,9 @@ class Executor:
             if opt.ctx is not None:
                 opt.ctx.check()
             if node.host == self.host:
-                with _ctx_span(opt.ctx, "leg", host=node.host or "local",
-                               slices=len(slices)):
+                with sched_context.stage("leg",
+                                         host=node.host or "local",
+                                         slices=len(slices)):
                     if local_fn is not None:
                         r = local_fn(slices)
                         if r is not NotImplemented:
@@ -4261,8 +4280,9 @@ class Executor:
         primary_gens: list = []
 
         def primary_leg():
-            rs = self._exec_remote(node, index, query, slices, opt,
-                                   gens_out=primary_gens)
+            with sched_context.use(opt.ctx):    # the rpc span's query
+                rs = self._exec_remote(node, index, query, slices, opt,
+                                       gens_out=primary_gens)
             return rs[0] if rs else None
 
         primary = pool.submit(primary_leg)
@@ -4281,8 +4301,8 @@ class Executor:
             self._apply_remote_gens(primary_gens)
             return res
         obs_metrics.HEDGED_REQUESTS.labels("fired").inc()
-        with _ctx_span(opt.ctx, "hedge", peer=node.host,
-                       slices=len(slices)):
+        with sched_context.span("hedge", peer=node.host,
+                                slices=len(slices)):
             pass
         hedge_gens: list = []
 
@@ -4292,8 +4312,9 @@ class Executor:
                     if local_fn is not None:
                         return local_fn(sl)
                     return self._mapper_local(sl, map_fn, reduce_fn)
-            rs = self._exec_remote(n2, index, query, sl, opt,
-                                   gens_out=hedge_gens)
+            with sched_context.use(opt.ctx):
+                rs = self._exec_remote(n2, index, query, sl, opt,
+                                       gens_out=hedge_gens)
             return rs[0] if rs else None
 
         hedges = [pool.submit(hedge_leg, n2, sl) for n2, sl in groups]

@@ -62,4 +62,4 @@ and cost wire contracts, and the perfetto/speedscope how-tos.
 
 from .metrics import (RegistryStatsClient, Registry,  # noqa: F401
                       default_registry)
-from .trace import Tracer, get_tracer, span_current  # noqa: F401
+from .trace import Tracer, get_tracer  # noqa: F401

@@ -30,6 +30,7 @@ import threading
 import time
 from typing import Callable, Optional
 
+from ..sched import context as sched_context
 from . import metrics as obs_metrics
 from .diskring import SegmentRing
 
@@ -88,7 +89,8 @@ class Blackbox:
     def _run(self) -> None:
         while not self._stop.wait(self.interval_s):
             try:
-                self.snapshot("periodic")
+                with sched_context.background_tick("blackbox"):
+                    self.snapshot("periodic")
             except Exception:  # noqa: BLE001 - recording must not kill serving
                 pass
 

@@ -28,6 +28,7 @@ import time
 from collections import Counter, deque
 from typing import Optional
 
+from ..sched import context as sched_context
 from ..utils.profiling import _is_idle_leaf
 
 DEFAULT_HZ = 10.0
@@ -98,7 +99,8 @@ class ContinuousProfiler:
     def _run(self) -> None:
         while not self._stop.wait(self.interval):
             try:
-                self.sample_once()
+                with sched_context.background_tick("profile"):
+                    self.sample_once()
             except Exception:  # noqa: BLE001 - sampling must not die
                 pass
 
@@ -108,7 +110,6 @@ class ContinuousProfiler:
         """One sampling tick: collapse every non-idle thread stack,
         tagged with the query id bound to that thread (if any).
         Returns how many stacks were recorded."""
-        from ..sched import context as sched_context
         me = threading.get_ident()
         by_thread = sched_context.by_thread()
         now = time.time()

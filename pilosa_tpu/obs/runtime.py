@@ -34,6 +34,7 @@ import threading
 import time
 from typing import Optional
 
+from ..sched import context as sched_context
 from . import metrics as obs_metrics
 
 DEFAULT_INTERVAL_S = 10.0
@@ -133,7 +134,8 @@ class RuntimeCollector:
     def _run(self) -> None:
         while not self._stop.wait(self.interval_s):
             try:
-                self.collect()
+                with sched_context.background_tick("runtime"):
+                    self.collect()
             except Exception:  # noqa: BLE001 - sampling must not kill serving
                 pass
 
@@ -174,7 +176,10 @@ class RuntimeCollector:
             snap["profiler"] = self.profiler.snapshot()
         if self.history is not None:
             try:
-                self.history.sample()
+                # Counted apart: it is the part of this tick that
+                # grows with the number of series.
+                with sched_context.background_tick("history"):
+                    self.history.sample()
                 snap["history"] = self.history.stats()
             except Exception:  # noqa: BLE001 - history must not break /status
                 pass

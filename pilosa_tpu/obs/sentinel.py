@@ -44,6 +44,7 @@ import threading
 import time
 from typing import Optional
 
+from ..sched import context as sched_context
 from . import metrics as obs_metrics
 from .history import split_key
 
@@ -197,7 +198,8 @@ class Sentinel:
     def _run(self) -> None:
         while not self._stop.wait(self.interval_s):
             try:
-                self.check()
+                with sched_context.background_tick("sentinel"):
+                    self.check()
             except Exception:  # noqa: BLE001 - the sentinel must not die
                 pass
 

@@ -19,10 +19,14 @@ lists the per-node bounded ring of recent traces;
 (open in https://ui.perfetto.dev — each node renders as a process,
 each thread as a track).
 
+One emitter: spans are recorded by the query's stage clock
+(``QueryContext.stage`` / ``.span``, sched.context) and by nothing
+else; this module keeps the span buffer, the ring and the export.
+
 Overhead contract: the *keep-everything* mode is OFF by default, and a
-QueryContext whose ``trace`` is None allocates nothing —
-``span_current()`` returns a shared no-op context manager after two
-attribute reads. Since the always-on PR the serving layer attaches a
+QueryContext whose ``trace`` is None records no span (``ctx.span()``
+returns a shared no-op context manager). Since the always-on PR the
+serving layer attaches a
 span buffer to EVERY query (tail sampling, obs.sampler): the buffer
 itself is the measured-near-free part, and the keep decision at query
 end picks which traces reach the ring and the on-disk segment ring
@@ -35,10 +39,9 @@ from __future__ import annotations
 import json
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Optional
-
-from ..sched import context as sched_context
 
 # Wire headers (see module docstring).
 TRACE_HEADER = "X-Pilosa-Trace"
@@ -75,39 +78,6 @@ class Span:
                     tags=row[5], node=row[3], tid=int(row[4]))
 
 
-class _SpanCM:
-    """Context manager recording one span into a trace on exit."""
-
-    __slots__ = ("_trace", "_name", "_tags", "_t0")
-
-    def __init__(self, trace: "Trace", name: str, tags: Optional[dict]):
-        self._trace = trace
-        self._name = name
-        self._tags = tags
-
-    def __enter__(self):
-        self._t0 = time.time()
-        return self
-
-    def __exit__(self, exc_type, exc, tb):
-        self._trace.add_span(self._name, self._t0,
-                             time.time() - self._t0, self._tags)
-        return False
-
-
-class _NopSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, exc_type, exc, tb):
-        return False
-
-
-NOP_SPAN = _NopSpan()
-
-
 class Trace:
     """All spans this node recorded (or stitched) for one query."""
 
@@ -124,6 +94,12 @@ class Trace:
         self.keep_reason = ""
         self._mu = threading.Lock()
         self._spans: list[Span] = []
+        # The query whose stage clock records this trace's stage spans
+        # (bound by Tracer.start): read from it while it lives, copied
+        # in by ``seal`` when a kept trace is about to outlive it. A
+        # weak reference: ctx.trace points here, and a cycle would
+        # leave every request to the cyclic collector.
+        self._ctx = None
 
     # -- recording -----------------------------------------------------------
 
@@ -136,9 +112,6 @@ class Trace:
                 return False
             self.keep_reason = reason
             return True
-
-    def span(self, name: str, **tags) -> _SpanCM:
-        return _SpanCM(self, name, tags or None)
 
     def add_span(self, name: str, start: float, dur: float,
                  tags: Optional[dict] = None, node: str = "",
@@ -169,9 +142,29 @@ class Trace:
 
     # -- export --------------------------------------------------------------
 
+    def _stage_spans(self) -> list[Span]:
+        ctx = self._ctx() if self._ctx is not None else None
+        if ctx is None:
+            return []
+        return [Span(name, start, dur, tags, self.node, tid)
+                for name, start, dur, tags, tid in ctx.stage_spans()]
+
     def spans(self) -> list[Span]:
         with self._mu:
-            return list(self._spans)
+            out = list(self._spans)
+        room = max(0, self.max_spans - len(out))
+        out.extend(self._stage_spans()[:room])
+        return out
+
+    def seal(self) -> None:
+        """Copy the query's stage spans in and let go of the query:
+        called when the query ends, for a trace that was kept."""
+        staged = self._stage_spans()
+        with self._mu:
+            self._ctx = None
+            room = max(0, self.max_spans - len(self._spans))
+            self.dropped += max(0, len(staged) - room)
+            self._spans.extend(staged[:room])
 
     # Serialized-spans budget for the piggyback header: http.client
     # rejects header LINES over 65536 bytes (LineTooLong kills the
@@ -256,6 +249,7 @@ class Tracer:
         trace = Trace(ctx.id, node=node or getattr(ctx, "node", ""),
                       pql=getattr(ctx, "pql", ""),
                       max_spans=self.max_spans)
+        trace._ctx = weakref.ref(ctx)
         ctx.trace = trace
         return trace
 
@@ -291,17 +285,3 @@ _tracer = Tracer(enabled=False)
 
 def get_tracer() -> Tracer:
     return _tracer
-
-
-def span_current(name: str, **tags):
-    """A span on the current query's trace, or the shared no-op when
-    the thread has no traced query — the single hook device dispatch
-    and compile layers call without taking a ctx argument. The
-    disabled fast path is two attribute reads and no allocation."""
-    ctx = sched_context.current()
-    if ctx is None:
-        return NOP_SPAN
-    trace = getattr(ctx, "trace", None)
-    if trace is None:
-        return NOP_SPAN
-    return trace.span(name, **tags)

@@ -51,6 +51,7 @@ import threading
 import time
 from typing import Callable, Optional
 
+from ..sched import context as sched_context
 from . import metrics as obs_metrics
 
 DEFAULT_INTERVAL_S = 1.0
@@ -138,7 +139,8 @@ class Watchdog:
     def _run(self) -> None:
         while not self._stop.wait(self.interval_s):
             try:
-                self.check()
+                with sched_context.background_tick("watchdog"):
+                    self.check()
             except Exception:  # noqa: BLE001 - the watchdog must not die
                 pass
 

@@ -36,6 +36,7 @@ import logging
 import os
 import threading
 import time
+from collections import deque
 
 import jax
 import jax.numpy as jnp
@@ -45,7 +46,6 @@ from jax.sharding import PartitionSpec as P
 
 from ..fault import failpoints as _failpoints
 from ..obs import accounting as _accounting
-from ..obs import trace as obs_trace
 from ..ops.kernels import _BITWISE
 from ..sched import context as sched_context
 
@@ -247,8 +247,26 @@ def _on_jax_cache_event(event: str, **kwargs) -> None:
             _COMPILE_STATS["persistentMisses"] += 1
 
 
-def _finalize_program(fn):
-    """Builder epilogue: compile accounting.
+# The last compilations, behind /debug/vars ``compileLog``: which
+# program (by its stable name), over which argument shapes, how long,
+# and when — so a compile counted inside a serving window can be named.
+_COMPILE_LOG: deque = deque(maxlen=16)
+
+
+def compile_log() -> list[dict]:
+    with _COMPILE_MU:
+        return list(_COMPILE_LOG)
+
+
+def _finalize_program(fn, name: str, **jit_kw):
+    """Builder epilogue: ``jax.jit`` under a stable name, plus compile
+    accounting.
+
+    ``fn`` is the program's Python function (or its ``shard_map``).
+    The name becomes the function's ``__name__`` BEFORE the jit, so the
+    XLA module is ``jit_<name>`` (``count_exprs_k3``, ``topn_exact``,
+    ``bsi_range``, …) in the profiler's ``XLA Modules`` line and in
+    ``compileLog`` instead of ``jit_fn`` for every program.
 
     Accounting is per XLA COMPILATION, not per builder run: a jitted
     program re-traces for every distinct input shape, so before the
@@ -258,8 +276,9 @@ def _finalize_program(fn):
     (``_cache_size``) and charges its wall time to ``firstCalls`` /
     ``compileSeconds`` — making "compile count stays bucket-bound as
     slice count grows" an assertable number. The predicted first call
-    additionally records an ``xla_compile`` span on any traced query
-    that triggers it."""
+    runs under the ``compile`` stage of the query that triggers it."""
+    fn.__name__ = fn.__qualname__ = name
+    fn = jax.jit(fn, **jit_kw)
     with _COMPILE_MU:
         _COMPILE_STATS["programsBuilt"] += 1
     state = {"first": True}
@@ -271,7 +290,7 @@ def _finalize_program(fn):
         t0 = time.perf_counter()
         if first:
             state["first"] = False  # benign race: double-count at worst
-            with obs_trace.span_current("xla_compile"):
+            with sched_context.stage("compile"):
                 out = fn(*args, **kwargs)
         else:
             out = fn(*args, **kwargs)
@@ -280,6 +299,11 @@ def _finalize_program(fn):
             with _COMPILE_MU:
                 _COMPILE_STATS["firstCalls"] += 1
                 _COMPILE_STATS["compileSeconds"] += dt
+                _COMPILE_LOG.append({
+                    "program": name,
+                    "shapes": [list(getattr(a, "shape", ()))
+                               for a in args],
+                    "seconds": round(dt, 4), "at": time.time()})
             # Attribute the trace+compile to the query that paid it
             # (obs.accounting: compileMs in its cost ledger).
             _accounting.note_compile(dt)
@@ -288,10 +312,28 @@ def _finalize_program(fn):
     return program
 
 
+def _run(fn, *args) -> np.ndarray:
+    """One device program, in two statements so two stages can tell
+    them apart: ``dispatch`` is the call into the jitted program, which
+    returns before the device finishes; ``fetch`` is the ``np.asarray``
+    of its result, which blocks until the device is done."""
+    with sched_context.stage("dispatch"):
+        out = fn(*args)
+    with sched_context.stage("fetch"):
+        return np.asarray(out)
+
+
+def _run_hilo(fn, *args) -> list[int]:
+    """``_run`` of a stacked (hi, lo) program, decoded under ``merge``."""
+    arr = _run(fn, *args)
+    with sched_context.stage("merge"):
+        return hilo_combine(arr)
+
+
 def _note_dispatch(*operands) -> None:
     """Charge one device-program dispatch (+ its operand bytes) to the
     current query's cost ledger (obs.accounting) — the per-query form
-    of the mesh_dispatch trace span. None-cost fast path: one
+    of the dispatch stage. None-cost fast path: one
     thread-local read."""
     cost = _accounting.current_cost()
     if cost is not None:
@@ -457,10 +499,10 @@ def _densify_sharded_fn(mesh: Mesh, lead_shape: tuple, subs: int,
         out = pk.densify_pallas(flat_l, flat_v, n_words, interpret)
         return out.reshape(lanes.shape[:-2] + (n_words,))
 
-    return _finalize_program(jax.jit(jax.shard_map(
+    return _finalize_program(jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(AXIS_SLICES), P(AXIS_SLICES)),
-        out_specs=P(AXIS_SLICES), check_vma=False)))
+        out_specs=P(AXIS_SLICES), check_vma=False), "densify")
 
 
 @_fair_dispatch
@@ -505,10 +547,10 @@ def _count_fn(mesh: Mesh, op: str):
         lo = jax.lax.psum(jnp.sum(row & 0xFFFF), AXIS_SLICES)
         return jnp.stack([hi, lo])  # one output = one host fetch
 
-    return _finalize_program(jax.jit(jax.shard_map(
+    return _finalize_program(jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(AXIS_SLICES), P(AXIS_SLICES)),
-        out_specs=P())))
+        out_specs=P()), f"count_{op}")
 
 
 def count_op(mesh: Mesh, op: str, a: jax.Array, b: jax.Array) -> int:
@@ -534,10 +576,10 @@ def _count_expr_fn_cached(mesh: Mesh, expr: tuple, mode: str | None):
 
     # check_vma off when Pallas is in the shard body: pallas_call's
     # out_shape carries no varying-axis info, which trips the inference.
-    return _finalize_program(jax.jit(jax.shard_map(
+    return _finalize_program(jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(None, AXIS_SLICES),), out_specs=P(),
-        check_vma=(mode is None))))
+        check_vma=(mode is None)), "count_expr_block_pallas")
 
 
 def count_expr_fn(mesh: Mesh, expr: tuple):
@@ -592,10 +634,10 @@ def _count_exprs_fn_cached(mesh: Mesh, exprs: tuple, mode: str | None):
         return jnp.stack([jax.lax.psum(his, AXIS_SLICES),
                           jax.lax.psum(los, AXIS_SLICES)])
 
-    return _finalize_program(jax.jit(jax.shard_map(
+    return _finalize_program(jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(None, AXIS_SLICES),), out_specs=P(),
-        check_vma=(mode is None))))
+        check_vma=(mode is None)), f"count_exprs_block_pallas_n{len(exprs)}")
 
 
 def count_exprs_fn(mesh: Mesh, exprs: tuple):
@@ -632,15 +674,14 @@ def count_expr(mesh: Mesh, expr: tuple, leaves: np.ndarray) -> int:
     fn = count_expr_fn(mesh, expr)
     total = 0
     step = slice_chunk_bound(n_dev)
-    with obs_trace.span_current("mesh_dispatch", kind="count_expr",
-                                slices=int(leaves.shape[1])):
-        for off in range(0, leaves.shape[1], step):
-            chunk = programs_mod.bucket_pad(
-                leaves[:, off:off + step], 1, n_dev)
-            # Per chunk: each loop pass dispatches one program.
-            _note_dispatch(chunk)
-            total += hilo_combine(
-                fn(shard_slices_axis1(mesh, chunk)))[0]
+    for off in range(0, leaves.shape[1], step):
+        chunk = programs_mod.bucket_pad(
+            leaves[:, off:off + step], 1, n_dev)
+        # Per chunk: each loop pass dispatches one program.
+        _note_dispatch(chunk)
+        with sched_context.stage("upload"):
+            block = shard_slices_axis1(mesh, chunk)
+        total += _run_hilo(fn, block)[0]
     return total
 
 
@@ -690,10 +731,11 @@ def _count_exprs_sharded_fn(mesh: Mesh, exprs: tuple, n_leaves: int,
         return jnp.stack([jax.lax.psum(his, AXIS_SLICES),
                           jax.lax.psum(los, AXIS_SLICES)])
 
-    return _finalize_program(jax.jit(jax.shard_map(
+    return _finalize_program(jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(AXIS_SLICES),) * n_leaves, out_specs=P(),
-        check_vma=(mode is None))))
+        check_vma=(mode is None)),
+        f"count_exprs_pallas_n{len(exprs)}_k{n_leaves}")
 
 
 @_fair_dispatch
@@ -720,10 +762,7 @@ def count_exprs_sharded(mesh: Mesh, exprs: tuple,
         fn = _count_exprs_sharded_fn(mesh, exprs, len(leaf_arrays),
                                      mode)
     _note_dispatch(*leaf_arrays)
-    with obs_trace.span_current("mesh_dispatch", kind="count_exprs",
-                                exprs=len(exprs),
-                                leaves=len(leaf_arrays)):
-        return hilo_combine(fn(*leaf_arrays))
+    return _run_hilo(fn, *leaf_arrays)
 
 
 def count_expr_sharded(mesh: Mesh, expr: tuple,
@@ -767,11 +806,7 @@ def fused_tree_sharded(mesh: Mesh, count_exprs: tuple,
               for (expr, _), rows in zip(topn_items, rows_arrays)),
         len(leaf_arrays))
     _note_dispatch(*leaf_arrays, *rows_arrays)
-    with obs_trace.span_current("mesh_dispatch", kind="fused_tree",
-                                exprs=len(count_exprs),
-                                topns=len(topn_items),
-                                leaves=len(leaf_arrays)):
-        flat = hilo_combine(fn(*leaf_arrays, *rows_arrays))
+    flat = _run_hilo(fn, *leaf_arrays, *rows_arrays)
     counts = flat[:len(count_exprs)]
     out_topn: list[list[int]] = []
     off = len(count_exprs)
@@ -793,10 +828,11 @@ def _topn_exact_sharded_fn(mesh: Mesh, expr, n_leaves: int,
         return _psum_hi_lo_rows(
             _shard_topn_inter(expr, rows, leaves, mode))
 
-    return _finalize_program(jax.jit(jax.shard_map(
+    return _finalize_program(jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(AXIS_SLICES),) * (n_leaves + 1),
-        out_specs=P(), check_vma=(mode is None))))
+        out_specs=P(), check_vma=(mode is None)),
+        f"topn_exact_pallas_k{n_leaves}")
 
 
 def _shard_topn_inter(expr, rows, leaves, mode):
@@ -870,10 +906,11 @@ def _topn_filtered_sharded_fn(mesh: Mesh, expr, n_leaves: int,
             expr, rows, jnp.stack(leaf_shards), threshold, tanimoto,
             mode))
 
-    return _finalize_program(jax.jit(jax.shard_map(
+    return _finalize_program(jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(), P()) + (P(AXIS_SLICES),) * (n_leaves + 1),
-        out_specs=P(), check_vma=(mode is None))))
+        out_specs=P(), check_vma=(mode is None)),
+        f"topn_filtered_pallas_k{n_leaves}")
 
 
 @_fair_dispatch
@@ -898,11 +935,8 @@ def topn_filtered_sharded(mesh: Mesh, expr, rows: jax.Array,
                                        mode)
     threshold = min(threshold, 2**31 - 1)  # counts never exceed 2^31
     _note_dispatch(rows, *leaf_arrays)
-    with obs_trace.span_current("mesh_dispatch", kind="topn_filtered",
-                                rows=int(rows.shape[1])):
-        return hilo_combine(
-            fn(jnp.int32(threshold), jnp.int32(tanimoto), rows,
-               *leaf_arrays))[:rows.shape[1]]
+    return _run_hilo(fn, jnp.int32(threshold), jnp.int32(tanimoto),
+                     rows, *leaf_arrays)[:rows.shape[1]]
 
 
 @_fair_dispatch
@@ -925,9 +959,7 @@ def topn_exact_sharded(mesh: Mesh, expr, rows: jax.Array,
     else:
         fn = _topn_exact_sharded_fn(mesh, expr, len(leaf_arrays), mode)
     _note_dispatch(rows, *leaf_arrays)
-    with obs_trace.span_current("mesh_dispatch", kind="topn_exact",
-                                rows=int(rows.shape[1])):
-        return hilo_combine(fn(rows, *leaf_arrays))[:rows.shape[1]]
+    return _run_hilo(fn, rows, *leaf_arrays)[:rows.shape[1]]
 
 
 @_fair_dispatch
@@ -955,9 +987,7 @@ def topn_topk_sharded(mesh: Mesh, expr, rows: jax.Array,
     from . import programs as programs_mod
     fn = programs_mod.topn_topk_program(mesh, expr, len(leaf_arrays), k)
     _note_dispatch(rows, *leaf_arrays)
-    with obs_trace.span_current("mesh_dispatch", kind="topn_topk",
-                                rows=int(rows.shape[1]), k=k):
-        out = np.asarray(fn(rows, *leaf_arrays)).astype(np.int64)
+    out = _run(fn, rows, *leaf_arrays).astype(np.int64)
     counts = ((out[0] << 16) + out[1]).tolist()
     return counts, out[2].tolist()
 
@@ -1024,10 +1054,10 @@ def _topn_exact_fn_cached(mesh: Mesh, expr, mode: str | None):
         return _psum_hi_lo_rows(
             _shard_topn_inter(expr, rows, leaves, mode))
 
-    return _finalize_program(jax.jit(jax.shard_map(
+    return _finalize_program(jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(AXIS_SLICES), P(None, AXIS_SLICES)),
-        out_specs=P(), check_vma=(mode is None))))
+        out_specs=P(), check_vma=(mode is None)), "topn_exact_block_pallas")
 
 
 @functools.lru_cache(maxsize=256)
@@ -1036,10 +1066,10 @@ def _topn_filtered_fn_cached(mesh: Mesh, expr, mode: str | None):
         return _psum_hi_lo_rows(_filtered_counts(
             expr, rows, leaves, threshold, tanimoto, mode))
 
-    return _finalize_program(jax.jit(jax.shard_map(
+    return _finalize_program(jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(), P(), P(AXIS_SLICES), P(None, AXIS_SLICES)),
-        out_specs=P(), check_vma=(mode is None))))
+        out_specs=P(), check_vma=(mode is None)), "topn_filtered_block_pallas")
 
 
 def topn_filtered_fn(mesh: Mesh, expr):
@@ -1093,9 +1123,7 @@ def materialize_expr_sharded(mesh: Mesh, expr,
     from . import programs as programs_mod
     fn = programs_mod.materialize_program(mesh, expr, len(leaf_arrays))
     _note_dispatch(*leaf_arrays)
-    with obs_trace.span_current("mesh_dispatch", kind="materialize",
-                                leaves=len(leaf_arrays)):
-        return np.asarray(fn(*leaf_arrays))
+    return _run(fn, *leaf_arrays)
 
 
 @_fair_dispatch
@@ -1123,9 +1151,7 @@ def bsi_range_sharded(mesh: Mesh, op: str, upred, depth: int,
     from . import programs as programs_mod
     fn = programs_mod.bsi_range_program(mesh, op, len(plane_arrays))
     _note_dispatch(*plane_arrays)
-    with obs_trace.span_current("mesh_dispatch", kind="bsi_range",
-                                depth=depth):
-        return np.asarray(fn(pbits, pbits2, *plane_arrays))
+    return _run(fn, pbits, pbits2, *plane_arrays)
 
 
 # Device-block budget for one topn_exact call (mirrors the 256 MB
@@ -1176,8 +1202,10 @@ def topn_exact(mesh: Mesh, expr, rows: np.ndarray,
             rc = programs_mod.bucket_pad(rc, 0, n_dev)
             lcc = programs_mod.bucket_pad(lcc, 1, n_dev)
             _note_dispatch(rc, lcc)  # per chunk: one program each
-            counts = hilo_combine(fn(shard_slices(mesh, rc),
-                                     shard_slices_axis1(mesh, lcc)))
+            with sched_context.stage("upload"):
+                rc_dev = shard_slices(mesh, rc)
+                lcc_dev = shard_slices_axis1(mesh, lcc)
+            counts = _run_hilo(fn, rc_dev, lcc_dev)
             for r in range(rc.shape[1]):
                 totals[r_off + r] += counts[r]
     return totals
@@ -1206,10 +1234,10 @@ def _topn_fn(mesh: Mesh, op: str, k: int):
 
     # check_vma off: the all_gather over ``rows`` makes counts replicated,
     # but the varying-axis inference can't prove it.
-    return _finalize_program(jax.jit(jax.shard_map(
+    return _finalize_program(jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(AXIS_SLICES, AXIS_ROWS), P(AXIS_SLICES)),
-        out_specs=(P(), P()), check_vma=False)))
+        out_specs=(P(), P()), check_vma=False), f"topn_{op}_top{k}")
 
 
 def topn_counts(mesh: Mesh, op: str, rows: jax.Array, src: jax.Array,
@@ -1247,11 +1275,11 @@ def _query_step_fn(mesh: Mesh, k: int):
         top_vals, top_ids = jax.lax.top_k(counts, k)
         return n_inter, n_union, top_vals, top_ids
 
-    return _finalize_program(jax.jit(jax.shard_map(
+    return _finalize_program(jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(AXIS_SLICES), P(AXIS_SLICES),
                   P(AXIS_SLICES, AXIS_ROWS)),
-        out_specs=(P(), P(), P(), P()), check_vma=False)))
+        out_specs=(P(), P(), P(), P()), check_vma=False), f"query_step_top{k}")
 
 
 def query_step(mesh: Mesh, a: jax.Array, b: jax.Array, rows: jax.Array,

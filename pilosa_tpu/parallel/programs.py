@@ -145,7 +145,8 @@ def count_exprs_program(mesh, exprs: tuple, n_leaves: int):
         his, los = mesh_mod._exprs_hi_lo(exprs, leaves, None)
         return jnp.stack([his, los])
 
-    return mesh_mod._finalize_program(jax.jit(fn))
+    return mesh_mod._finalize_program(
+        fn, f"count_exprs_n{len(exprs)}_k{n_leaves}")
 
 
 @functools.lru_cache(maxsize=256)
@@ -162,7 +163,7 @@ def count_exprs_block_program(mesh, exprs: tuple):
         return jnp.stack([his, los])
 
     return mesh_mod._finalize_program(
-        jax.jit(fn, **_donate_kw(mesh, 1)))
+        fn, f"count_exprs_block_n{len(exprs)}", **_donate_kw(mesh, 1))
 
 
 @functools.lru_cache(maxsize=256)
@@ -192,7 +193,8 @@ def topn_program(mesh, expr, n_leaves: int, filtered: bool):
             return _hi_lo_rows(mesh_mod._shard_topn_inter(
                 expr, rows, stack_leaves(rows, leaf_shards), None))
 
-    return mesh_mod._finalize_program(jax.jit(fn))
+    return mesh_mod._finalize_program(
+        fn, f"topn_{'filtered' if filtered else 'exact'}_k{n_leaves}")
 
 
 @functools.lru_cache(maxsize=256)
@@ -218,7 +220,9 @@ def topn_block_program(mesh, expr, filtered: bool):
                 expr, rows, leaves, None))
         donate = _donate_kw(mesh, 2)
 
-    return mesh_mod._finalize_program(jax.jit(fn, **donate))
+    return mesh_mod._finalize_program(
+        fn, f"topn_{'filtered' if filtered else 'exact'}_block",
+        **donate)
 
 
 @functools.lru_cache(maxsize=128)
@@ -258,7 +262,7 @@ def topn_topk_program(mesh, expr, n_leaves: int, k: int):
         return jnp.stack([shi[::-1][:k], slo[::-1][:k],
                           -sneg[::-1][:k]])
 
-    return mesh_mod._finalize_program(jax.jit(fn))
+    return mesh_mod._finalize_program(fn, f"topn_topk_k{n_leaves}_top{k}")
 
 
 @functools.lru_cache(maxsize=256)
@@ -275,7 +279,7 @@ def materialize_program(mesh, expr, n_leaves: int):
         return jax.lax.with_sharding_constraint(
             mesh_mod._eval_expr(expr, leaves), sh)
 
-    return mesh_mod._finalize_program(jax.jit(fn))
+    return mesh_mod._finalize_program(fn, f"materialize_k{n_leaves}")
 
 
 @functools.lru_cache(maxsize=256)
@@ -300,7 +304,7 @@ def bsi_range_program(mesh, op: str, n_planes: int):
             out = kernels.bsi_compare_select(op, pbits, planes)
         return jax.lax.with_sharding_constraint(out, sh)
 
-    return mesh_mod._finalize_program(jax.jit(fn))
+    return mesh_mod._finalize_program(fn, f"bsi_range_p{n_planes}")
 
 
 @functools.lru_cache(maxsize=128)
@@ -341,7 +345,8 @@ def fused_program(mesh, count_exprs: tuple, topn_exprs: tuple,
         return jnp.stack([jnp.concatenate(parts_hi),
                           jnp.concatenate(parts_lo)])
 
-    return mesh_mod._finalize_program(jax.jit(fn))
+    return mesh_mod._finalize_program(
+        fn, f"fused_n{len(count_exprs)}_t{len(topn_exprs)}_k{n_leaves}")
 
 
 # Builder caches, appended to mesh._PROGRAM_CACHES so compile_stats()

@@ -47,6 +47,7 @@ import jax
 import numpy as np
 
 from ..ops import packed
+from ..sched import context as sched_context
 
 # Default packed-row budget per fragment (256 rows × 128 KB = 32 MB
 # host-side).
@@ -178,23 +179,29 @@ def leaf_slab(mesh, key: tuple, frags: list, row_id: int) -> jax.Array:
     from . import mesh as mesh_mod
 
     def build():
+        # A residency miss: ``pack`` is roaring → words on the host,
+        # ``upload`` the transfer (and the on-device densify).
         from ..ops import packed
         n = _bucketed_slices(mesh, len(frags))
         mode = mesh_mod.densify_mode()
-        pairs = [frag.sparse_row_pairs(row_id)
-                 if frag is not None else None for frag in frags]
-        pairs += [None] * (n - len(pairs))
-        if mode is not None:
-            use_sparse, plan = packed.sparse_gate(
-                pairs, packed.WORDS_PER_SLICE)
-            if use_sparse:
-                subs = packed.WORDS_PER_SLICE // 128
-                lanes, vals = packed.bucket_prepared(pairs, subs,
-                                                     plan=plan)
+        with sched_context.stage("pack"):
+            pairs = [frag.sparse_row_pairs(row_id)
+                     if frag is not None else None for frag in frags]
+            pairs += [None] * (n - len(pairs))
+            sparse = None
+            if mode is not None:
+                use_sparse, plan = packed.sparse_gate(
+                    pairs, packed.WORDS_PER_SLICE)
+                if use_sparse:
+                    sparse = packed.bucket_prepared(
+                        pairs, packed.WORDS_PER_SLICE // 128, plan=plan)
+            if sparse is None:
+                block = packed.densify_host(pairs, packed.WORDS_PER_SLICE)
+        with sched_context.stage("upload"):
+            if sparse is not None:
                 return mesh_mod.densify_sharded(
-                    mesh, lanes, vals, interpret=(mode == "interpret"))
-        block = packed.densify_host(pairs, packed.WORDS_PER_SLICE)
-        return mesh_mod.shard_slices(mesh, block)
+                    mesh, *sparse, interpret=(mode == "interpret"))
+            return mesh_mod.shard_slices(mesh, block)
 
     return device_cache().get_or_build(key, build)
 
@@ -215,27 +222,31 @@ def candidate_block(mesh, key: tuple, frags: list,
         # device densify (3-6x cold-upload win at sparse shapes,
         # benchmarks/DENSIFY.json) or host dense scatter.
         mode = mesh_mod.densify_mode()
-        pairs: list = []
-        for si in range(n):
-            frag = frags[si] if si < len(frags) else None
-            for rid in row_ids:
-                pairs.append(None if frag is None
-                             else frag.sparse_row_pairs(rid))
-        if mode is not None:
-            use_sparse, plan = packed.sparse_gate(
-                pairs, packed.WORDS_PER_SLICE)
-            if use_sparse:
-                subs = packed.WORDS_PER_SLICE // 128
-                lanes, vals = packed.bucket_prepared(pairs, subs,
-                                                     plan=plan)
-                shp = (n, len(row_ids)) + lanes.shape[1:]
+        with sched_context.stage("pack"):
+            pairs: list = []
+            for si in range(n):
+                frag = frags[si] if si < len(frags) else None
+                for rid in row_ids:
+                    pairs.append(None if frag is None
+                                 else frag.sparse_row_pairs(rid))
+            sparse = None
+            if mode is not None:
+                use_sparse, plan = packed.sparse_gate(
+                    pairs, packed.WORDS_PER_SLICE)
+                if use_sparse:
+                    lanes, vals = packed.bucket_prepared(
+                        pairs, packed.WORDS_PER_SLICE // 128, plan=plan)
+                    shp = (n, len(row_ids)) + lanes.shape[1:]
+                    sparse = lanes.reshape(shp), vals.reshape(shp)
+            if sparse is None:
+                rows = packed.densify_host(
+                    pairs, packed.WORDS_PER_SLICE).reshape(
+                        n, len(row_ids), packed.WORDS_PER_SLICE)
+        with sched_context.stage("upload"):
+            if sparse is not None:
                 return mesh_mod.densify_sharded(
-                    mesh, lanes.reshape(shp), vals.reshape(shp),
-                    interpret=(mode == "interpret"))
-        rows = packed.densify_host(
-            pairs, packed.WORDS_PER_SLICE).reshape(
-                n, len(row_ids), packed.WORDS_PER_SLICE)
-        return mesh_mod.shard_slices(mesh, rows)
+                    mesh, *sparse, interpret=(mode == "interpret"))
+            return mesh_mod.shard_slices(mesh, rows)
 
     return device_cache().get_or_build(key, build)
 

@@ -12,8 +12,12 @@ executor's map-reduce fan-out, the device dispatch layer
   original) or ``?timeout=`` (Go-style duration on the entry request),
 - a **cancel flag** set by DELETE /debug/queries/{id} (locally or via
   the cluster broadcast), and
-- **stage timings** (parse/admission/execute/encode) for the
-  slow-query log.
+- the **stage clock**: self-time stages that tile the request from
+  socket to socket (``StageClock`` below) — self seconds and entries
+  per stage, CPU seconds per thread, for /debug/queries, the slow log,
+  kept traces, the ``queryStages`` totals at /debug/vars and, under a
+  profiler session, ``pilosa.<stage>`` segments on the profiler's own
+  clock.
 
 Checks are cooperative: every layer that can block or loop calls
 ``ctx.check()`` (or module-level ``check_current()`` from code that
@@ -25,6 +29,7 @@ is gone. The context travels between executor worker threads via
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 import uuid
@@ -54,6 +59,318 @@ QUERY_ID_HEADER = "X-Pilosa-Query-Id"
 TENANT_HEADER = "X-Pilosa-Tenant"
 
 
+# -- the stage clock ---------------------------------------------------------
+# The stage names are a contract: counter keys in /debug/vars
+# ``queryStages``, span names in kept traces, ``pilosa.<name>`` in the
+# profiler. On the connection thread, in order: http_read, parse, setup,
+# admission, execute (self), plan, route, pack, upload, dispatch
+# (+ compile), fetch, merge, legs_wait, commit, finish, encode,
+# http_write; ``leg`` is the base stage of a map-reduce worker thread.
+# docs/OBSERVABILITY.md "Trace contract" has the table.
+
+# WSGI environ key under which the HTTP front end hands its clock
+# (opened at ``recv``, closed after ``sendall``) to the handler.
+CLOCK_ENVIRON = "pilosa.stage_clock"
+
+# Ended stages a clock keeps for the kept trace (obs.trace caps a
+# trace's own spans the same way).
+MAX_STAGE_SPANS = 512
+
+_annotation = None      # jax.profiler.TraceAnnotation, resolved lazily
+
+
+def _session_live() -> bool:
+    """Is a profiler session recording? One flag test (no switch of
+    our own: the session IS the switch). A process that has not
+    imported jax has none — look again next time instead of paying the
+    import here."""
+    global _annotation
+    if _annotation is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return False
+        _annotation = jax.profiler.TraceAnnotation
+    return _annotation.is_enabled()
+
+
+def _annotate(name: str):
+    """An entered ``TraceAnnotation("pilosa.<name>")``: one flat segment
+    on this thread's /host:CPU line, on the clock the device trace
+    uses; None with no session."""
+    if not _session_live():
+        return None
+    ann = _annotation("pilosa." + name)
+    ann.__enter__()
+    return ann
+
+
+class StageClock:
+    """Self-time stages of ONE thread: a stack of open stages and, per
+    stage name, ``[entries, wall seconds]``.
+
+    Entering a stage charges the time since the last boundary to the
+    stage that was on top and suspends it; leaving charges it to the
+    stage itself and resumes the parent — so every stage holds SELF
+    time and the stages sum to the thread's time between the first and
+    the last boundary by construction. ``switch`` replaces the top
+    stage: the form for the connection thread's top-level sequence
+    (http_read → parse → … → http_write), which never leaves the stack
+    empty in between. A boundary reads ``perf_counter`` once.
+
+    The thread's CPU clock is read only where the stack fills and where
+    it empties (twice a request on the connection thread, twice a leg):
+    ``cpu`` is the CPU seconds of the whole tiling, and wall − CPU is
+    time the thread was not running (the GIL, a lock, the device, the
+    socket). Not at every boundary, and not per stage: on the sandboxed
+    kernel the chip's host runs (gVisor), ``thread_time`` costs 6 µs a
+    call and ticks every 10 ms (my chip run, PR 25) — right summed over
+    thousands of requests, noise within one, and 40 reads a request
+    would cost 5 % of a 5 ms Count. Only the owning thread calls the
+    mutators."""
+
+    __slots__ = ("acct", "cpu", "ctx", "requests", "spans", "tid",
+                 "_stack", "_wall", "_cpu0", "_ann")
+
+    def __init__(self, name: str = "", start: Optional[float] = None):
+        self.acct: dict[str, list] = {}
+        self.cpu = 0.0
+        self.ctx: Optional["QueryContext"] = None
+        self.requests = 1       # the pipelined batch lane sets its size
+        self._stack: list[tuple] = []     # (name, wall at entry, tags)
+        self._wall = start or time.perf_counter()
+        self._cpu0 = 0.0
+        self._ann = None
+        # Ended stages, whole (entry to exit, children inside): what a
+        # kept trace shows. Plain tuples (name, entry, exit, tags) on
+        # this clock; ``QueryContext.stage_spans`` makes Spans of them
+        # only when a trace is read.
+        self.spans: list[tuple] = []
+        self.tid = threading.get_ident()
+        if name:
+            self._cpu0 = time.thread_time()
+            self._begin(name, self._wall, None)
+
+    def _mark(self, name: str) -> None:
+        """Close this thread's profiler segment and open ``name``'s:
+        one name at a time per thread, never nested."""
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self._ann = _annotate(name) if name else None
+
+    def _leave(self) -> None:
+        name, t0, tags = self._stack.pop()
+        ctx = self.ctx
+        if ((ctx is None or ctx.trace is not None)
+                and len(self.spans) < MAX_STAGE_SPANS):
+            self.spans.append((name, t0, self._wall, tags))
+
+    def _begin(self, name: str, wall: float,
+               tags: Optional[dict]) -> None:
+        a = self.acct.get(name)
+        if a is None:
+            self.acct[name] = [1, 0.0]
+        else:
+            a[0] += 1
+        self._stack.append((name, wall, tags))
+        if self._ann is not None or _session_live():
+            self._mark(name)
+
+    def push(self, name: str, tags: Optional[dict] = None) -> None:
+        """The boundary into ``name``: charge the time since the last
+        one to the stage on top, suspend it, enter ``name``."""
+        wall = time.perf_counter()
+        if self._stack:
+            self.acct[self._stack[-1][0]][1] += wall - self._wall
+        else:
+            self._cpu0 = time.thread_time()
+        self._wall = wall
+        self._begin(name, wall, tags)
+
+    def pop(self) -> None:
+        """The boundary out of the stage on top: charge it, resume its
+        parent."""
+        wall = time.perf_counter()
+        stack = self._stack
+        self.acct[stack[-1][0]][1] += wall - self._wall
+        self._wall = wall
+        self._leave()
+        if not stack:
+            self.cpu += time.thread_time() - self._cpu0
+        if self._ann is not None or _session_live():
+            self._mark(stack[-1][0] if stack else "")
+
+    def switch(self, name: str) -> None:
+        """End the stage on top and begin ``name`` in its place, in one
+        boundary (a push where nothing is open)."""
+        if not self._stack:
+            return self.push(name)
+        wall = time.perf_counter()
+        self.acct[self._stack[-1][0]][1] += wall - self._wall
+        self._wall = wall
+        self._leave()
+        self._begin(name, wall, None)
+
+    def close(self) -> None:
+        """The last boundary: every open stage ends, the profiler
+        segment closes, and a clock that served a query folds into the
+        process totals. The HTTP front end calls this once the
+        response has been handed to the socket."""
+        if self._stack:
+            wall = time.perf_counter()
+            self.acct[self._stack[-1][0]][1] += wall - self._wall
+            self._wall = wall
+            self.cpu += time.thread_time() - self._cpu0
+            while self._stack:
+                self._leave()
+        self._mark("")
+        ctx, self.ctx = self.ctx, None
+        if ctx is not None:
+            trace = ctx.trace
+            if trace is not None and trace.keep_reason:
+                trace.seal()    # a kept trace outlives its query
+            _fold(ctx, self)
+
+
+class _StageCM:
+    """``with`` form of push/pop; pops on an exception too."""
+
+    __slots__ = ("_clock", "_name", "_tags")
+
+    def __init__(self, clock: StageClock, name: str,
+                 tags: Optional[dict]):
+        self._clock = clock
+        self._name = name
+        self._tags = tags
+
+    def __enter__(self):
+        self._clock.push(self._name, self._tags)
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self._clock.pop()
+        return False
+
+
+class _SpanCM:
+    """A tagged wall-clock span of the kept trace that is NOT a stage
+    of the tiling (fan-out events: rpc, failover, hedge, ...)."""
+
+    __slots__ = ("_ctx", "_name", "_tags", "_t0")
+
+    def __init__(self, ctx: "QueryContext", name: str,
+                 tags: Optional[dict]):
+        self._ctx = ctx
+        self._name = name
+        self._tags = tags
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        ctx = self._ctx
+        ctx.trace.add_span(self._name, ctx.wall_at(self._t0),
+                           time.perf_counter() - self._t0, self._tags)
+        return False
+
+
+class _Nop:
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        return False
+
+
+NOP = _Nop()
+
+
+# Process totals behind /debug/vars: ``queryStages`` by lane (non-remote
+# /query requests, folded once each when the response is on the socket)
+# and ``backgroundTicks`` by loop. One lock, taken once a request / tick.
+_TOTALS_MU = threading.Lock()
+_QUERY_TOTALS: dict[str, dict] = {}
+_BG_TOTALS: dict[str, list] = {}
+
+
+def _add(into: dict, acct: dict) -> None:
+    for name, (n, wall) in acct.items():
+        t = into.get(name)
+        if t is None:
+            into[name] = [n, wall]
+        else:
+            t[0] += n
+            t[1] += wall
+
+
+def _fold(ctx: "QueryContext", clock: StageClock) -> None:
+    if ctx.remote or ctx.lane not in (LANE_READ, LANE_WRITE):
+        return
+    off = ctx.stage_totals()[1]
+    off_cpu = ctx.stage_cpu()[1]
+    with _TOTALS_MU:
+        lane = _QUERY_TOTALS.get(ctx.lane)
+        if lane is None:
+            lane = _QUERY_TOTALS[ctx.lane] = {
+                "requests": 0, "cpu": 0.0, "offCpu": 0.0,
+                "stages": {}, "offThread": {}}
+        lane["requests"] += clock.requests
+        lane["cpu"] += clock.cpu
+        lane["offCpu"] += off_cpu
+        _add(lane["stages"], clock.acct)
+        _add(lane["offThread"], off)
+
+
+def _acct_json(acct: dict) -> dict:
+    return {name: {"n": n, "wallUs": round(wall * 1e6)}
+            for name, (n, wall) in acct.items()}
+
+
+def stage_totals() -> dict:
+    """The /debug/vars blocks ``queryStages`` and ``backgroundTicks``."""
+    with _TOTALS_MU:
+        return {
+            "queryStages": {
+                lane: {"requests": t["requests"],
+                       "cpuUs": round(t["cpu"] * 1e6),
+                       "offThreadCpuUs": round(t["offCpu"] * 1e6),
+                       "stages": _acct_json(t["stages"]),
+                       "offThread": _acct_json(t["offThread"])}
+                for lane, t in _QUERY_TOTALS.items()},
+            "backgroundTicks": {
+                loop: {"n": n, "wallUs": round(wall * 1e6),
+                       "cpuUs": round(cpu * 1e6)}
+                for loop, (n, wall, cpu) in _BG_TOTALS.items()}}
+
+
+@contextmanager
+def background_tick(loop: str):
+    """Around ONE tick of a background loop: the same wall/CPU/entries
+    counter under ``backgroundTicks[loop]`` and a ``pilosa.bg.<loop>``
+    segment on the profiler's clock, so what the loops cost a served
+    request (the GIL they hold) can be read beside its stages."""
+    ann = _annotate("bg." + loop)
+    wall, cpu = time.perf_counter(), time.thread_time()
+    try:
+        yield
+    finally:
+        dw = time.perf_counter() - wall
+        dc = time.thread_time() - cpu
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        with _TOTALS_MU:
+            t = _BG_TOTALS.get(loop)
+            if t is None:
+                _BG_TOTALS[loop] = [1, dw, dc]
+            else:
+                t[0] += 1
+                t[1] += dw
+                t[2] += dc
+
+
+
 class QueryContext:
     """Lifecycle state of one in-flight query."""
 
@@ -61,7 +378,8 @@ class QueryContext:
                  lane: str = LANE_READ,
                  timeout_s: Optional[float] = None,
                  id: Optional[str] = None, remote: bool = False,
-                 node: str = "", tenant: str = ""):
+                 node: str = "", tenant: str = "",
+                 clock: Optional[StageClock] = None):
         self.id = id or uuid.uuid4().hex[:16]
         self.pql = pql
         self.index = index
@@ -74,18 +392,28 @@ class QueryContext:
         self.node = node
         self.started = time.monotonic()
         self.started_wall = time.time()
+        self._started_perf = time.perf_counter()
         self.deadline = (self.started + timeout_s
                          if timeout_s else None)
         self.state = "queued"
         self.cancel_reason = ""
         self._cancelled = threading.Event()
         self._mu = threading.Lock()
-        self.stages: dict[str, float] = {}
+        # The stage clocks, one a thread that ran a stage of this
+        # query. The request thread's (the constructing thread; the
+        # HTTP front end's clock when it handed one over) tiles the
+        # request; the others (map-reduce workers bound with ``use``)
+        # are kept apart so that sum stays a tiling.
+        self._owner = threading.get_ident()
+        self._clocks: dict[int, StageClock] = {}
+        if clock is not None:
+            clock.ctx = self
+            self._clocks[self._owner] = clock
         self.legs: list[dict] = []
         # Distributed-tracing attachment (obs.trace.Trace), bound by
         # the tracer when tracing is on. None (the default) is the
-        # no-allocation fast path: stage() and span_current() check it
-        # and record nothing.
+        # no-allocation fast path: a stage records no span and
+        # span() returns the shared no-op.
         self.trace = None
         # Resource-accounting attachment (obs.accounting.QueryCost),
         # bound by the serving layer when accounting is on. Same
@@ -188,21 +516,77 @@ class QueryContext:
 
     # -- bookkeeping ---------------------------------------------------------
 
-    @contextmanager
-    def stage(self, name: str):
-        """Record wall time of one pipeline stage (accumulating —
-        a stage may run more than once, e.g. per-leg encode). When a
-        trace is attached, the stage doubles as a span."""
-        t0 = time.perf_counter()
-        t0_wall = time.time() if self.trace is not None else 0.0
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            with self._mu:
-                self.stages[name] = self.stages.get(name, 0.0) + dt
-            if self.trace is not None:
-                self.trace.add_span(name, t0_wall, dt)
+    def clock(self) -> StageClock:
+        """The calling thread's stage clock for this query."""
+        tid = threading.get_ident()
+        clock = self._clocks.get(tid)
+        if clock is None:
+            # No back-reference: a context and its clocks must not form
+            # a cycle (every request would leave garbage only the
+            # cyclic collector can free). The request thread's clock
+            # holds its context until ``close`` folds it, and no longer.
+            clock = self._clocks[tid] = StageClock()
+        return clock
+
+    def stage(self, name: str, **tags) -> _StageCM:
+        """One stage of the calling thread's tiling, as a ``with``
+        block (accumulating: a stage may run more than once; nested
+        stages suspend their parent). When a trace is attached the
+        stage doubles as a span carrying ``tags``."""
+        clock = self.clock()
+        if not tags and clock._stack and clock._stack[-1][0] == name:
+            return NOP      # already in it (a recursive or inner route)
+        return _StageCM(clock, name, tags or None)
+
+    def span(self, name: str, **tags):
+        """A tagged wall-clock span on the kept trace that is not a
+        stage of the tiling (fan-out events); the shared no-op when no
+        trace is attached."""
+        if self.trace is None:
+            return NOP
+        return _SpanCM(self, name, tags or None)
+
+    def stage_spans(self) -> list[tuple]:
+        """Every ended stage of every thread as (name, wall start,
+        seconds, tags, thread id): the spans a trace of this query
+        shows, on the wall clock its peers share."""
+        out = []
+        for clock in list(self._clocks.values()):
+            for name, t0, t1, tags in list(clock.spans):
+                out.append((name, self.wall_at(t0), t1 - t0, tags,
+                            clock.tid))
+        return out
+
+    def wall_at(self, t_perf: float) -> float:
+        """Wall-clock seconds of a ``perf_counter`` reading: the
+        context's wall start plus the monotonic offset (no second
+        wall-clock read a span)."""
+        return self.started_wall + (t_perf - self._started_perf)
+
+    def _own_and_other_clocks(self) -> tuple:
+        clocks = dict(self._clocks)
+        return clocks.pop(self._owner, None), list(clocks.values())
+
+    def stage_totals(self) -> tuple[dict, dict]:
+        """({stage: [n, wall s]} of the request thread, the same
+        summed over every other thread that ran a stage)."""
+        own, others = self._own_and_other_clocks()
+        off: dict[str, list] = {}
+        for clock in others:
+            _add(off, dict(clock.acct))
+        return (dict(own.acct) if own is not None else {}), off
+
+    def stage_cpu(self) -> tuple[float, float]:
+        """(CPU seconds of the request thread's tiling, of the other
+        threads' stages), as far as their stacks have emptied."""
+        own, others = self._own_and_other_clocks()
+        return (own.cpu if own is not None else 0.0,
+                sum(c.cpu for c in others))
+
+    @property
+    def stages(self) -> dict[str, float]:
+        """Self wall seconds by stage on the request thread."""
+        return {name: a[1] for name, a in self.stage_totals()[0].items()}
 
     def add_leg(self, host: str, n_slices: int) -> None:
         """Record a map-reduce leg (node host + slice count) for
@@ -214,7 +598,8 @@ class QueryContext:
         rem = self.remaining()
         with self._mu:
             legs = list(self.legs)
-            stages = dict(self.stages)
+        own, off = self.stage_totals()
+        stages = {name: a[1] for name, a in own.items()}
         out = {
             "id": self.id,
             "pql": self.pql[:200],
@@ -230,6 +615,8 @@ class QueryContext:
             "legs": legs,
             "stages": {k: round(v, 4) for k, v in stages.items()},
         }
+        if off:
+            out["offThread"] = {k: round(a[1], 4) for k, a in off.items()}
         if self.cost is not None:
             # The accounting roll-up rides /debug/queries and the slow
             # log (obs.accounting.QueryCost.summary — totals only).
@@ -296,6 +683,25 @@ def use(ctx: Optional[QueryContext]):
             _by_thread[tid] = prev
         else:
             _by_thread.pop(tid, None)
+
+
+def stage(name: str, **tags):
+    """``ctx.stage`` on the thread's current query; the shared no-op
+    when none is bound. The form for layers that take no ctx argument
+    (device dispatch, residency, the planner)."""
+    ctx = getattr(_tls, "ctx", None)
+    if ctx is None:
+        return NOP
+    return ctx.stage(name, **tags)
+
+
+def span(name: str, **tags):
+    """``ctx.span`` on the thread's current query; the shared no-op
+    when none is bound or it has no trace."""
+    ctx = getattr(_tls, "ctx", None)
+    if ctx is None or ctx.trace is None:
+        return NOP
+    return _SpanCM(ctx, name, tags or None)
 
 
 def check_current() -> None:

@@ -36,6 +36,8 @@ import threading
 import time
 from typing import Optional
 
+from . import context as sched_context
+
 
 def warmup_enabled() -> bool:
     return os.environ.get("PILOSA_TPU_WARMUP", "1") != "0"
@@ -165,7 +167,8 @@ class Warmup:
                 step = steps.get(name)
                 if step is None:
                     continue
-                step()
+                with sched_context.background_tick("warmup"):
+                    step()
                 self.compiled.append(name)
             self.state = "done"
             self.elapsed_s = time.monotonic() - t0

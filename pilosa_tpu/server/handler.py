@@ -18,6 +18,7 @@ import json
 import os
 import re
 import threading
+import time
 from typing import Callable, Optional
 from urllib.parse import parse_qs
 
@@ -595,6 +596,14 @@ class Handler:
                                  "deviceBps": model.cal.device_bps,
                                  "margin": model.margin,
                                  "drift": model.drift_snapshot()}
+        # The stage clock's process totals (sched.context): self-time
+        # stages of the served /query requests by lane, one tick's cost
+        # of every background loop, and the last compilations by
+        # program name.
+        snap.update(sched_context.stage_totals())
+        snap["sampledAt"] = time.time()     # two reads bound a window
+        from ..parallel import mesh as mesh_mod
+        snap["compileLog"] = mesh_mod.compile_log()
         return Response.json(snap)
 
     # -- profiling (reference handler.go:30,99 mounts net/http/pprof) --------
@@ -1580,6 +1589,24 @@ class Handler:
     # -- query ---------------------------------------------------------------
 
     def _handle_post_query(self, req: Request) -> Response:
+        clock = req.environ.get(sched_context.CLOCK_ENVIRON)
+        if clock is not None:
+            return self._post_query(req, clock)
+        # A bare WSGI caller, no front end that times its own read and
+        # write: the query's stages begin and end here.
+        clock = sched_context.StageClock()
+        try:
+            return self._post_query(req, clock)
+        finally:
+            clock.close()
+
+    def _post_query(self, req: Request,
+                    clock: sched_context.StageClock) -> Response:
+        """One query under its stage clock (sched.context): this
+        thread's top-level stages are switched here in order — parse,
+        setup (context, accounting, tracer, registry), finish (the
+        ``finally`` below), encode — with admission, execute and commit
+        nested inside setup, so the clock never stands on no stage."""
         index_name = req.vars["index"]
         proto_out = _PROTOBUF in req.accept
 
@@ -1608,14 +1635,12 @@ class Handler:
             column_attrs = req.query.get("columnAttrs") == "true"
             remote = False
 
-        import time as time_mod
-        parse_wall = time_mod.time()
-        parse_t0 = time_mod.perf_counter()
+        clock.switch("parse")
         try:
             query = pql.parse(query_str)
         except PilosaError as e:
             return error_resp(400, str(e))
-        parse_s = time_mod.perf_counter() - parse_t0
+        clock.switch("setup")
 
         if req.query.get("plan") == "1" and not remote:
             # EXPLAIN-only: plan the query without executing. The
@@ -1645,8 +1670,7 @@ class Handler:
             pql=query_str, index=index_name, lane=lane,
             timeout_s=self._query_timeout_s(req),
             id=self.environ_header(req, "HTTP_X_PILOSA_QUERY_ID") or None,
-            remote=remote, node=self.host, tenant=tenant)
-        ctx.stages["parse"] = parse_s
+            remote=remote, node=self.host, tenant=tenant, clock=clock)
         # ?profile=1 asks for EXPLAIN ANALYZE: the executor fills in
         # exact per-node actual cardinalities (it pays one count()
         # walk per planned call) on top of the always-on wall times.
@@ -1677,7 +1701,6 @@ class Handler:
                 req, "HTTP_X_PILOSA_TRACE") == "1"))
         if trace_requested or self.sampler is not None:
             trace = self.tracer.start(ctx, node=self.host)
-            trace.add_span("parse", parse_wall, parse_s)
         # Query latency label set: one call name when the query is
         # homogeneous, "multi" otherwise (bounded cardinality).
         call_names = {c.name for c in query.calls}
@@ -1815,6 +1838,7 @@ class Handler:
                                index_name, query_str, e)
             return error_resp(500, str(e), headers=_resp_headers())
         finally:
+            clock.switch("finish")
             if slot is not None:
                 slot.release()
             if isinstance(err, HTTPError):
@@ -2011,23 +2035,23 @@ class Handler:
             qid_hdr.append(("X-Pilosa-Partial", ",".join(
                 str(s) for s in sorted(exec_opt.missing_slices))))
             obs_metrics.PARTIAL_RESULTS.inc()
-        with ctx.stage("encode"):
-            if proto_out:
-                return Response.proto(
-                    codec.encode_query_response(results, attr_sets),
-                    headers=qid_hdr)
-            payload = codec.query_response_json(results, attr_sets)
-            if req.query.get("profile") == "1" and ctx.cost is not None:
-                # EXPLAIN ANALYZE for PQL: the merged per-node,
-                # per-stage cost tree rides inline with the results
-                # (remote legs' ledgers arrived as stitched children).
-                payload["profile"] = ctx.cost.to_tree(dict(ctx.stages))
-            if req.query.get("profile") == "1" and ctx.plan is not None:
-                # The chosen plan with per-node est-vs-actual rows and
-                # wall time, remote legs stitched in from
-                # X-Pilosa-Plan headers.
-                payload["plan"] = ctx.plan.to_tree()
-            return Response.json(payload, headers=qid_hdr)
+        clock.switch("encode")
+        if proto_out:
+            return Response.proto(
+                codec.encode_query_response(results, attr_sets),
+                headers=qid_hdr)
+        payload = codec.query_response_json(results, attr_sets)
+        if req.query.get("profile") == "1" and ctx.cost is not None:
+            # EXPLAIN ANALYZE for PQL: the merged per-node,
+            # per-stage cost tree rides inline with the results
+            # (remote legs' ledgers arrived as stitched children).
+            payload["profile"] = ctx.cost.to_tree(dict(ctx.stages))
+        if req.query.get("profile") == "1" and ctx.plan is not None:
+            # The chosen plan with per-node est-vs-actual rows and
+            # wall time, remote legs stitched in from
+            # X-Pilosa-Plan headers.
+            payload["plan"] = ctx.plan.to_tree()
+        return Response.json(payload, headers=qid_hdr)
 
     # -- attr diff (anti-entropy) --------------------------------------------
 

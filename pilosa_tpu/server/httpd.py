@@ -13,6 +13,12 @@ Design:
   the few headers the app reads).
 - PIPELINING: every complete request already buffered is parsed before
   responding, and responses go out in one sendall.
+- STAGE CLOCK: a request's self-time stages begin here. ``recv``
+  returning is the first boundary (never the idle wait inside it); a
+  ``StageClock`` opened on ``http_read`` rides the WSGI environ to the
+  handler, which switches it through its own stages and binds it to the
+  QueryContext; this loop switches it to ``http_write`` when the app
+  returns and closes it after ``sendall`` (sched.context).
 - QUERY BATCH LANE: consecutive pipelined ``POST /index/{i}/query``
   requests (plain-PQL JSON mode, same index) execute as ONE combined
   executor call — the executor's mutate-batch run then turns a 1000-
@@ -28,7 +34,9 @@ import re
 import socket
 import sys
 import threading
+import time
 
+from ..sched import context as sched_context
 from ..utils import logger as logger_mod
 
 _QUERY_PATH_RE = re.compile(r"^/index/([^/]+)/query$")
@@ -64,7 +72,8 @@ class HTTPServer:
                  logger=logger_mod.NOP, query_batcher=None):
         self.app = app
         self.logger = logger
-        # query_batcher(index, [pql bodies]) -> list[response bytes] | None
+        # query_batcher(index, [pql bodies], stage clock)
+        #   -> list[response bytes] | None
         self.query_batcher = query_batcher
         self._sock = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
         self._sock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
@@ -137,6 +146,7 @@ class HTTPServer:
         conn.settimeout(self.IDLE_TIMEOUT_S)
         buf = bytearray()
         need = 0
+        t_recv = None   # clock reading of the recv that came last
         try:
             while not self._closing.is_set():
                 if need and len(buf) < need:
@@ -148,6 +158,7 @@ class HTTPServer:
                         data = conn.recv(1 << 20)
                     except TimeoutError:
                         return
+                    t_recv = time.perf_counter()
                     if not data:
                         return
                     buf += data
@@ -162,33 +173,19 @@ class HTTPServer:
                     # the client must not read the 400 as the response
                     # to an earlier (valid, possibly mutating) request.
                     if reqs:
-                        items, _ = self._process(reqs)
-                        for item in items:
-                            if isinstance(item, bytes):
-                                conn.sendall(item)
-                            else:
-                                for chunk in item:
-                                    if chunk:
-                                        conn.sendall(chunk)
+                        self._respond(conn, reqs, t_recv)
                     conn.sendall(self._plain_response(
                         400, "malformed request", close=True))
                     return
                 if reqs:
-                    items, close = self._process(reqs)
-                    for item in items:
-                        if isinstance(item, bytes):
-                            conn.sendall(item)
-                        else:  # streamed body: send chunk by chunk
-                            for chunk in item:
-                                if chunk:
-                                    conn.sendall(chunk)
-                    if close:
+                    if self._respond(conn, reqs, t_recv):
                         return
                     continue
                 try:
                     data = conn.recv(1 << 16)
                 except TimeoutError:
                     return  # idle past IDLE_TIMEOUT_S
+                t_recv = time.perf_counter()
                 if not data:
                     return
                 buf += data
@@ -205,6 +202,25 @@ class HTTPServer:
                 pass
             with self._conns_mu:
                 self._conns.discard(conn)
+
+    def _respond(self, conn: socket.socket, reqs: list, t_recv) -> bool:
+        """Process a pipelined group and send its responses; True when
+        the connection closes after it. The group's last stage clock
+        stays on ``http_write`` through the ``sendall`` and closes (and
+        folds into the process totals) once the socket has the bytes."""
+        items, close, clock = self._process(reqs, t_recv)
+        try:
+            for item in items:
+                if isinstance(item, bytes):
+                    conn.sendall(item)
+                else:  # streamed body: send chunk by chunk
+                    for chunk in item:
+                        if chunk:
+                            conn.sendall(chunk)
+        finally:
+            if clock is not None:
+                clock.close()
+        return close
 
     def _drain_requests(self, buf: bytearray):
         """Parse every complete request in ``buf`` (consuming them).
@@ -250,14 +266,29 @@ class HTTPServer:
 
     # -- request processing --------------------------------------------------
 
-    def _process(self, reqs: list[_Request]) -> tuple[list, bool]:
-        """Response items (bytes, or a generator for streamed bodies)
-        for a pipelined group, batching query POST runs."""
+    def _process(self, reqs: list[_Request], t_recv=None):
+        """(response items, close, the last stage clock) for a
+        pipelined group, batching query POST runs. An item is bytes, or
+        a generator for a streamed body. Every request (every batch)
+        has a clock of its own, opened on ``http_read`` — the first at
+        ``t_recv``, the boundary reading of the recv that completed the
+        group; a clock closes when the next request begins, and the
+        last is returned open (on ``http_write``) for the caller to
+        close after ``sendall``. Only ``POST /index/{i}/query`` is
+        clocked: the stages are a query's."""
         out: list = []
         close = False
+        clock = None
         i = 0
         n = len(reqs)
         while i < n:
+            if clock is not None:
+                clock.close()
+                clock = None
+            if (reqs[i].method == "POST"
+                    and _QUERY_PATH_RE.match(reqs[i].path)):
+                clock = sched_context.StageClock(
+                    "http_read", start=t_recv if i == 0 else None)
             run_index = self._batchable_index(reqs[i])
             if run_index is not None:
                 j = i + 1
@@ -267,8 +298,10 @@ class HTTPServer:
                 if j - i >= 2 and self.query_batcher is not None:
                     bodies = [reqs[k].body.decode("latin-1")
                               for k in range(i, j)]
-                    batched = self.query_batcher(run_index, bodies)
+                    batched = self.query_batcher(run_index, bodies,
+                                                 clock)
                     if batched is not None:
+                        clock.switch("http_write")
                         out.append(b"".join(
                             self._json_response(payload,
                                                 reqs[i + k].close)
@@ -276,12 +309,16 @@ class HTTPServer:
                         close = reqs[j - 1].close
                         i = j
                         continue
-            resp, close = self._dispatch_wsgi(reqs[i])
+                    # Declined: per-request dispatch, on a fresh clock
+                    # (the batch's attempt is not this request's time).
+                    clock.close()
+                    clock = sched_context.StageClock("http_read")
+            resp, close = self._dispatch_wsgi(reqs[i], clock)
             out.append(resp)
             i += 1
             if close:
                 break
-        return out, close
+        return out, close, clock
 
     def _batchable_index(self, req: _Request):
         """The index name when this request can join a query batch run,
@@ -298,8 +335,9 @@ class HTTPServer:
             return None
         return m.group(1)
 
-    def _dispatch_wsgi(self, req: _Request):
+    def _dispatch_wsgi(self, req: _Request, clock=None):
         environ = {
+            sched_context.CLOCK_ENVIRON: clock,
             "REQUEST_METHOD": req.method,
             "PATH_INFO": req.path,
             "QUERY_STRING": req.qs,
@@ -325,6 +363,8 @@ class HTTPServer:
             captured["headers"] = headers
 
         body_iter = self.app(environ, start_response)
+        if clock is not None:
+            clock.switch("http_write")
         status = captured.get("status", "500 Internal Server Error")
         headers = captured.get("headers", [])
         has_length = any(k.lower() == "content-length"

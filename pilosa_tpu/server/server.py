@@ -737,7 +737,7 @@ class Server:
     def _serve(self) -> None:
         self._httpd.serve_forever()
 
-    def _query_batcher(self, index: str, bodies: list[str]):
+    def _query_batcher(self, index: str, bodies: list[str], clock):
         """Combine pipelined plain-PQL query bodies into one executor
         call (the httpd batch lane); None falls back to per-request
         dispatch. Partial-failure semantics are IDENTICAL to sequential
@@ -752,7 +752,11 @@ class Server:
         lane classified over all calls) and registers ONE QueryContext;
         if admission is full the lane declines (None) and the requests
         fall back to per-request dispatch through the handler, which
-        produces the proper per-request 429 + Retry-After."""
+        produces the proper per-request 429 + Retry-After.
+
+        ``clock`` is the front end's stage clock for the whole batch
+        (sched.context): ONE set of stages, folded into the process
+        totals with ``requests`` = the batch's size."""
         from ..errors import PilosaError
         from ..executor import _WRITE_CALLS, ExecOptions
         from ..pql import parser as pql
@@ -762,10 +766,12 @@ class Server:
         from . import codec
         if self.executor is None:
             return None
+        clock.switch("parse")
         try:
             queries = [pql.parse(b) for b in bodies]
         except PilosaError:
             return None
+        clock.switch("setup")
         calls = [c for q in queries for c in q.calls]
         if not calls or all(c.name == "SetRowAttrs" for c in calls):
             return None  # bulk-attrs path applies non-positionally
@@ -777,6 +783,7 @@ class Server:
                 # Write-unready after ENOSPC: decline the batch so
                 # per-request dispatch answers the proper 507s.
                 return None
+        clock.push("admission")
         try:
             # The batch's tenant is resolved BEFORE the slot is taken
             # (all requests in a batchable run share one index, which
@@ -785,17 +792,21 @@ class Server:
             slot = self.admission.acquire(lane, tenant=index)
         except AdmissionFullError:
             return None  # per-request dispatch answers the 429s/507s
+        finally:
+            clock.pop()
         ctx = QueryContext(pql=f"<pipelined batch: {len(calls)} calls>",
                            index=index, lane=lane,
                            timeout_s=self.query_config.default_timeout
-                           or None, node=self.host, tenant=index)
+                           or None, node=self.host, tenant=index,
+                           clock=clock)
+        clock.requests = len(bodies)
         if self.metrics_config.accounting:
             obs_accounting.attach(ctx, node=self.host)
         self.tenants.install(ctx)
         err = None  # stays None if execute_partial itself raises —
         # the finally below must never NameError over the real failure
         try:
-            with self.query_registry.track(ctx):
+            with self.query_registry.track(ctx), ctx.stage("execute"):
                 results, err = self.executor.execute_partial(
                     index, Query(calls), opt=ExecOptions(ctx=ctx))
             if lane == LANE_WRITE:
@@ -803,8 +814,10 @@ class Server:
                 # leader flush covers every mutation the whole
                 # pipelined group applied (storage.wal group commit).
                 from ..storage import wal as storage_wal
-                storage_wal.barrier_all()
+                with ctx.stage("commit"):
+                    storage_wal.barrier_all()
         finally:
+            clock.switch("finish")
             slot.release()
             # The batch lane bypasses the handler's query path, so it
             # records its own latency sample (obs.metrics).
@@ -827,6 +840,7 @@ class Server:
             payload = codec.query_response_json(rs, [])
             return (json.dumps(payload) + "\n").encode()
 
+        clock.switch("encode")
         if err is None:
             out = []
             pos = 0

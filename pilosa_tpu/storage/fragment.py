@@ -47,6 +47,7 @@ from ..obs import accounting as _accounting
 from ..obs import metrics as obs_metrics
 from ..parallel.residency import DeviceRowCache
 from ..proto import internal_pb2 as pb
+from ..sched import context as sched_context
 from ..utils import logger as logger_mod
 from ..utils import arrays as arrays_mod
 from ..utils.arrays import sort_dedupe
@@ -1193,6 +1194,10 @@ class Fragment:
             name="frag-snapshot", daemon=True).start()
 
     def _snapshot_worker(self, frozen, tail_off: int) -> None:
+        with sched_context.background_tick("snapshot"):
+            self._snapshot_write(frozen, tail_off)
+
+    def _snapshot_write(self, frozen, tail_off: int) -> None:
         # Runs with _snap_mu held (acquired by _snapshot_async,
         # released here — a plain Lock supports cross-thread release).
         try:

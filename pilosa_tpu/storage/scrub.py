@@ -28,6 +28,7 @@ from typing import Optional
 import numpy as np
 
 from ..obs import metrics as obs_metrics
+from ..sched import context as sched_context
 from ..utils import logger as logger_mod
 from . import integrity
 from . import roaring
@@ -197,7 +198,8 @@ class Scrubber:
             if self._stop.is_set():
                 return
             try:
-                self.pass_once()
+                with sched_context.background_tick("scrub"):
+                    self.pass_once()
             except Exception as e:  # noqa: BLE001 - scrub must not die
                 self.logger.printf("scrub: pass failed: %s", e)
             del woke

@@ -68,6 +68,7 @@ from ..fault import diskfull as _diskfull
 from ..fault import failpoints as _fp
 from ..obs import accounting as _accounting
 from ..obs import metrics as obs_metrics
+from ..sched import context as sched_context
 
 OP_SIZE = 13  # one op record (storage.roaring.OP_SIZE; kept in sync)
 
@@ -476,32 +477,33 @@ def _flush_loop() -> None:
         _flusher_wake.wait()
         _flusher_wake.clear()
         time.sleep(window_s())
-        with _dirty_mu:
-            _flusher_beat = time.monotonic()
-            wals = list(_dirty)
-        for wal in wals:
-            if wal.closed:
-                with wal._mu:
-                    wal._registered = False
-                    _deregister_dirty(wal)
-                continue
-            try:
-                wal.flush(None,
-                          sync=wal.fsync_policy == FSYNC_ALWAYS)
-            except WalError:
-                # Drop it from the dirty set so the loop doesn't
-                # retry a failing disk every window — but clear
-                # _registered with it, so the owner's NEXT append
-                # re-registers and its barrier surfaces the error
-                # (leaving _registered set would make barrier_all()
-                # skip this WAL forever: acked-but-volatile).
-                with wal._mu:
-                    wal._registered = False
-                    _deregister_dirty(wal)
-        # Re-arm while anything stays dirty (a flush that early-returns
-        # because a batch formed mid-write leaves records pending with
-        # no new registration event to wake us): the window bound must
-        # hold without relying on future appends.
-        with _dirty_mu:
-            if _dirty:
-                _flusher_wake.set()
+        with sched_context.background_tick("wal_flush"):
+            with _dirty_mu:
+                _flusher_beat = time.monotonic()
+                wals = list(_dirty)
+            for wal in wals:
+                if wal.closed:
+                    with wal._mu:
+                        wal._registered = False
+                        _deregister_dirty(wal)
+                    continue
+                try:
+                    wal.flush(None,
+                              sync=wal.fsync_policy == FSYNC_ALWAYS)
+                except WalError:
+                    # Drop it from the dirty set so the loop doesn't
+                    # retry a failing disk every window — but clear
+                    # _registered with it, so the owner's NEXT append
+                    # re-registers and its barrier surfaces the error
+                    # (leaving _registered set would make barrier_all()
+                    # skip this WAL forever: acked-but-volatile).
+                    with wal._mu:
+                        wal._registered = False
+                        _deregister_dirty(wal)
+            # Re-arm while anything stays dirty (a flush that early-returns
+            # because a batch formed mid-write leaves records pending with
+            # no new registration event to wake us): the window bound must
+            # hold without relying on future appends.
+            with _dirty_mu:
+                if _dirty:
+                    _flusher_wake.set()

@@ -48,6 +48,7 @@ from typing import Optional
 
 from ..fault import failpoints as _fp
 from ..obs import metrics as obs_metrics
+from ..sched import context as sched_context
 from ..storage import integrity as integrity_mod
 from ..utils import logger as logger_mod
 from . import blob as blob_mod
@@ -142,7 +143,8 @@ class TierManager:
     def _run(self) -> None:
         while not self._stop.wait(self.interval_s):
             try:
-                self.pass_once()
+                with sched_context.background_tick("tier"):
+                    self.pass_once()
             except Exception as e:  # noqa: BLE001 - the loop must not die
                 self.logger.printf("tier: pass failed: %s", e)
 

@@ -163,7 +163,7 @@ class TestWatchdog:
         registry.register(ctx)
         ctx.state = "running"
         trace = tracer.start(ctx, node="n1")
-        with trace.span("execute"):
+        with ctx.stage("execute"):
             pass
         time.sleep(0.1)  # now well past deadline + grace
         fired = wd.check()

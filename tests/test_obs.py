@@ -460,12 +460,22 @@ class TestOverheadGuard:
         assert handler.tracer.traces() == []
 
     def test_span_current_nop_fast_path(self):
-        assert obs_trace.span_current("x") is obs_trace.NOP_SPAN
+        """The one emitter's no-op paths: no query bound to the thread
+        → neither a stage nor a span allocates; a bound but untraced
+        query runs its stages (the counters are always on) and records
+        no span."""
         from pilosa_tpu.sched import QueryContext
         from pilosa_tpu.sched import context as sched_context
+        assert sched_context.span("x") is sched_context.NOP
+        assert sched_context.stage("x") is sched_context.NOP
         ctx = QueryContext(pql="q")  # no trace attached
+        assert ctx.span("x") is sched_context.NOP
         with sched_context.use(ctx):
-            assert obs_trace.span_current("x") is obs_trace.NOP_SPAN
+            assert sched_context.span("x") is sched_context.NOP
+            with sched_context.stage("route"):
+                pass
+        assert ctx.stage_totals()[0]["route"][0] == 1
+        assert not hasattr(obs_trace, "span_current")
 
 
 class TestRuntimeCollector:
