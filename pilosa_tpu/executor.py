@@ -73,6 +73,18 @@ def _attach_plan_nodes(call: Call, node) -> None:
     for ch_call, ch_node in zip(call.children, node.children):
         _attach_plan_nodes(ch_call, ch_node)
 
+
+class _RoutedSlices(list):
+    """The whole-index slice list of one route record
+    (``Executor._route``). It carries its record, so every layer below
+    ``_execute`` recognises a memoised route by the list it was handed;
+    any list derived from it (a filter, a failover re-map, a peer's
+    group) is a plain list and takes the per-slice walk. Shared by
+    concurrent reads: never mutated."""
+
+    __slots__ = ("route",)
+
+
 # Lowest count used in a TopN when no threshold is given (executor.go:39).
 MIN_THRESHOLD = 1
 
@@ -284,6 +296,13 @@ class Executor:
         self.planner = plan_planner.Planner(holder,
                                             margin=self._cost_margin)
         self.planner_enabled = True
+        # /debug/vars.routeMemo: device-lowered reads whose whole route
+        # (slice list, ownership, slice→node groups, each leaf view's
+        # fragment list) was reused from its record in the planner's
+        # memo (hits) or resolved by the per-slice walk (misses), and
+        # records or view entries dropped because a token moved.
+        self.route_memo = {"hits": 0, "misses": 0, "invalidated": 0}
+        self._route_mu = threading.Lock()
         # Per-fingerprint plan store behind GET /debug/plans (the
         # handler records finished coordinator queries into it).
         self.plan_store = plan_store.PlanStore()
@@ -474,7 +493,7 @@ class Executor:
             idx = self.holder.index(index)
             if idx is None:
                 raise IndexNotFoundError(index)
-            slices = list(range(idx.max_slice() + 1))
+            slices = self._whole_slices(index, idx)
             inverse_slices = list(range(idx.max_inverse_slice() + 1))
             column_label = idx.column_label
         slices = slices or []
@@ -599,10 +618,12 @@ class Executor:
             all_local = self._owns_all_slices(index, slices)
         except Exception:  # noqa: BLE001 - locality is advisory here
             all_local = False
+        route = getattr(slices, "route", None)
         try:
             planned, rec = self.planner.plan_query_cached(
                 index, query.calls, slices, all_local=all_local,
-                node=self.host)
+                node=self.host,
+                slices_key=route["skey"] if route is not None else None)
         except Exception:  # noqa: BLE001 - planning never fails a query
             return query, None
         for call, node in zip(planned, rec.roots):
@@ -669,6 +690,9 @@ class Executor:
         the write-accept union — a stream target's copy is incomplete
         until the flip, so it must not claim local fast paths for a
         moving slice (cluster.topology.read_allowed)."""
+        route = getattr(slices, "route", None)
+        if route is not None:
+            return route["all_local"]  # this walk's answer, memoised
         q = getattr(self.holder, "quarantine", None)
         if q is not None and len(q) and any(
                 q.slice_blocked(index, s) for s in slices):
@@ -690,6 +714,97 @@ class Executor:
         host = self.host
         allowed = self.cluster.read_allowed
         return all(allowed(host, index, s) for s in slices)
+
+    # -- the route record (a read's route, resolved once per generation) -----
+
+    def _route_note(self, kind: str, invalidated: int = 0) -> None:
+        with self._route_mu:
+            self.route_memo[kind] += 1
+            self.route_memo["invalidated"] += invalidated
+
+    def _placement_token(self) -> Optional[tuple]:
+        """What a memoised slice→node grouping rests on, or None while
+        anything may steer a read away from its placement-order owner:
+        a resize in flight, a quarantined or tier-blocked slice, a
+        circuit that is not closed or hedging (fault.steering), a pod.
+        Node identity, not equality: a node replaced by its equal is
+        another node."""
+        cl = self.cluster
+        if cl.resize is not None or self.pod is not None:
+            return None
+        q = getattr(self.holder, "quarantine", None)
+        if q is not None and len(q):
+            return None
+        tier = getattr(self.holder, "tier", None)
+        if tier is not None and tier._blocked_slices:
+            return None
+        if self.fault is not None and self.fault.steering():
+            return None
+        return (cl.epoch, cl.replica_n, cl.partition_n, cl.hasher,
+                self.host, tuple(map(id, cl.nodes)))
+
+    def _whole_slices(self, index: str, idx) -> list[int]:
+        """Every slice of the index, as a read that names none asks:
+        the route record's own list where a route may be memoised."""
+        n = idx.max_slice() + 1
+        route = self._route(index, idx, n)
+        return route["slices"] if route is not None else list(range(n))
+
+    def _route(self, index: str, idx, n: int) -> Optional[dict]:
+        """The route record of a whole-index read over ``n`` slices:
+        ``{slices, skey, all_local, groups, views}`` — the slice list,
+        its O(1) name in residency keys, _owns_all_slices' answer,
+        _slices_by_node's grouping over the whole cluster, and for each
+        leaf view met so far its ((uid, generation), fragment list).
+        It lives in the planner's memo (one LRU, one validity rule:
+        tokens compared for equality) and is reused until the
+        placement token, the Index object or the slice count differs —
+        then it is dropped, counted, and resolved again by the walk,
+        which stays the slow path and the tests' oracle. View entries
+        are confirmed a leaf (_view_token). None = no memo for this
+        read (something steers reads away, no planner, or the grouping
+        depends on health order): the walk serves it."""
+        planner = self.planner
+        if planner is None:
+            return None
+        key = ("route", index)
+        placement = self._placement_token()
+        ent = planner.memo_get(key)
+        if ent is not None:
+            if (placement is not None and ent["idx"] is idx
+                    and len(ent["slices"]) == n
+                    and ent["placement"] == placement):
+                return ent
+            planner.memo_drop(key, ent)
+            self._route_note("invalidated")
+        if placement is None:
+            return None
+        slices = _RoutedSlices(range(n))
+        slices.route = None  # the walks below must not see a record
+        nodes = list(self.cluster.nodes)
+        try:
+            groups = self._slices_by_node(nodes, index, slices)
+        except SliceUnavailableError:
+            return None
+        local = all(node.host == self.host for node, _ in groups)
+        if (self.fault is not None and not local
+                and min(self.cluster.replica_n, len(nodes)) > 1):
+            # Remote replicas are ranked by health score, which moves
+            # without any token moving: keep the walk.
+            return None
+        all_local = self._owns_all_slices(index, slices)
+        if self._placement_token() != placement:
+            return None  # it moved under the walk: resolve again later
+        ent = {"idx": idx, "placement": placement, "nodes": nodes,
+               "slices": slices, "skey": ("r", 0, n),
+               "all_local": all_local, "views": {},
+               # A group that is the whole list IS the record's list,
+               # so the leg that serves it finds the record too.
+               "groups": [(node, slices if len(g) == n else g)
+                          for node, g in groups]}
+        slices.route = ent
+        planner.memo_put(key, ent)
+        return ent
 
     # -- coordinator hot-query result cache (cluster.generations) -----------
 
@@ -1504,14 +1619,14 @@ class Executor:
             shard, budget = self._count_budget(slices)
             if self._leaf_block_bytes(len(leaves), shard) > budget:
                 return NotImplemented
-            cold = self._cold_leaves(mesh, index, leaves, slices)
+            keys, found, cold = self._leaf_lookup(mesh, index, leaves,
+                                                  slices)
             if not self._device_pays(mesh, len(leaves), len(slices),
                                      cold_rows=cold):
                 return NotImplemented
             try:
-                arrs = [self._leaf_device_array(mesh, index, leaf,
-                                                tuple(slices))
-                        for leaf in leaves]
+                arrs = self._leaf_device_arrays(mesh, index, leaves,
+                                                slices, (keys, found))
                 counts = mesh_mod.count_exprs_sharded(mesh, exprs, arrs)
             except Exception as e:  # noqa: BLE001 - device trouble
                 self._note_device_fallback("sum_exprs", e)
@@ -1801,11 +1916,12 @@ class Executor:
         # block (costmodel host_bytes). A vetoed batch falls to
         # per-call gates that agree, landing everything on the host.
         from .parallel.residency import device_cache
-        cold = self._cold_leaves(mesh, index, leaves, slices)
+        keys, found, cold = self._leaf_lookup(mesh, index, leaves,
+                                              slices)
         rows_keys = []
         for expr_t, frame_name, ids in topn_items:
             rk = self._topn_rows_key(mesh, index, frame_name,
-                                     tuple(ids), tuple(slices))
+                                     tuple(ids), slices)
             rows_keys.append(rk)
             if not device_cache().contains(rk):
                 cold += len(ids)
@@ -1815,19 +1931,17 @@ class Executor:
                                  cold_rows=cold, host_rows=host_rows):
             return None
         try:
-            arrs = [self._leaf_device_array(mesh, index, leaf,
-                                            tuple(slices))
-                    for leaf in leaves]
+            arrs = self._leaf_device_arrays(mesh, index, leaves, slices,
+                                            (keys, found))
             if topn_items:
                 from .parallel import residency
                 rows_arrays = []
                 for (expr_t, frame_name, ids), rk in zip(topn_items,
                                                          rows_keys):
-                    frags = [self.holder.fragment(index, frame_name,
-                                                  VIEW_STANDARD, s)
-                             for s in slices]
                     rows_arrays.append(residency.candidate_block(
-                        mesh, rk, frags, tuple(ids)))
+                        mesh, rk, self._key_frags(
+                            index, frame_name, VIEW_STANDARD, slices,
+                            rk), tuple(ids)))
                 counts, topn_counts = mesh_mod.fused_tree_sharded(
                     mesh, count_exprs,
                     [(expr_t, len(ids))
@@ -1939,7 +2053,7 @@ class Executor:
 
     def _compile_device_expr(self, index: str, c: Call, leaves: list):
         """``_compile_expr`` under the ``route`` stage: with
-        _cold_leaves, _device_pays and _leaf_device_array, everything
+        _leaf_lookup, _device_pays and _leaf_device_arrays, everything
         that decides where a call runs and finds its operands."""
         with sched_context.stage("route"):
             return self._compile_expr(index, c, leaves)
@@ -2071,9 +2185,8 @@ class Executor:
                 return NotImplemented
             from .parallel import mesh as mesh_mod
             try:
-                arrs = [self._leaf_device_array(mesh, index, leaf,
-                                                tuple(slices))
-                        for leaf in leaves]
+                arrs = self._leaf_device_arrays(mesh, index, leaves,
+                                                slices)
                 words = mesh_mod.materialize_expr_sharded(mesh, expr,
                                                           arrs)
             except Exception as e:  # noqa: BLE001 - device trouble
@@ -2126,9 +2239,8 @@ class Executor:
                 return NotImplemented
             from .parallel import mesh as mesh_mod
             try:
-                arrs = [self._leaf_device_array(mesh, index, leaf,
-                                                tuple(slices))
-                        for leaf in leaves]
+                arrs = self._leaf_device_arrays(mesh, index, leaves,
+                                                slices)
                 words = mesh_mod.bsi_range_sharded(mesh, cop, upred,
                                                    depth, arrs)
             except Exception as e:  # noqa: BLE001 - device trouble
@@ -2196,7 +2308,8 @@ class Executor:
             # One ``route`` entry for the whole decision (the callees'
             # own route stages collapse into it).
             with sched_context.stage("route"):
-                cold = self._cold_leaves(mesh, index, leaves, slices)
+                keys, found, cold = self._leaf_lookup(mesh, index,
+                                                      leaves, slices)
                 if not self._device_pays(mesh, len(leaves), len(slices),
                                          cold_rows=cold, note=note):
                     return NotImplemented  # calibrated: host faster
@@ -2210,10 +2323,8 @@ class Executor:
                             mesh.shape[mesh_mod.AXIS_SLICES]):
                         # Residency fast path: leaf slabs stay device-
                         # resident across queries (budgeted HBM cache).
-                        with sched_context.stage("route"):
-                            arrs = [self._leaf_device_array(
-                                mesh, index, leaf, tuple(slices))
-                                for leaf in leaves]
+                        arrs = self._leaf_device_arrays(
+                            mesh, index, leaves, slices, (keys, found))
                         return mesh_mod.count_expr_sharded(mesh, expr,
                                                            arrs)
                     block = self._pack_leaf_block(index, leaves, slices)
@@ -2320,29 +2431,136 @@ class Executor:
         if pred is not None and self.cost_model is not None:
             self.cost_model.record("host", pred, elapsed_s)
 
-    def _leaf_cache_key(self, mesh, index: str, leaf: tuple,
-                        slices: tuple[int, ...]) -> tuple:
+    @staticmethod
+    def _slices_key(slices) -> tuple:
+        """The slice set as one part of a residency key: the route
+        record's interned ``("r", first, n)`` when ``slices`` is a
+        record's own list, the same triple for any other contiguous
+        ascending run (equal sets key equally, however they arrived),
+        else the tuple itself."""
+        route = getattr(slices, "route", None)
+        if route is not None:
+            return route["skey"]
+        n = len(slices)
+        if n and slices[-1] - slices[0] == n - 1 and (
+                n < 3 or list(slices) == list(
+                    range(slices[0], slices[0] + n))):
+            return ("r", slices[0], n)
+        return tuple(slices)
+
+    def _leaf_frags(self, index: str, frame: str, view: str,
+                    slices) -> list:
+        """One fragment a slice (None = absent), by the per-slice walk:
+        what a route record keeps a view, and its oracle."""
+        v = self.holder.view(index, frame, view)
+        if v is None:
+            return [None] * len(slices)
+        frag = v.fragment
+        return [frag(s) for s in slices]
+
+    def _view_token(self, index: str, frame: str, view: str, slices,
+                    walked: Optional[list] = None) -> tuple[int, int]:
+        """The (uid, generation) of a leaf's view — (0, 0) while the
+        view does not exist — read BEFORE anything is resolved under
+        it. Where ``slices`` is a route record's list the record's
+        entry for the view (its fragment list under that token) is
+        confirmed, or resolved again by the walk; ``walked`` then
+        gains one entry: True where an entry's token had moved."""
+        v = self.holder.view(index, frame, view)
+        if v is None:
+            return (0, 0)
+        tok = (v.uid, v.generation)
+        route = getattr(slices, "route", None)
+        if route is None:
+            return tok
+        ent = route["views"].get((frame, view))
+        if ent is not None and ent[0] == tok:
+            return tok
+        if walked is not None:
+            walked.append(ent is not None)
+        route["views"][(frame, view)] = (
+            tok, self._leaf_frags(index, frame, view, slices))
+        return tok
+
+    def _leaf_cache_key(self, mesh, index: str, leaf: tuple, slices,
+                        walked: Optional[list] = None) -> tuple:
+        """Residency key of one leaf slab, O(1) in the slice count: the
+        slice set by name and the view's token stand for what used to
+        be a (uid, generation) pair a fragment."""
         from .parallel import mesh as mesh_mod
         frame, view, row_id = leaf
-        frags = [self.holder.fragment(index, frame, view, s)
-                 for s in slices]
-        gens = tuple((f.device.uid, f.device.generation) if f is not None
-                     else (0, 0) for f in frags)
-        n_dev = mesh.shape[mesh_mod.AXIS_SLICES]
+        uid, gen = self._view_token(index, frame, view, slices, walked)
         return ("leaf", id(self.holder), index, frame, view, row_id,
-                slices, gens, n_dev)
+                self._slices_key(slices), uid, gen,
+                mesh.shape[mesh_mod.AXIS_SLICES])
 
-    def _cold_leaves(self, mesh, index: str, leaves: list[tuple],
-                     slices: list[int]) -> int:
-        """How many leaf slabs an upcoming dispatch would have to pack
-        and upload (i.e. are not in the device residency cache)."""
+    def _leaf_lookup(self, mesh, index: str, leaves: list[tuple],
+                     slices) -> tuple[list, list, int]:
+        """(keys, resident arrays, cold count) of an upcoming dispatch:
+        each leaf's key built once and looked up once, in one hold of
+        the residency cache's lock and without touching its LRU order —
+        a leaf that is absent (None) counts as cold for _device_pays
+        and is built only if the device leg is taken
+        (_leaf_device_arrays). This is also where a read's route is
+        counted (``routeMemo``): a hit when ``slices`` is a route
+        record's list and every leaf's view was reused from it."""
         from .parallel.residency import device_cache
-        cache = device_cache()
-        t = tuple(slices)
         with sched_context.stage("route"):
-            return sum(1 for leaf in leaves
-                       if not cache.contains(
-                           self._leaf_cache_key(mesh, index, leaf, t)))
+            walked: list = []
+            keys = [self._leaf_cache_key(mesh, index, leaf, slices, walked)
+                    for leaf in leaves]
+            found = device_cache().lookup(keys)
+            routed = getattr(slices, "route", None) is not None
+            self._route_note("hits" if routed and not walked
+                             else "misses", invalidated=sum(walked))
+            return keys, found, found.count(None)
+
+    def _leaf_device_arrays(self, mesh, index: str, leaves: list[tuple],
+                            slices, looked=None) -> list:
+        """Device-resident [bucket(n_slices), words] slab of every leaf
+        row, held in the budgeted HBM cache (parallel.residency.
+        leaf_slab — bucket-padded so the program catalogue's compiled
+        shapes stay stable as slice count grows). ``looked`` is the
+        (keys, found) of the leg's own _leaf_lookup, so that a leaf is
+        looked up once; what it found is reported as hits now that the
+        leg is taken, and only the absent slabs resolve fragments.
+
+        The key embeds the backing view's (uid, generation), so
+        writes/reopens stop the entry being referenced and it ages out
+        of the LRU — repeated Count/TopN over a stable index re-use the
+        upload instead of re-packing + re-transferring per query."""
+        from .parallel import residency
+        with sched_context.stage("route"):  # a miss nests pack, upload
+            keys, found = (looked if looked is not None else
+                           self._leaf_lookup(mesh, index, leaves,
+                                             slices)[:2])
+            residency.device_cache().touch(
+                [k for k, a in zip(keys, found) if a is not None])
+            arrs = list(found)
+            for i, arr in enumerate(arrs):
+                if arr is None:
+                    frame, view, row_id = leaves[i]
+                    arrs[i] = residency.leaf_slab(
+                        mesh, keys[i],
+                        self._key_frags(index, frame, view, slices,
+                                        keys[i]), row_id)
+            return arrs
+
+    def _key_frags(self, index: str, frame: str, view: str, slices,
+                   key: tuple):
+        """The lazy fragment list of a slab or candidate block under
+        residency ``key`` (only a miss resolves it): the route
+        record's, where it holds one resolved under the key's view
+        token (a list resolved after the token was read shows every
+        fragment the token vouches for), else the walk."""
+        def frags():
+            route = getattr(slices, "route", None)
+            if route is not None:
+                ent = route["views"].get((frame, view))
+                if ent is not None and ent[0] == key[-3:-1]:
+                    return ent[1]
+            return self._leaf_frags(index, frame, view, slices)
+        return frags
 
     def _pack_leaf_block(self, index: str, leaves: list[tuple],
                          slices: list[int]) -> np.ndarray:
@@ -2357,25 +2575,6 @@ class Executor:
                 if frag is not None:
                     frag.pack_row(row_id, out=block[li, si])
         return block
-
-    def _leaf_device_array(self, mesh, index: str, leaf: tuple,
-                           slices: tuple[int, ...]):
-        """Device-resident [bucket(n_slices), words] slab for one PQL
-        leaf row, held in the budgeted HBM cache
-        (parallel.residency.leaf_slab — bucket-padded so the program
-        catalogue's compiled shapes stay stable as slice count grows).
-
-        The key embeds every backing fragment's (uid, generation), so
-        writes/reopens stop the entry being referenced and it ages out
-        of the LRU — repeated Count/TopN over a stable index re-use the
-        upload instead of re-packing + re-transferring per query."""
-        from .parallel import residency
-        frame, view, row_id = leaf
-        with sched_context.stage("route"):  # a miss nests pack, upload
-            frags = [self.holder.fragment(index, frame, view, s)
-                     for s in slices]
-            key = self._leaf_cache_key(mesh, index, leaf, slices)
-            return residency.leaf_slab(mesh, key, frags, row_id)
 
     # -- TopN (executor.go:271-396) ------------------------------------------
 
@@ -2452,7 +2651,7 @@ class Executor:
                     mesh.shape[mesh_mod.AXIS_SLICES])):
             return None
         rows_key = self._topn_rows_key(mesh, index, frame_name,
-                                       tuple(ids), tuple(slices))
+                                       tuple(ids), slices)
         cold = (0 if residency.device_cache().contains(rows_key)
                 else len(ids))
         if not self._device_pays(mesh, len(ids), len(slices),
@@ -2461,11 +2660,10 @@ class Executor:
         k = min(n, len(ids)) if n else len(ids)
         try:
             def run():
-                frags = [self.holder.fragment(index, frame_name,
-                                              VIEW_STANDARD, s)
-                         for s in slices]
                 rows_arr = residency.candidate_block(
-                    mesh, rows_key, frags, tuple(ids))
+                    mesh, rows_key,
+                    self._key_frags(index, frame_name, VIEW_STANDARD,
+                                    slices, rows_key), tuple(ids))
                 return mesh_mod.topn_topk_sharded(mesh, None, rows_arr,
                                                   [], k)
             counts, idxs = self._timed_device_leg(
@@ -3050,8 +3248,9 @@ class Executor:
             # streaming form re-packs it every query, so it is always
             # cold there. Leaf slabs add their own cold rows.
             rows_key = self._topn_rows_key(mesh, index, frame_name,
-                                           tuple(ids), tuple(slices))
-            cold = self._cold_leaves(mesh, index, leaves, slices)
+                                           tuple(ids), slices)
+            keys, found, cold = self._leaf_lookup(mesh, index, leaves,
+                                                  slices)
             if not (resident_ok and device_cache().contains(rows_key)):
                 cold += len(ids)
             if not self._device_pays(mesh, len(ids) + len(leaves),
@@ -3063,8 +3262,8 @@ class Executor:
                     if resident_ok:
                         return self._topn_exact_resident(
                             mesh, index, frame_name, expr, leaves,
-                            tuple(ids), tuple(slices), threshold,
-                            tanimoto, rows_key=rows_key)
+                            tuple(ids), slices, threshold,
+                            tanimoto, rows_key, (keys, found))
                     return mesh_mod.topn_exact(
                         mesh, expr,
                         self._pack_candidate_rows(index, frame_name,
@@ -3125,38 +3324,33 @@ class Executor:
         return rows
 
     def _topn_rows_key(self, mesh, index: str, frame_name: str,
-                       row_ids: tuple[int, ...],
-                       slices: tuple[int, ...]) -> tuple:
+                       row_ids: tuple[int, ...], slices) -> tuple:
+        """Residency key of a TopN candidate block: the leaf key's
+        shape over the frame's standard view, rows in place of a row."""
         from .parallel import mesh as mesh_mod
-        frags = [self.holder.fragment(index, frame_name, VIEW_STANDARD, s)
-                 for s in slices]
-        gens = tuple((f.device.uid, f.device.generation) if f is not None
-                     else (0, 0) for f in frags)
-        n_dev = mesh.shape[mesh_mod.AXIS_SLICES]
+        uid, gen = self._view_token(index, frame_name, VIEW_STANDARD,
+                                    slices)
         return ("topnrows", id(self.holder), index, frame_name, row_ids,
-                slices, gens, n_dev)
+                self._slices_key(slices), uid, gen,
+                mesh.shape[mesh_mod.AXIS_SLICES])
 
     def _topn_exact_resident(self, mesh, index: str, frame_name: str,
                              expr, leaves: list[tuple],
-                             row_ids: tuple[int, ...],
-                             slices: tuple[int, ...],
-                             threshold: int = 1,
-                             tanimoto: int = 0,
-                             rows_key: Optional[tuple] = None
-                             ) -> list[int]:
+                             row_ids: tuple[int, ...], slices,
+                             threshold: int, tanimoto: int,
+                             rows_key: tuple, looked) -> list[int]:
         """TopN exact counts with the candidate block and leaf slabs
         device-resident (budgeted HBM cache) — repeat TopN queries skip
         the per-query pack + upload entirely. threshold>1 / tanimoto
         engage the per-slice pruning program (mesh.topn_filtered_sharded)."""
         from .parallel import mesh as mesh_mod
         from .parallel import residency
-        frags = [self.holder.fragment(index, frame_name, VIEW_STANDARD, s)
-                 for s in slices]
-        key = rows_key if rows_key is not None else self._topn_rows_key(
-            mesh, index, frame_name, row_ids, slices)
-        rows_arr = residency.candidate_block(mesh, key, frags, row_ids)
-        leaf_arrays = [self._leaf_device_array(mesh, index, leaf, slices)
-                       for leaf in leaves]
+        rows_arr = residency.candidate_block(
+            mesh, rows_key,
+            self._key_frags(index, frame_name, VIEW_STANDARD, slices,
+                            rows_key), row_ids)
+        leaf_arrays = self._leaf_device_arrays(mesh, index, leaves,
+                                               slices, looked)
         if threshold > 1 or tanimoto > 0:
             return mesh_mod.topn_filtered_sharded(
                 mesh, expr, rows_arr, leaf_arrays,
@@ -4079,7 +4273,7 @@ class Executor:
                 opt.missing_slices = []
             missing = opt.missing_slices
 
-        def submit(nodes, slices):
+        def submit(nodes, slices, groups=None):
             nonlocal processed
             before = len(missing) if missing is not None else 0
             # Elastic resize, migrating phase: moving slices fan out as
@@ -4103,8 +4297,10 @@ class Executor:
                         if ctx is not None:
                             ctx.add_leg("double-read", len(group))
                     slices = [s for s in slices if s not in moved]
-            for node, node_slices in self._slices_by_node(
-                    nodes, index, slices, missing=missing):
+            if groups is None or self.cluster.resize is not None:
+                groups = self._slices_by_node(nodes, index, slices,
+                                              missing=missing)
+            for node, node_slices in groups:
                 fut = pool.submit(self._mapper_node, node, index, c,
                                   node_slices, opt, map_fn, reduce_fn,
                                   local_fn)
@@ -4126,8 +4322,13 @@ class Executor:
         span = sched_context.span("map_reduce", call=c.name,
                                   slices=len(slices))
         span.__enter__()
+        # The first fan-out of a whole-index read takes its grouping
+        # from the route record; a failover re-map walks.
+        route = (getattr(slices, "route", None) if not opt.remote
+                 else None)
         try:
-            submit(nodes, slices)
+            submit(nodes, slices,
+                   route["groups"] if route is not None else None)
             while processed < len(slices):
                 if ctx is None:
                     done, _ = wait(list(futures),

@@ -104,6 +104,13 @@ class FaultManager:
             return True
         return self.breakers.would_allow(host)
 
+    def steering(self) -> bool:
+        """True while this manager may route a read away from its
+        placement-order owner: a circuit that is not closed, or hedging
+        configured. The executor reuses a memoised slice→node grouping
+        only while this is False (one attr read each)."""
+        return self.breakers.not_closed > 0 or self.hedge_s > 0
+
     def order_nodes(self, nodes: list, local: str = "") -> list:
         """Replica owners ordered for placement: breaker-allowed nodes
         first (stable within each class, so equal-health clusters keep

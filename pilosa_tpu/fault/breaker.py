@@ -78,6 +78,9 @@ class BreakerBoard:
         self._clock = clock or time.monotonic
         self._mu = threading.Lock()
         self._peers: dict[str, _Breaker] = {}
+        # How many peers' circuits are not closed: the O(1) answer to
+        # "is anything steering reads away" (FaultManager.steering).
+        self.not_closed = 0
 
     def _peer(self, host: str) -> _Breaker:
         b = self._peers.get(host)
@@ -91,6 +94,8 @@ class BreakerBoard:
                     reason: str = "") -> None:
         if b.state == to:
             return
+        self.not_closed += ((to != STATE_CLOSED)
+                            - (b.state != STATE_CLOSED))
         b.state = to
         b.last_reason = reason
         obs_metrics.BREAKER_STATE.labels(host).set(_STATE_GAUGE[to])

@@ -14,6 +14,7 @@ import threading
 from typing import Callable, Optional
 
 from .. import SLICE_WIDTH
+from ..parallel.residency import next_uid
 from ..storage import cache as cache_mod
 from ..storage.fragment import Fragment
 from ..utils import logger as logger_mod
@@ -65,6 +66,26 @@ class View:
         self.fragments: dict[int, Fragment] = {}
         self._max_slice = 0
         self._mu = threading.RLock()
+        # The view-level mutation token (docs/OBSERVABILITY.md "The
+        # view token"): ``generation`` moves whenever the resident
+        # picture of any fragment of this view may have — wherever a
+        # fragment's own device generation moves, and when a fragment
+        # is created, opened, closed or snapshotted; a view dropped and
+        # recreated is a new object with a fresh ``uid``. Residency
+        # keys and the executor's route records embed the pair, so a
+        # read validates a whole view with one compare.
+        self.uid = next_uid()
+        self.generation = 0
+        self._token_mu = threading.Lock()
+
+    def bump(self) -> None:
+        """Move the token. Writers call this AFTER changing the data
+        (or the fragment map) and BEFORE acknowledging; readers read
+        ``generation`` BEFORE the data. Under the lock, so that racing
+        writers of two fragments cannot store the counter backwards —
+        a reader compares for equality and needs it monotonic."""
+        with self._token_mu:
+            self.generation += 1
 
     # -- lifecycle
 
@@ -94,20 +115,24 @@ class View:
                 frag.open()
                 self.fragments[slice] = frag
             self._max_slice = max(self.fragments, default=0)
+            self.bump()  # after the map holds every opened fragment
 
     def close(self) -> None:
         with self._mu:
             for frag in self.fragments.values():
                 frag.close()
             self.fragments.clear()
+            self.bump()  # after the map is empty
 
     def _new_fragment(self, slice: int) -> Fragment:
-        return Fragment(self.fragment_path(slice), self.index, self.frame,
+        frag = Fragment(self.fragment_path(slice), self.index, self.frame,
                         self.name, slice, cache_type=self.cache_type,
                         cache_size=self.cache_size,
                         row_attr_store=self.row_attr_store,
                         stats=self.stats.with_tags(f"slice:{slice}"),
                         logger=self.logger, quarantine=self.quarantine)
+        frag.device.owner = self
+        return frag
 
     # -- fragments
 
@@ -127,6 +152,8 @@ class View:
                 if self.on_create_slice is not None:
                     self.on_create_slice(slice)
             self.fragments[slice] = frag
+            self.bump()  # after the map holds it: a walk that missed
+            # the fragment read the token before this bump
             self.stats.count("maxSlice", 1)
             return frag
 

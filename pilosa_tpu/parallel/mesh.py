@@ -499,10 +499,16 @@ def _densify_sharded_fn(mesh: Mesh, lead_shape: tuple, subs: int,
         out = pk.densify_pallas(flat_l, flat_v, n_words, interpret)
         return out.reshape(lanes.shape[:-2] + (n_words,))
 
+    # The output names its sharding as ``shard_slices`` does: on a
+    # one-device mesh jit would otherwise hand back ``P()``, unequal to
+    # a device_put slab's ``P(slices)``, and every program re-specialised
+    # for each mix of slab origins among its leaves (2^k a k-leaf Count,
+    # ~2 ms each, most of compiles_in_window).
     return _finalize_program(jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(AXIS_SLICES), P(AXIS_SLICES)),
-        out_specs=P(AXIS_SLICES), check_vma=False), "densify")
+        out_specs=P(AXIS_SLICES), check_vma=False), "densify",
+        out_shardings=_slice_sharding(mesh))
 
 
 @_fair_dispatch

@@ -16,9 +16,15 @@ so device state is an explicit, *budgeted* cache:
   (feeds block builds and mesh uploads; invalidated per row by writes).
 
 Staleness is handled by keys, not callbacks: every cached block's key
-embeds the owning fragments' ``(uid, generation)`` pairs — writes bump
-the generation, fragment reopen mints a fresh uid — so stale entries
-simply stop being referenced and age out of the LRU.
+embeds the owning VIEW's ``(uid, generation)`` pair (models.view.View)
+— every write-invalidation of any of the view's fragments bumps the
+generation where the fragment's own generation bumps, a fragment
+created, opened, closed or snapshotted bumps it too, and a view that is
+dropped and recreated mints a fresh uid — so stale entries simply stop
+being referenced and age out of the LRU. The key is O(1) in the slice
+count; invalidation is as coarse as it was for every query over the
+whole index (a write to slice s always retired every slab whose slice
+set held s).
 
 Upload layout: the globally-sharded slab builders (``leaf_slab``,
 ``candidate_block``) pad the slice axis to its canonical bucket
@@ -118,6 +124,27 @@ class DeviceBlockCache:
         with self._mu:
             return key in self._lru
 
+    def lookup(self, keys: list) -> list:
+        """The resident array of every key (None where absent) in ONE
+        lock hold, WITHOUT touching LRU order or the hit counters: the
+        routing cost model asks what a leg would have to upload before
+        the leg is taken, and a vetoed leg must leave no trace. A leg
+        that is taken reports what it used with ``touch``."""
+        with self._mu:
+            get = self._lru.get
+            return [get(k) for k in keys]
+
+    def touch(self, keys: list) -> None:
+        """Count a hit for, and refresh the LRU position of, every key
+        a taken leg served from ``lookup`` (one lock hold). A key
+        evicted in between still counts: the leg held its array."""
+        with self._mu:
+            lru = self._lru
+            for k in keys:
+                if k in lru:
+                    lru.move_to_end(k)
+            self.hits += len(keys)
+
     def clear(self) -> None:
         with self._mu:
             self._lru.clear()
@@ -165,23 +192,27 @@ def _bucketed_slices(mesh, n_slices: int) -> int:
                                  mesh.shape[mesh_mod.AXIS_SLICES])
 
 
-def leaf_slab(mesh, key: tuple, frags: list, row_id: int) -> jax.Array:
+def leaf_slab(mesh, key: tuple, frags, row_id: int) -> jax.Array:
     """Device-resident ``[bucket(n_slices), words]`` slab of one PQL
     leaf row across ``frags`` (one fragment per slice, None = absent =
-    zero words), globally sharded over the slice axis and held in the
-    budgeted HBM cache under ``key``.
+    zero words; a list, or a callable that resolves it — only a miss
+    needs the fragments), globally sharded over the slice axis and held
+    in the budgeted HBM cache under ``key``.
 
-    The caller owns the key contract (executor embeds every backing
-    fragment's (uid, generation), so writes/reopens age entries out of
-    the LRU); this builder owns the transfer: sparse-gate → bucketed
-    sparse upload + on-device densify when it wins, dense host pack
-    otherwise — always at the bucket-padded, program-stable shape."""
+    The caller owns the key contract (executor embeds the backing
+    view's (uid, generation), read BEFORE the fragments are resolved,
+    so writes/reopens age entries out of the LRU); this builder owns
+    the transfer: sparse-gate → bucketed sparse upload + on-device
+    densify when it wins, dense host pack otherwise — always at the
+    bucket-padded, program-stable shape."""
     from . import mesh as mesh_mod
 
-    def build():
+    def build(frags=frags):
         # A residency miss: ``pack`` is roaring → words on the host,
         # ``upload`` the transfer (and the on-device densify).
         from ..ops import packed
+        if callable(frags):
+            frags = frags()
         n = _bucketed_slices(mesh, len(frags))
         mode = mesh_mod.densify_mode()
         with sched_context.stage("pack"):
@@ -206,16 +237,18 @@ def leaf_slab(mesh, key: tuple, frags: list, row_id: int) -> jax.Array:
     return device_cache().get_or_build(key, build)
 
 
-def candidate_block(mesh, key: tuple, frags: list,
+def candidate_block(mesh, key: tuple, frags,
                     row_ids: tuple) -> jax.Array:
     """Device-resident ``[bucket(n_slices), n_rows, words]`` TopN
-    candidate block (same key/staleness contract as ``leaf_slab``),
-    bucket-padded and slice-sharded — repeat TopN queries skip the
-    per-query pack + upload entirely."""
+    candidate block (same key/staleness contract and lazy ``frags`` as
+    ``leaf_slab``), bucket-padded and slice-sharded — repeat TopN
+    queries skip the per-query pack + upload entirely."""
     from . import mesh as mesh_mod
 
-    def build():
+    def build(frags=frags):
         from ..ops import packed
+        if callable(frags):
+            frags = frags()
         n = _bucketed_slices(mesh, len(frags))
         # Extract once as sparse (word idx, value) pairs; the gate
         # then picks the transfer representation — bucketed sparse +
@@ -251,13 +284,22 @@ def candidate_block(mesh, key: tuple, frags: list,
     return device_cache().get_or_build(key, build)
 
 
+def next_uid() -> int:
+    """A process-unique id for a residency token (a fragment's
+    DeviceRowCache, a View): one counter, so no two ever alias."""
+    return next(_uid_counter)
+
+
 class DeviceRowCache:
     """Per-fragment residency state: host packed-row LRU + the
-    (uid, generation) pair that keys this fragment's entries in the
-    shared ``DeviceBlockCache``."""
+    fragment's own (uid, generation) pair (the cluster generation map
+    publishes it). ``owner`` is the fragment's View (None for a bare
+    library fragment): every bump here bumps the view's generation too
+    — the token the shared ``DeviceBlockCache``'s keys embed."""
 
     def __init__(self, max_rows: int = DEFAULT_MAX_ROWS):
         self.max_rows = max_rows
+        self.owner = None   # View._new_fragment sets it
         # Host-side packed words, feeding the device row blocks and the
         # executor's mesh block builds (which stack rows across
         # fragments host-side before one sharded device_put).
@@ -266,7 +308,7 @@ class DeviceRowCache:
         # generation bumps on every write-invalidation; uid is unique
         # per DeviceRowCache instance so a reopened fragment at
         # generation 0 can never alias a prior instance's entries.
-        self.uid = next(_uid_counter)
+        self.uid = next_uid()
         self.generation = 0
 
     # -- single rows
@@ -289,9 +331,24 @@ class DeviceRowCache:
             self._host_rows.popitem(last=False)
         return words
 
+    def bump(self) -> None:
+        """The fragment's resident picture may have changed. The data
+        changed BEFORE this call and the write is acknowledged AFTER
+        it: a reader that finds the token unmoved has seen the data."""
+        self.generation += 1
+        self.bump_owner()
+
+    def bump_owner(self) -> None:
+        """Move the view's token alone: the fragment's lifecycle
+        (opened, storage swapped by a snapshot) without a change of
+        its bits, which the cluster generation map must not see."""
+        owner = self.owner
+        if owner is not None:
+            owner.bump()
+
     def invalidate_row(self, row_id: int) -> None:
         self._host_rows.pop(row_id, None)
-        self.generation += 1
+        self.bump()
 
     def invalidate_rows(self, row_ids) -> None:
         """Batch invalidation: one generation bump for the whole write
@@ -299,8 +356,8 @@ class DeviceRowCache:
         pop = self._host_rows.pop
         for rid in row_ids:
             pop(rid, None)
-        self.generation += 1
+        self.bump()
 
     def invalidate_all(self) -> None:
         self._host_rows.clear()
-        self.generation += 1
+        self.bump()

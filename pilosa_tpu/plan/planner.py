@@ -162,7 +162,12 @@ class Planner:
         self._seen: OrderedDict[str, int] = OrderedDict()
         self._estimates: OrderedDict[tuple, tuple] = OrderedDict()
         # Finished-plan memo: key -> {planned, roots, fingerprint,
-        # decisions, deps, cse_nodes} (plan_query_cached).
+        # decisions, deps, cse_nodes} (plan_query_cached). The
+        # executor's route records live here too, one an index under
+        # ("route", index) (memo_get / memo_put / memo_drop): what a
+        # read resolves once per generation — the plan of a call shape,
+        # the route of a slice set — is one bounded LRU under one
+        # validity rule, a compare of view tokens.
         self._plans: OrderedDict[tuple, dict] = OrderedDict()
         # Decision roll-up for the blackbox / debug snapshot.
         self.decision_totals: dict[str, int] = {}
@@ -177,13 +182,13 @@ class Planner:
         """Plan a batch of read calls. Returns (rewritten clones, the
         populated record). Caller is responsible for gating (write
         queries and disabled planning never reach here). ``deps``, when
-        given, collects the (frame, view, fragment-epoch) facts the
+        given, collects the (frame, view, view-token) facts the
         plan's estimates rest on — the memo validity set."""
         t0 = time.perf_counter()
         if record is None:
             record = PlanRecord(fingerprint_calls(calls))
         idx = self.holder.index(index)
-        slices = tuple(int(s) for s in slices)
+        slices = tuple(map(int, slices))
         planned: list[Call] = []
         for call in calls:
             c = call.clone()
@@ -198,29 +203,32 @@ class Planner:
         return planned, record
 
     def plan_query_cached(self, index: str, calls: list[Call], slices,
-                          all_local: bool = True, node: str = ""
+                          all_local: bool = True, node: str = "",
+                          slices_key: Optional[tuple] = None
                           ) -> tuple[list[Call], PlanRecord]:
         """``plan_query`` behind a bounded memo: a repeated query (the
         hot shape the PR-9 caches serve) reuses its finished plan
         instead of re-walking estimation and fingerprinting, so
         planning amortizes to a key build plus a validity sweep.
+        ``slices_key`` is the caller's O(1) name for the slice set
+        (the executor's route record has one); absent, the slices
+        themselves key the entry.
 
         Safety: every entry carries the exact facts its proofs rest on
-        — frame/view identity and per-fragment mutation epochs,
-        including PROVABLY ABSENT fragments/views (a fragment appearing
-        breaks an emptiness proof as surely as a write). Any mismatch
-        discards the entry and replans, so a memoized short-circuit can
-        never outlive the emptiness it proved. Plan NODES are shared
-        across hits; the per-query PlanRecord (actuals, stitched legs)
-        is always fresh."""
-        slices = tuple(int(s) for s in slices)
+        — frame/view identity and each view's mutation token (any
+        write to, and any fragment appearing in or leaving, the view
+        moves it: a fragment appearing breaks an emptiness proof as
+        surely as a write), including PROVABLY ABSENT views. Any
+        mismatch discards the entry and replans, so a memoized
+        short-circuit can never outlive the emptiness it proved. Plan
+        NODES are shared across hits; the per-query PlanRecord
+        (actuals, stitched legs) is always fresh."""
+        if slices_key is None:
+            slices_key = tuple(map(int, slices))
         try:
             key = (index, tuple(_memo_call_key(c) for c in calls),
-                   slices, bool(all_local))
-            with self._mu:
-                ent = self._plans.get(key)
-                if ent is not None:
-                    self._plans.move_to_end(key)
+                   slices_key, bool(all_local))
+            ent = self.memo_get(key)
         except TypeError:
             # Unhashable literal somewhere in the tree — plan uncached.
             key = ent = None
@@ -251,32 +259,47 @@ class Planner:
                    "fingerprint": rec.fingerprint,
                    "decisions": rec.decision_summary(),
                    "deps": deps, "cse_nodes": cse_nodes, "hits": 0}
-            with self._mu:
-                self._plans[key] = ent
-                while len(self._plans) > _PLAN_MEMO_ENTRIES:
-                    self._plans.popitem(last=False)
+            self.memo_put(key, ent)
         return planned, rec
+
+    # -- the memo's three verbs (plans here, routes from the executor) -------
+
+    def memo_get(self, key: tuple) -> Optional[dict]:
+        with self._mu:
+            ent = self._plans.get(key)
+            if ent is not None:
+                self._plans.move_to_end(key)
+        return ent
+
+    def memo_put(self, key: tuple, ent: dict) -> None:
+        with self._mu:
+            self._plans[key] = ent
+            while len(self._plans) > _PLAN_MEMO_ENTRIES:
+                self._plans.popitem(last=False)
+
+    def memo_drop(self, key: tuple, ent: dict) -> None:
+        """Forget ``ent`` (and only it: a racing reader may already
+        have stored its successor under the key)."""
+        with self._mu:
+            if self._plans.get(key) is ent:
+                del self._plans[key]
 
     def _deps_valid(self, index: str, deps) -> bool:
         """True when every fact a memoized plan depends on still
         holds. Identity checks (``is``) catch drop-and-recreate, not
-        just mutation."""
+        just mutation; one token compare a view stands for every
+        fragment of it."""
         idx = self.holder.index(index)
         if idx is None:
             return False
         try:
             for d in deps:
                 kind = d[0]
-                if kind == "frag":
-                    _, view, s, epoch = d
-                    frag = view.fragments.get(s)
-                    cur = (None if frag is None
-                           else getattr(frag, "_epoch", 0))
-                    if cur != epoch:
-                        return False
-                elif kind == "view":
-                    _, frame, view = d
+                if kind == "view":
+                    _, frame, view, gen = d
                     if frame.views.get("standard") is not view:
+                        return False
+                    if view is not None and view.generation != gen:
                         return False
                 else:  # "frame"
                     _, name, frame = d
@@ -473,16 +496,18 @@ class Planner:
         node.key = str(call)
         if deps is not None:
             deps.append(("frame", frame_name, frame))
-            # A view APPEARING breaks a proof ("no view" = exact 0).
-            deps.append(("view", frame, view))
+            # A view APPEARING breaks a proof ("no view" = exact 0);
+            # its token, read BEFORE the estimate walks its fragments,
+            # stands for every fragment's epoch and for the absent ones.
+            deps.append(("view", frame, view,
+                         view.generation if view is not None else 0))
         est, exact = self._estimate_row(view, row_id, slices,
-                                        all_local, deps)
+                                        all_local)
         node.est_rows, node.exact = est, exact
         return node
 
     def _estimate_row(self, view, row_id: int, slices,
-                      all_local: bool,
-                      deps: Optional[list] = None) -> tuple[int, bool]:
+                      all_local: bool) -> tuple[int, bool]:
         """Estimated bits for one (frame, standard view, row) over
         ``slices``. Exact only when every slice was enumerated against
         a local fragment (or a provably absent one)."""
@@ -491,31 +516,25 @@ class Planner:
         if len(slices) > EXACT_SLICES:
             step = max(1, len(slices) // ESTIMATE_SAMPLES)
             sample = slices[::step][:ESTIMATE_SAMPLES]
-            total, _ = self._sum_slices(view, row_id, sample, False,
-                                        deps)
+            total, _ = self._sum_slices(view, row_id, sample, False)
             scaled = int(total * len(slices) / max(len(sample), 1))
             return (scaled, False)
-        return self._sum_slices(view, row_id, slices, all_local, deps)
+        return self._sum_slices(view, row_id, slices, all_local)
 
     def _sum_slices(self, view, row_id: int, slices,
-                    all_local: bool,
-                    deps: Optional[list] = None) -> tuple[int, bool]:
+                    all_local: bool) -> tuple[int, bool]:
         total = 0
         exact = all_local
         for s in slices:
             frag = view.fragments.get(s)
             if frag is None:
                 # Locally absent fragment = 0 bits — exact only when
-                # this node owns every slice of the query. The absence
-                # itself is a memo dependency: a fragment appearing
-                # voids the proof.
-                if deps is not None:
-                    deps.append(("frag", view, s, None))
+                # this node owns every slice of the query. (A fragment
+                # appearing voids the proof: it moves the view's token,
+                # which the caller recorded as the memo dependency.)
                 continue
             key = (id(view), row_id, s)
             epoch = getattr(frag, "_epoch", 0)
-            if deps is not None:
-                deps.append(("frag", view, s, epoch))
             with self._mu:
                 hit = self._estimates.get(key)
                 if hit is not None and hit[0] == epoch:

@@ -587,6 +587,11 @@ class Handler:
         vetoes = getattr(self.executor, "cost_vetoes", None)
         if vetoes is not None:
             snap["costModelVetoes"] = vetoes
+        route_memo = getattr(self.executor, "route_memo", None)
+        if route_memo is not None:
+            # Device-lowered reads by how their route was found: reused
+            # whole from its record, or walked (docs/OBSERVABILITY.md).
+            snap["routeMemo"] = dict(route_memo)
         model = getattr(self.executor, "cost_model", None)
         if model is not None:
             snap["costModel"] = {"syncS": model.cal.sync_s,

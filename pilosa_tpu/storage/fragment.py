@@ -261,6 +261,7 @@ class Fragment:
                     self.tier_state = "blob"
                     self.storage = None
                     self._open = True
+                    self.device.bump_owner()
                     return
             self._open_storage_quarantining(verify=True)
             if not self.quarantined and os.path.exists(
@@ -278,6 +279,9 @@ class Fragment:
                     site="open")
             self._open_cache()
             self._open = True
+            # (Re)opened: whatever a reader resolved against the view
+            # while this fragment was closed is resolved again.
+            self.device.bump_owner()
 
     def _open_storage_quarantining(self, verify: bool = False) -> None:
         """_open_storage, but a file whose bytes contradict their
@@ -1121,6 +1125,10 @@ class Fragment:
         the fragment is visibly broken rather than quietly
         unlogged."""
         self._snapshot_n += 1
+        # The view token moves with every storage swap (bits unchanged,
+        # so the fragment's own generation stays): a route or a slab
+        # resolved before the swap is re-resolved after it.
+        self.device.bump_owner()
         if self._snapshot_n % _REMAP_EVERY == 0:
             self._close_storage()
             os.replace(tmp, self.path)

@@ -93,6 +93,39 @@ class TestQueryStep:
         assert list(vals) == sorted(want, reverse=True)[:3]
 
 
+class TestSlabOrigins:
+    @pytest.mark.parametrize("n_devices", [1, 8])
+    def test_one_specialisation_whatever_the_upload(self, n_devices):
+        """A slab uploaded sparse (the densify program's output) and one
+        uploaded dense (device_put) name the same sharding, so a count
+        program compiles once for a shape — not once a mix of origins
+        among its leaves. On a one-device mesh (one chip) jit hands an
+        unnamed output back as ``P()``: the 2^k ~2 ms re-specialisations
+        that were most of ``compiles_in_window``."""
+        from pilosa_tpu.ops import packed
+        mesh = mesh_mod.make_mesh(n_devices)
+        n = 8
+        dense = np.zeros((n, packed.WORDS_PER_SLICE), dtype=np.uint32)
+        dense[:, :4] = 7
+        pairs = [(np.array([1, 2, 3], dtype=np.int64),
+                  np.array([5, 6, 7], dtype=np.uint32))] * n
+        a = mesh_mod.shard_slices(mesh, dense)
+        b = mesh_mod.densify_sharded(
+            mesh, *packed.bucket_prepared(
+                pairs, packed.WORDS_PER_SLICE // 128), interpret=True)
+        assert a.sharding == b.sharding
+        expr = ("and", ("leaf", 0), ("leaf", 1))
+        want_ab = _popcount(np.asarray(a) & np.asarray(b))
+        assert mesh_mod.count_expr_sharded(mesh, expr, [a, a]) \
+            == _popcount(np.asarray(a))
+        compiled = mesh_mod.compile_stats()["firstCalls"]
+        for leaves in ([a, b], [b, a], [b, b]):
+            got = mesh_mod.count_expr_sharded(mesh, expr, leaves)
+            assert got == (want_ab if leaves[0] is not leaves[1]
+                           else _popcount(np.asarray(b)))
+        assert mesh_mod.compile_stats()["firstCalls"] == compiled
+
+
 class TestCompileCache:
     def test_one_rule_for_the_directory(self, monkeypatch, tmp_path,
                                         caplog):
