@@ -223,6 +223,11 @@ class Executor:
         self.cost_vetoes = 0
         self._mesh = None  # lazy: built on first device-batched call
         self._mesh_failed_until = None  # backoff after backend failure
+        # /debug/vars.mesh: how often make_mesh gave this executor its
+        # mesh, and how often it failed (each failure serves from the
+        # host for _MESH_RETRY_S).
+        self.mesh_builds = 0
+        self.mesh_failures = 0
         # Device-fallback observability (a real kernel bug would
         # otherwise silently demote every query to the host path):
         # counted per executor, surfaced via stats + one-shot warning.
@@ -396,16 +401,36 @@ class Executor:
             try:
                 from .parallel import mesh as mesh_mod
                 self._mesh = mesh_mod.make_mesh()
+                self.mesh_builds += 1
                 self._mesh_failed_until = None
                 # Failure is cyclic under retry (outage → recovery →
                 # outage); re-arm the one-shot log for the next one.
                 self._fallback_warned = False
             except Exception as e:  # noqa: BLE001 - backend unavailable
+                self.mesh_failures += 1
                 self._mesh_failed_until = (time.monotonic()
                                            + self._MESH_RETRY_S)
                 self._note_device_fallback("make_mesh", e)
                 return None
         return self._mesh
+
+    def mesh_state(self) -> Optional[dict]:
+        """/debug/vars.mesh: the one mesh this executor's device
+        programs run on — its (rows, slices) shape and device count, how
+        often it was built and how often building it failed, and the
+        device programs dispatched so far (process-wide: parallel.mesh
+        counts them). None before the first device call asked for a
+        mesh."""
+        mesh = self._mesh
+        if mesh is None and not self.mesh_failures:
+            return None
+        from .parallel import mesh as mesh_mod
+        shape = None if mesh is None else list(mesh.devices.shape)
+        return {"shape": shape,
+                "devices": 0 if mesh is None else int(mesh.devices.size),
+                "builds": self.mesh_builds,
+                "failures": self.mesh_failures,
+                "programsRun": mesh_mod.programs_run()}
 
     # -- entry point (executor.go:62-143) ------------------------------------
 
@@ -4322,6 +4347,8 @@ class Executor:
         span = sched_context.span("map_reduce", call=c.name,
                                   slices=len(slices))
         span.__enter__()
+        cost = ctx.cost if ctx is not None else None
+        programs_before = cost.device_programs if cost is not None else 0
         # The first fan-out of a whole-index read takes its grouping
         # from the route record; a failover re-map walks.
         route = (getattr(slices, "route", None) if not opt.remote
@@ -4400,6 +4427,9 @@ class Executor:
                         result = reduce_fn(result, r)
                     processed += len(node_slices)
         finally:
+            if cost is not None and cost.device_programs > programs_before:
+                # How wide a mesh this fan-out's device programs ran on.
+                span.tag(mesh_devices=cost.mesh_devices)
             span.__exit__(None, None, None)
             # On an error path, drain what we started: the pool is
             # shared with other queries, and the old per-query pool's

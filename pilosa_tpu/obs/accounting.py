@@ -12,9 +12,10 @@ work at container granularity, not just wall-clock:
 - **word-equivalents scanned** (1024 words per bitmap container
   operand, ``ceil(len/64)`` per array operand);
 - **bits written** (fragment mutate/import paths);
-- **device programs dispatched + device bytes** (parallel/mesh entry
-  points) and **XLA compile seconds** attributed to the query whose
-  first call paid the trace+compile;
+- **device programs dispatched + device bytes**, and the width of the
+  widest mesh one of them ran on (parallel/mesh entry points), and
+  **XLA compile seconds** attributed to the query whose first call
+  paid the trace+compile;
 - **RPC bytes in/out per peer** (cluster/client fan-out legs);
 - **queue wait** rides the context's existing ``admission`` stage.
 
@@ -74,8 +75,8 @@ class QueryCost:
 
     __slots__ = ("node", "container_ops", "words_scanned",
                  "bits_written", "device_programs", "device_bytes",
-                 "compile_s", "wal_wait_s", "result_cache_hits", "rpc",
-                 "children", "_mu")
+                 "mesh_devices", "compile_s", "wal_wait_s",
+                 "result_cache_hits", "rpc", "children", "_mu")
 
     def __init__(self, node: str = ""):
         self.node = node
@@ -84,6 +85,9 @@ class QueryCost:
         self.bits_written = 0
         self.device_programs = 0
         self.device_bytes = 0
+        # The widest mesh (device count) any of this query's device
+        # programs ran on; 0 while none ran.
+        self.mesh_devices = 0
         self.compile_s = 0.0
         # Seconds this query's threads spent blocked in WAL group
         # commit (waiting for a leader's flush to cover their records)
@@ -110,9 +114,12 @@ class QueryCost:
     def note_bits_written(self, n: int) -> None:
         self.bits_written += n
 
-    def note_device_dispatch(self, nbytes: int = 0) -> None:
+    def note_device_dispatch(self, nbytes: int = 0,
+                             mesh_devices: int = 0) -> None:
         self.device_programs += 1
         self.device_bytes += nbytes
+        if mesh_devices > self.mesh_devices:
+            self.mesh_devices = mesh_devices
 
     def note_compile(self, seconds: float) -> None:
         self.compile_s += seconds
@@ -164,6 +171,8 @@ class QueryCost:
             "deviceBytes": self.device_bytes,
             "compileMs": round(self.compile_s * 1e3, 3),
         }
+        if self.mesh_devices:
+            out["meshDevices"] = self.mesh_devices
         if self.wal_wait_s:
             out["walWaitMs"] = round(self.wal_wait_s * 1e3, 3)
         if self.result_cache_hits:
@@ -193,6 +202,8 @@ class QueryCost:
             "deviceBytes": self.device_bytes,
             "compileMs": round(self.compile_s * 1e3, 3),
         }
+        if self.mesh_devices:
+            out["meshDevices"] = self.mesh_devices
         if self.wal_wait_s:
             out["walWaitMs"] = round(self.wal_wait_s * 1e3, 3)
         if self.result_cache_hits:
@@ -282,10 +293,10 @@ def note_result_cache_hit(ctx=None) -> None:
         cost.note_result_cache_hit()
 
 
-def note_device_dispatch(nbytes: int = 0) -> None:
+def note_device_dispatch(nbytes: int = 0, mesh_devices: int = 0) -> None:
     cost = current_cost()
     if cost is not None:
-        cost.note_device_dispatch(nbytes)
+        cost.note_device_dispatch(nbytes, mesh_devices)
 
 
 def note_compile(seconds: float) -> None:

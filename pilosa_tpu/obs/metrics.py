@@ -37,6 +37,7 @@ import time
 from bisect import bisect_left
 from typing import Iterable, Optional
 
+from ..utils.hotlock import HotLock
 from ..utils.stats import StatsClient
 
 # pilosa_<subsystem>_<noun>_<unit>: at least three snake segments after
@@ -95,7 +96,7 @@ class _Family:
         self.help = help
         self.labelnames = tuple(labels)
         self.max_label_sets = max(1, int(max_label_sets))
-        self._mu = threading.Lock()
+        self._mu = HotLock()
         self._children: dict[tuple, object] = {}
 
     def _child(self, labelvalues: tuple):
@@ -156,7 +157,7 @@ class _CounterChild:
 
     def __init__(self):
         self._v = 0.0
-        self._mu = threading.Lock()
+        self._mu = HotLock()
 
     def inc(self, n: float = 1.0) -> None:
         with self._mu:
@@ -243,7 +244,7 @@ class _HistogramChild:
         self._counts = [0] * (len(bounds) + 1)  # + the +Inf bucket
         self._sum = 0.0
         self._count = 0
-        self._mu = threading.Lock()
+        self._mu = HotLock()
         # Per-bucket last exemplar: (labels, value, unix_ts) — the
         # OpenMetrics hook carrying a trace/query id next to the
         # latency observation that landed in that bucket.

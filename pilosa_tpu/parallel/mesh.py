@@ -330,15 +330,30 @@ def _run_hilo(fn, *args) -> list[int]:
         return hilo_combine(arr)
 
 
-def _note_dispatch(*operands) -> None:
-    """Charge one device-program dispatch (+ its operand bytes) to the
-    current query's cost ledger (obs.accounting) — the per-query form
-    of the dispatch stage. None-cost fast path: one
-    thread-local read."""
+# Device programs dispatched through the entry points below, whoever
+# asked (a query, the warm-up): /debug/vars ``mesh.programsRun``. A
+# plain int under the GIL: a rare lost count is accepted, as in the
+# cost ledger.
+_PROGRAMS_RUN = 0
+
+
+def programs_run() -> int:
+    return _PROGRAMS_RUN
+
+
+def _note_dispatch(mesh: Mesh, *operands) -> None:
+    """Count one device-program dispatch on ``mesh``, and charge it
+    (+ its operand bytes, and the mesh's width: how many devices the
+    program ran on) to the current query's cost ledger
+    (obs.accounting) — the per-query form of the dispatch stage.
+    None-cost fast path: one thread-local read."""
+    global _PROGRAMS_RUN
+    _PROGRAMS_RUN += 1
     cost = _accounting.current_cost()
     if cost is not None:
         cost.note_device_dispatch(
-            sum(int(getattr(a, "nbytes", 0)) for a in operands))
+            sum(int(getattr(a, "nbytes", 0)) for a in operands),
+            mesh.devices.size)
 
 
 def _all_program_caches():
@@ -684,7 +699,7 @@ def count_expr(mesh: Mesh, expr: tuple, leaves: np.ndarray) -> int:
         chunk = programs_mod.bucket_pad(
             leaves[:, off:off + step], 1, n_dev)
         # Per chunk: each loop pass dispatches one program.
-        _note_dispatch(chunk)
+        _note_dispatch(mesh, chunk)
         with sched_context.stage("upload"):
             block = shard_slices_axis1(mesh, chunk)
         total += _run_hilo(fn, block)[0]
@@ -767,7 +782,7 @@ def count_exprs_sharded(mesh: Mesh, exprs: tuple,
     else:
         fn = _count_exprs_sharded_fn(mesh, exprs, len(leaf_arrays),
                                      mode)
-    _note_dispatch(*leaf_arrays)
+    _note_dispatch(mesh, *leaf_arrays)
     return _run_hilo(fn, *leaf_arrays)
 
 
@@ -811,7 +826,7 @@ def fused_tree_sharded(mesh: Mesh, count_exprs: tuple,
         tuple((expr, int(rows.shape[1]))
               for (expr, _), rows in zip(topn_items, rows_arrays)),
         len(leaf_arrays))
-    _note_dispatch(*leaf_arrays, *rows_arrays)
+    _note_dispatch(mesh, *leaf_arrays, *rows_arrays)
     flat = _run_hilo(fn, *leaf_arrays, *rows_arrays)
     counts = flat[:len(count_exprs)]
     out_topn: list[list[int]] = []
@@ -940,7 +955,7 @@ def topn_filtered_sharded(mesh: Mesh, expr, rows: jax.Array,
         fn = _topn_filtered_sharded_fn(mesh, expr, len(leaf_arrays),
                                        mode)
     threshold = min(threshold, 2**31 - 1)  # counts never exceed 2^31
-    _note_dispatch(rows, *leaf_arrays)
+    _note_dispatch(mesh, rows, *leaf_arrays)
     return _run_hilo(fn, jnp.int32(threshold), jnp.int32(tanimoto),
                      rows, *leaf_arrays)[:rows.shape[1]]
 
@@ -964,7 +979,7 @@ def topn_exact_sharded(mesh: Mesh, expr, rows: jax.Array,
                                        filtered=False)
     else:
         fn = _topn_exact_sharded_fn(mesh, expr, len(leaf_arrays), mode)
-    _note_dispatch(rows, *leaf_arrays)
+    _note_dispatch(mesh, rows, *leaf_arrays)
     return _run_hilo(fn, rows, *leaf_arrays)[:rows.shape[1]]
 
 
@@ -992,7 +1007,7 @@ def topn_topk_sharded(mesh: Mesh, expr, rows: jax.Array,
         return [counts[i] for i in order.tolist()], order.tolist()
     from . import programs as programs_mod
     fn = programs_mod.topn_topk_program(mesh, expr, len(leaf_arrays), k)
-    _note_dispatch(rows, *leaf_arrays)
+    _note_dispatch(mesh, rows, *leaf_arrays)
     out = _run(fn, rows, *leaf_arrays).astype(np.int64)
     counts = ((out[0] << 16) + out[1]).tolist()
     return counts, out[2].tolist()
@@ -1128,7 +1143,7 @@ def materialize_expr_sharded(mesh: Mesh, expr,
     _dispatch_gate()
     from . import programs as programs_mod
     fn = programs_mod.materialize_program(mesh, expr, len(leaf_arrays))
-    _note_dispatch(*leaf_arrays)
+    _note_dispatch(mesh, *leaf_arrays)
     return _run(fn, *leaf_arrays)
 
 
@@ -1156,7 +1171,7 @@ def bsi_range_sharded(mesh: Mesh, op: str, upred, depth: int,
         pbits2 = np.zeros(depth, dtype=np.uint32)
     from . import programs as programs_mod
     fn = programs_mod.bsi_range_program(mesh, op, len(plane_arrays))
-    _note_dispatch(*plane_arrays)
+    _note_dispatch(mesh, *plane_arrays)
     return _run(fn, pbits, pbits2, *plane_arrays)
 
 
@@ -1207,7 +1222,7 @@ def topn_exact(mesh: Mesh, expr, rows: np.ndarray,
             # program across nearby slice counts.
             rc = programs_mod.bucket_pad(rc, 0, n_dev)
             lcc = programs_mod.bucket_pad(lcc, 1, n_dev)
-            _note_dispatch(rc, lcc)  # per chunk: one program each
+            _note_dispatch(mesh, rc, lcc)  # per chunk: one program each
             with sched_context.stage("upload"):
                 rc_dev = shard_slices(mesh, rc)
                 lcc_dev = shard_slices_axis1(mesh, lcc)

@@ -47,7 +47,6 @@ the ≤2% overhead budget on hot repeated queries.
 
 from __future__ import annotations
 
-import threading
 import time
 from collections import OrderedDict
 from typing import Optional
@@ -56,6 +55,7 @@ from ..cluster import generations
 from ..obs import metrics as obs_metrics
 from ..ops.packed import WORDS_PER_SLICE
 from ..pql.ast import Call, Condition
+from ..utils.hotlock import HotLock
 from .record import PlanNode, PlanRecord, fingerprint_calls
 
 # Slice-count ceiling for exact (every slice enumerated) estimation;
@@ -100,7 +100,7 @@ class SubresultCache:
                  max_bits: int = 32 << 20):
         self.max_entries = max_entries
         self.max_bits = max_bits
-        self._mu = threading.Lock()
+        self._mu = HotLock()
         self._entries: OrderedDict[tuple, tuple] = OrderedDict()
         self._bits = 0
 
@@ -145,7 +145,9 @@ class SubresultCache:
 
 class Planner:
     """One per executor. Thread-safe: planning itself runs on the
-    query thread; the seen/estimate LRUs take the planner lock."""
+    query thread; the memo, the seen LRU and inserts into the estimate
+    cache take the planner lock (a ``HotLock``: every request thread
+    enters it), reads of warm estimates do not."""
 
     def __init__(self, holder, margin: float = 0.5,
                  subresult_entries: int = 512,
@@ -158,7 +160,7 @@ class Planner:
         # the executor installs its calibrated model's constants once
         # a mesh exists, warmup primes the persisted ones earlier.
         self.calibration = None
-        self._mu = threading.Lock()
+        self._mu = HotLock()
         self._seen: OrderedDict[str, int] = OrderedDict()
         self._estimates: OrderedDict[tuple, tuple] = OrderedDict()
         # Finished-plan memo: key -> {planned, roots, fingerprint,
@@ -331,9 +333,10 @@ class Planner:
 
     def _bump(self, outcome: str) -> None:
         obs_metrics.PLANNER_DECISIONS.labels(outcome).inc()
-        with self._mu:
-            self.decision_totals[outcome] = \
-                self.decision_totals.get(outcome, 0) + 1
+        # A plain bump, no lock (see _sum_slices): a roll-up for the
+        # debug surfaces, where a rare lost count is accepted.
+        self.decision_totals[outcome] = \
+            self.decision_totals.get(outcome, 0) + 1
 
     def _decide(self, record: PlanRecord, node: PlanNode,
                 outcome: str) -> None:
@@ -535,12 +538,20 @@ class Planner:
                 continue
             key = (id(view), row_id, s)
             epoch = getattr(frag, "_epoch", 0)
-            with self._mu:
-                hit = self._estimates.get(key)
-                if hit is not None and hit[0] == epoch:
-                    self._estimates.move_to_end(key)
-                    total += hit[1]
-                    continue
+            # Read without the lock (one dict look-up is atomic under
+            # the interpreter's own lock) and without refreshing the
+            # entry's place: k leaves x 8 sampled slices a plan took
+            # the planner's lock 16-32 times a request, and eight
+            # connection threads doing so formed a convoy on it — the
+            # waiter that is handed the lock holds it while it waits for
+            # the interpreter, so everyone behind it queues: `plan` read
+            # 0.2 ms or 8-20 ms for tens of seconds at a time (PERF.md,
+            # PR 27). Eviction is by age of insertion; the cache holds
+            # 4,096 estimates and a hot set is a few hundred.
+            hit = self._estimates.get(key)
+            if hit is not None and hit[0] == epoch:
+                total += hit[1]
+                continue
             n = 0
             try:
                 if frag.cache is not None:

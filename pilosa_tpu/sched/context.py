@@ -267,6 +267,10 @@ class _SpanCM:
         self._t0 = time.perf_counter()
         return self
 
+    def tag(self, **tags) -> None:
+        """Tags learned while the span is open."""
+        self._tags = {**(self._tags or {}), **tags}
+
     def __exit__(self, exc_type, exc, tb):
         ctx = self._ctx
         ctx.trace.add_span(self._name, ctx.wall_at(self._t0),
@@ -279,6 +283,9 @@ class _Nop:
 
     def __enter__(self):
         return self
+
+    def tag(self, **tags) -> None:
+        pass
 
     def __exit__(self, exc_type, exc, tb):
         return False

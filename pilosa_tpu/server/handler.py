@@ -587,6 +587,11 @@ class Handler:
         vetoes = getattr(self.executor, "cost_vetoes", None)
         if vetoes is not None:
             snap["costModelVetoes"] = vetoes
+        mesh_state = getattr(self.executor, "mesh_state", None)
+        if mesh_state is not None:
+            # The mesh the device programs run on; null before the
+            # first device call (docs/OBSERVABILITY.md).
+            snap["mesh"] = mesh_state()
         route_memo = getattr(self.executor, "route_memo", None)
         if route_memo is not None:
             # Device-lowered reads by how their route was found: reused

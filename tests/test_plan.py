@@ -346,6 +346,64 @@ class TestPlanMemo:
 # -- randomized differential: planned == unplanned (host) ----------------------
 
 
+class _CountingLock:
+    """``threading.Lock`` that counts its acquisitions."""
+
+    def __init__(self):
+        import threading
+        self._mu = threading.Lock()
+        self.taken = 0
+
+    def __enter__(self):
+        self._mu.acquire()
+        self.taken += 1
+        return self
+
+    def __exit__(self, *exc):
+        self._mu.release()
+        return False
+
+
+class TestPlannerLock:
+    @pytest.mark.parametrize("k", [2, 3, 4])
+    def test_a_warm_plan_takes_the_lock_a_few_times_not_per_slice(
+            self, tmp_path, k):
+        """Planning a k-leaf Count over many slices samples 8 slices a
+        leaf; each sample used to take the planner's lock (16-32 times
+        a request), and eight connection threads convoyed on it: the
+        served ``plan`` stage read 0.2 ms or 8-20 ms for tens of
+        seconds at a time (PERF.md, PR 27). Warm estimates are read
+        without the lock: what is left is the memo's get and put, the
+        route record's get and the CSE ladder — whatever the leaf and
+        slice counts."""
+        from pilosa_tpu.plan import planner as planner_mod
+        holder = Holder(str(tmp_path / "d"))
+        holder.open()
+        try:
+            f = holder.create_index("w").create_frame("f")
+            n_slices = planner_mod.EXACT_SLICES + 8     # sampled, not exact
+            for row in range(6):
+                cols = (np.arange(n_slices, dtype=np.uint64)
+                        * SLICE_WIDTH + row)
+                f.import_bits(np.full(n_slices, row, dtype=np.uint64),
+                              cols)
+            ex = Executor(holder, host="local", use_mesh=False)
+
+            def q(rows):
+                return "Count(Intersect(%s))" % ", ".join(
+                    f"Bitmap(rowID={r}, frame=f)" for r in rows)
+            for first in range(6 - k + 1):      # warms every estimate
+                assert ex.execute("w", q(range(first, first + k)))[0] \
+                    == 0
+            lock = ex.planner._mu = _CountingLock()
+            rows = list(range(k))[::-1]         # a call shape not planned yet
+            assert ex.execute("w", q(rows))[0] == 0
+            assert 0 < lock.taken <= 6, lock.taken
+            assert ex.planner.decision_totals["planned"] >= 6 - k + 2
+        finally:
+            holder.close()
+
+
 class TestPlannedVsUnplannedDifferential:
     @pytest.mark.parametrize("seed", [11, 12, 13])
     def test_random_trees_with_writes_between(self, tmp_path, seed):

@@ -141,8 +141,10 @@ class TestTiling:
 
         with ctx.stage("execute"):
             t = threading.Thread(target=leg)
-            t.start()
+            # started inside the wait: on a loaded machine a leg started
+            # before it can be in ``fetch`` by the time the wait begins
             with ctx.stage("legs_wait"):
+                t.start()
                 t.join()
         clock.close()
         t1 = time.perf_counter()
