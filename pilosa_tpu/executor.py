@@ -28,7 +28,8 @@ import os
 import threading
 from collections import OrderedDict
 import time
-from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
+from concurrent.futures import (FIRST_COMPLETED, Future,
+                                ThreadPoolExecutor, wait)
 from dataclasses import dataclass
 from typing import Callable, Optional
 
@@ -113,6 +114,18 @@ class ExecOptions:
     ctx: Optional[object] = None
     partial: bool = False
     missing_slices: Optional[list] = None
+
+
+def _ran_here(fn, *args) -> Future:
+    """``fn(*args)`` on the calling thread, as the finished future a
+    pool would have handed back: whatever it raised is raised again
+    where ``result()`` is read."""
+    fut: Future = Future()
+    try:
+        fut.set_result(fn(*args))
+    except BaseException as e:  # noqa: BLE001 - result() re-raises it
+        fut.set_exception(e)
+    return fut
 
 
 def _needs_slices(calls: list[Call]) -> bool:
@@ -308,6 +321,11 @@ class Executor:
         # records or view entries dropped because a token moved.
         self.route_memo = {"hits": 0, "misses": 0, "invalidated": 0}
         self._route_mu = threading.Lock()
+        # /debug/vars.legs: map-reduce fan-outs whose lone local leg
+        # ran on the calling thread (inline) or that went to the
+        # ``node`` pool (pooled). No lock: two threads may lose a
+        # count between them, and a reader takes shares of these.
+        self.legs = {"inline": 0, "pooled": 0}
         # Per-fingerprint plan store behind GET /debug/plans (the
         # handler records finished coordinator queries into it).
         self.plan_store = plan_store.PlanStore()
@@ -4287,7 +4305,6 @@ class Executor:
 
         result = None
         processed = 0
-        pool = self._pool("node")
         futures: dict = {}
         # Degraded reads (?partial=1): slices with no reachable
         # replica land here instead of failing the query; the handler
@@ -4298,8 +4315,10 @@ class Executor:
                 opt.missing_slices = []
             missing = opt.missing_slices
 
+        first = True    # the next fan-out is the read's first
+
         def submit(nodes, slices, groups=None):
-            nonlocal processed
+            nonlocal processed, first
             before = len(missing) if missing is not None else 0
             # Elastic resize, migrating phase: moving slices fan out as
             # DOUBLE-READ legs (old owner authoritative, new owner the
@@ -4312,6 +4331,7 @@ class Executor:
                 groups = self._resize_moving_groups(index, slices)
                 if groups:
                     moved = set()
+                    pool = self._pool("node")
                     for (old_hosts, new_hosts), group in groups.items():
                         moved.update(group)
                         fut = pool.submit(
@@ -4325,10 +4345,30 @@ class Executor:
             if groups is None or self.cluster.resize is not None:
                 groups = self._slices_by_node(nodes, index, slices,
                                               missing=missing)
+            # A read whose whole first fan-out is ONE leg on this node
+            # runs it here, on the calling thread: the pool could only
+            # make this thread wait (a Future, a queue put, two thread
+            # wake-ups, twice the threads under one interpreter lock)
+            # for work it can do itself. The leg comes back as the
+            # finished future the loop below reads, so a failure takes
+            # the same re-map, through the pool. Local legs running at
+            # once are then bounded by admission (sched/admission.py),
+            # not by the pool's workers. Deadline and cancel stay
+            # cooperative (the checks inside the leg); what is given up
+            # is walking away from a STALLED local leg. A pod's "local"
+            # leg may fan out to its other processes over the network,
+            # so a pod keeps the pool and the walk-away.
+            inline = (first and len(groups) == 1
+                      and groups[0][0].host == self.host
+                      and self.cluster.resize is None
+                      and self.pod is None)
+            first = False
+            self.legs["inline" if inline else "pooled"] += 1
+            start = _ran_here if inline else self._pool("node").submit
             for node, node_slices in groups:
-                fut = pool.submit(self._mapper_node, node, index, c,
-                                  node_slices, opt, map_fn, reduce_fn,
-                                  local_fn)
+                fut = start(self._mapper_node, node, index, c,
+                            node_slices, opt, map_fn, reduce_fn,
+                            local_fn)
                 futures[fut] = (node, node_slices)
                 if ctx is not None:
                     ctx.add_leg(node.host, len(node_slices))
@@ -4357,7 +4397,12 @@ class Executor:
             submit(nodes, slices,
                    route["groups"] if route is not None else None)
             while processed < len(slices):
-                if ctx is None:
+                # A leg that ran inline is finished already: it is read
+                # without a wait, and without a ``legs_wait`` stage.
+                done = [f for f in futures if f.done()]
+                if done:
+                    pass
+                elif ctx is None:
                     done, _ = wait(list(futures),
                                    return_when=FIRST_COMPLETED)
                 else:

@@ -597,6 +597,12 @@ class Handler:
             # Device-lowered reads by how their route was found: reused
             # whole from its record, or walked (docs/OBSERVABILITY.md).
             snap["routeMemo"] = dict(route_memo)
+        legs = getattr(self.executor, "legs", None)
+        if legs is not None:
+            # Map-reduce fan-outs by where their legs ran: the lone
+            # local leg on the calling thread, or the pool
+            # (docs/OBSERVABILITY.md).
+            snap["legs"] = dict(legs)
         model = getattr(self.executor, "cost_model", None)
         if model is not None:
             snap["costModel"] = {"syncS": model.cal.sync_s,
