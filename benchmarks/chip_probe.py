@@ -3,11 +3,10 @@
 
 One process on the chip(s), each check compared with numpy:
 
-- every Pallas kernel compiles under the installed jax — ``densify_pallas``
+- the one Pallas kernel compiles under the installed jax — ``densify_pallas``
   (the default sparse-upload leg on a TPU) at 256 slices for each bucketed
-  group width and the candidate-block form, the count/TopN kernels behind
-  ``PILOSA_TPU_PALLAS=1``, and the ``shard_map`` builders in
-  ``parallel/mesh.py`` that wrap them or plain XLA bodies;
+  group width and the candidate-block form — and so do the fixed-shape
+  ``shard_map`` builders left in ``parallel/mesh.py``;
 - a leaf slab uploaded by the executor's own path is one shard per device
   of the mesh, and ``memory_stats()["bytes_in_use"]`` grows on every device.
 
@@ -34,11 +33,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from pilosa_tpu.ops import packed  # noqa: E402
-from pilosa_tpu.ops import pallas_kernels as pk  # noqa: E402
 from pilosa_tpu.parallel import mesh as mesh_mod  # noqa: E402
 
 W = packed.WORDS_PER_SLICE
@@ -88,61 +85,16 @@ def densify_case(mesh, rng, g_target: int, block_rows: int | None = None):
             "secondS": round(t2 - t1, 4)}
 
 
-def pallas_kernels(rng) -> dict:
-    """name -> check, for the count/TopN kernels off the default path."""
-    a = rng.integers(0, 1 << 32, size=(16, 1 << 15), dtype=np.uint32)
-    b = rng.integers(0, 1 << 32, size=(16, 1 << 15), dtype=np.uint32)
-    x = rng.integers(0, 1 << 32, size=(1 << 22,), dtype=np.uint32)
-    y = rng.integers(0, 1 << 32, size=(1 << 22,), dtype=np.uint32)
-    leaves = rng.integers(0, 1 << 32, size=(3, 64, W), dtype=np.uint32)
-    rows = rng.integers(0, 1 << 32, size=(64, 10, W), dtype=np.uint32)
-    expr = ("andnot", ("or", ("leaf", 0), ("leaf", 1)), ("leaf", 2))
-
-    def op_count_rows():
-        got = np.asarray(pk.op_count_rows_pallas(
-            "and", jnp.asarray(a), jnp.asarray(b)))
-        require((got == np.bitwise_count(a & b).sum(axis=1)).all())
-
-    def op_count_long_row():
-        got = int(pk.op_count_rows_pallas(
-            "and", jnp.asarray(x), jnp.asarray(y)))
-        require(got == popc(x & y))
-
-    def expr_count_rows():
-        got = np.asarray(pk.expr_count_rows_pallas(
-            expr, jnp.asarray(leaves)))
-        want = np.bitwise_count(
-            (leaves[0] | leaves[1]) & ~leaves[2]).sum(axis=1)
-        require((got == want).all())
-
-    def topn_block_count():
-        got = np.asarray(pk.topn_block_count_pallas(
-            ("leaf", 0), jnp.asarray(rows), jnp.asarray(leaves[:1])))
-        want = np.bitwise_count(rows & leaves[0][:, None, :]).sum(axis=2)
-        require((got == want).all())
-        got = np.asarray(pk.topn_block_count_pallas(
-            None, jnp.asarray(rows), jnp.asarray(leaves[:0])))
-        require((got == np.bitwise_count(rows).sum(axis=2)).all())
-
-    return {"pallas_op_count_rows": op_count_rows,
-            "pallas_op_count_long_row": op_count_long_row,
-            "pallas_expr_count_rows": expr_count_rows,
-            "pallas_topn_block_count": topn_block_count}
-
-
 def shard_map_builders(mesh, rng) -> dict:
-    """name -> check, for mesh.py's builders: the first seven take their
-    Pallas bodies under PILOSA_TPU_PALLAS=1 (set by the caller around
-    them), the last three are plain XLA."""
-    leaves = rng.integers(0, 1 << 32, size=(3, 64, W), dtype=np.uint32)
+    """name -> check, for the shard_map builders mesh.py keeps beside
+    densify (the query programs are parallel/programs.py's, which
+    chip_smoke.py drives through the server)."""
+    leaves = rng.integers(0, 1 << 32, size=(2, 64, W), dtype=np.uint32)
     rows = rng.integers(0, 1 << 32, size=(64, 10, W), dtype=np.uint32)
-    expr = ("andnot", ("or", ("leaf", 0), ("leaf", 1)), ("leaf", 2))
-    la = [mesh_mod.shard_slices(mesh, leaves[i]) for i in range(3)]
+    la = [mesh_mod.shard_slices(mesh, leaves[i]) for i in range(2)]
     ra = mesh_mod.shard_slices(mesh, rows)
-    want_expr = popc((leaves[0] | leaves[1]) & ~leaves[2])
     want_topn = np.bitwise_count(
         rows & leaves[0][:, None, :]).sum(axis=(0, 2)).tolist()
-    src = ("leaf", 0)
 
     def same(got, want):
         require(got == want, (str(got)[:180], str(want)[:180]))
@@ -157,23 +109,6 @@ def shard_map_builders(mesh, rng) -> dict:
                           popc(leaves[0] | leaves[1])))
 
     return {
-        "pallas:count_expr_sharded": lambda: same(
-            mesh_mod.count_expr_sharded(mesh, expr, la), want_expr),
-        "pallas:count_exprs_sharded": lambda: same(
-            mesh_mod.count_exprs_sharded(mesh, (expr, src), la),
-            [want_expr, popc(leaves[0])]),
-        "pallas:count_expr_stream": lambda: same(
-            mesh_mod.count_expr(mesh, expr, leaves), want_expr),
-        "pallas:topn_exact_sharded": lambda: same(
-            mesh_mod.topn_exact_sharded(mesh, src, ra, la[:1]), want_topn),
-        "pallas:topn_filtered_sharded": lambda: same(
-            mesh_mod.topn_filtered_sharded(mesh, src, ra, la[:1],
-                                           threshold=2), want_topn),
-        "pallas:topn_exact_stream": lambda: same(
-            mesh_mod.topn_exact(mesh, src, rows, leaves[:1]), want_topn),
-        "pallas:topn_filtered_stream": lambda: same(
-            mesh_mod.topn_exact(mesh, src, rows, leaves[:1], threshold=2),
-            want_topn),
         "xla:count_op": lambda: same(
             mesh_mod.count_op(mesh, "and", la[0], la[1]),
             popc(leaves[0] & leaves[1])),
@@ -258,19 +193,14 @@ def main() -> int:
             lambda g=g: densify_case(mesh, rng, g))
     checks["densify_block_R10_G8"] = lambda: densify_case(
         mesh, rng, 8, block_rows=10)
-    checks.update(pallas_kernels(rng))
-    builders = shard_map_builders(mesh, rng)
-    checks.update(builders)
+    checks.update(shard_map_builders(mesh, rng))
     checks["layout_and_hbm"] = lambda: layout_and_hbm(n_dev)
 
     failed = []
     for name, fn in checks.items():
         # Every check runs and is recorded, the refused ones by name: a
-        # kernel the compiler refuses is a finding (ROADMAP D3 deletes it
-        # with its builder), and the exit code says that there was one.
-        pallas = name.startswith("pallas:")
-        if pallas:
-            os.environ["PILOSA_TPU_PALLAS"] = "1"
+        # kernel the compiler refuses is a finding, and the exit code
+        # says that there was one.
         t0 = time.perf_counter()
         try:
             rec = {"ok": True, "info": fn()}
@@ -278,9 +208,6 @@ def main() -> int:
             rec = {"ok": False,
                    "error": f"{type(e).__name__}: {e}"[:1500]}
             failed.append(name)
-        finally:
-            if pallas:
-                del os.environ["PILOSA_TPU_PALLAS"]
         rec["seconds"] = round(time.perf_counter() - t0, 3)
         out["checks"][name] = rec
         print(name, json.dumps(rec)[:400], flush=True)

@@ -2,7 +2,7 @@
 """Chip smoke: one real server, BASELINE config 4's width, served from the TPU.
 
 Starts ``python -m pilosa_tpu.cli server`` as a subprocess with no
-routing, mesh, warmup, Pallas or sparse-upload variable set, loads a
+routing, mesh, warmup or sparse-upload variable set, loads a
 256-slice index over the HTTP import route, answers every device-eligible
 query class over HTTP, and compares each answer with a plain numpy
 reference built from the same seed. Then it reads the server's own
@@ -61,8 +61,7 @@ RUN_ROWS = (1, 4)
 
 # A server that came up without these set is the server a user gets.
 _STEERING_PREFIXES = ("PILOSA_TPU_MESH", "PILOSA_TPU_COST_",
-                      "PILOSA_TPU_WARMUP", "PILOSA_TPU_PALLAS",
-                      "PILOSA_TPU_SPARSE_UPLOAD")
+                      "PILOSA_TPU_WARMUP", "PILOSA_TPU_SPARSE_UPLOAD")
 
 # A class is repeated until a device program answers it, at most this
 # often, then WARM_REPEATS more times for the warm figure.

@@ -3,9 +3,9 @@
 A ground-up re-design of Pilosa (reference: /root/reference, Go) for TPU
 hardware: host-side storage keeps the reference's roaring snapshot+op-log file
 format, while the compute hot path (container intersect/union/andnot/popcount,
-TopN) runs as XLA/Pallas kernels over dense packed words held in HBM, and the
-per-slice map-reduce is a `shard_map` over a `jax.sharding.Mesh` with ICI
-collectives for the reductions.
+TopN) runs as XLA programs over dense packed words held in HBM, and the
+per-slice map-reduce is one jitted program over arrays sharded on a
+`jax.sharding.Mesh`, with ICI collectives for the reductions.
 
 Layer map (mirrors SURVEY.md §1):
     cli/        command-line verbs (server, import, export, backup, ...)
@@ -15,8 +15,9 @@ Layer map (mirrors SURVEY.md §1):
     cluster/    topology, jump-hash sharding, broadcast, node-to-node client
     models/     holder → index → frame → view schema hierarchy
     storage/    fragment (snapshot+oplog), roaring bitmaps, caches, attrs
-    ops/        device kernel layer: packed bitmaps + XLA/Pallas kernels
-    parallel/   mesh construction, shard_map slice executor, HBM residency
+    ops/        device kernel layer: packed bitmaps, XLA kernels, the
+                Pallas sparse-upload densify
+    parallel/   mesh construction, the device program catalogue, HBM residency
     utils/      time quantum engine, stats, config, iterators
 """
 

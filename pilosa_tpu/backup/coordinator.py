@@ -55,8 +55,8 @@ JOURNAL_FILE = "backup.json"
 # STREAMS + re-verifies whole fragments (up to 128 MB each) where
 # scrub only read-verifies; at 100 ms/fragment a 256-slice index
 # pays ~26 s of pacing per pass — noise for a once-per-operator-
-# request op, and what keeps the backup-while-serving p50 inside the
-# ≤5% bound (benchmarks/suite.py config_backup).
+# request op, and what keeps the backup-while-serving p50 within 5 %
+# of serving alone.
 DEFAULT_PACE_S = 0.1
 
 # Journal-write coalescing window: per-fragment journal fsyncs were
@@ -153,9 +153,8 @@ class BackupCoordinator:
         self.kind = kind if kind in ("full", "incremental") else "full"
         # Inter-fragment pacing, the storage-scrub discipline: the
         # snapshot/digest/push work yields between fragments so a
-        # backup in flight stays out of serving's way (the ≤5%
-        # backup-while-serving bound in benchmarks/suite.py
-        # config_backup is measured with this pacing).
+        # backup in flight stays out of serving's way (see
+        # DEFAULT_PACE_S).
         self.pace_s = max(0.0, float(pace_s))
         self.id = backup_id or uuid.uuid4().hex[:12]
         self.journal = journal or BackupJournal.for_data_dir(

@@ -1111,7 +1111,7 @@ def build_parser() -> argparse.ArgumentParser:
                         " (default true)")
     s.add_argument("--sentinel.manifest", dest="sentinel_manifest",
                    default="", metavar="PATH",
-                   help="benchmarks/MANIFEST.json whose committed"
+                   help="JSON file whose recorded metrics are the"
                         " envelope live latencies must stay inside")
     s.add_argument("--watchdog.enabled", dest="watchdog_enabled",
                    default=None,

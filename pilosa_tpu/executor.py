@@ -1851,8 +1851,8 @@ class Executor:
         replica_n == nodes shape) answers the whole batch from local
         fragments and keeps the fused device fold. Count and TopN never
         take the inverse slice list (only Bitmap does), so every call
-        in the run shares ``slices``. Pod runs and Pallas-kernel meshes
-        fuse counts only (their per-kind programs serve TopN).
+        in the run shares ``slices``. Pod runs fuse counts only (their
+        per-kind programs serve TopN).
         """
         if not self.use_mesh or len(slices) < self.mesh_min_slices:
             return None
@@ -1873,13 +1873,11 @@ class Executor:
             return None
         from .parallel import mesh as mesh_mod
         mesh = None
-        pallas = False
         if self.pod is None:
             mesh = self._mesh_or_none()
             if mesh is None or len(slices) > mesh_mod.slice_chunk_bound(
                     mesh.shape[mesh_mod.AXIS_SLICES]):
                 return None
-            pallas = mesh_mod._mesh_pallas_mode(mesh) is not None
         shard, budget = self._count_budget(slices)
         leaves: list[tuple] = []
         leaf_ids: dict[tuple, int] = {}
@@ -1919,7 +1917,7 @@ class Executor:
                 host_rows += len(call_leaves)
                 j += 1
                 continue
-            if (c.name == "TopN" and self.pod is None and not pallas):
+            if c.name == "TopN" and self.pod is None:
                 item = self._topn_fusable(index, c, slices, shard,
                                           budget - rows_bytes, leaves,
                                           leaf_ids)

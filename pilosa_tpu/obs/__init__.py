@@ -50,8 +50,8 @@ runtime collector.
   bounded breaker-aware parallel scrape with the ``?partial=1``
   degradation contract.
 - ``obs.sentinel`` — the regression sentinel: robust-z rules over
-  the live history plus committed-envelope rules against
-  benchmarks/MANIFEST.json; a finding raises
+  the live history plus envelope rules against the operator's
+  ``[sentinel] manifest`` file; a finding raises
   ``pilosa_sentinel_findings_total{metric,direction}``, force-keeps
   in-flight traces (reason ``anomaly``), and lands a blackbox
   snapshot naming the regressed metric.

@@ -6,7 +6,7 @@ options, query id, plan fingerprint, status, latency, and a canonical
 64-bit digest of the normalized result JSON — into a crc-framed on-disk
 segment ring under ``<data>/capture/`` (the obs.diskring discipline:
 bounded bytes, torn tails skipped on reopen, diagnostics never raise).
-A captured stream is replayable: ``benchmarks/replay.py`` re-issues it
+A captured stream is replayable: ``pilosa-tpu replay`` re-issues it
 against any cluster preserving inter-arrival gaps, and the shadow-diff
 mode compares digests between a baseline and a candidate endpoint.
 
@@ -35,8 +35,7 @@ still agree. Floats are round-tripped through repr via json; bools,
 ints, and bitmap JSON pass through structurally.
 
 Sampling modes (``[capture] mode``): ``off`` is a nop-cost path (one
-attribute read per request, proven by the overhead guard in
-benchmarks/suite.py config_replay); ``sampled`` (the default) records
+attribute read per request); ``sampled`` (the default) records
 EVERY write and import — replay must reproduce state — plus 1-in-N
 reads; ``full`` records everything. Redaction (``redact``): for the
 listed tenants ("*" = all), PQL string/numeric literals are replaced
@@ -227,7 +226,7 @@ class CaptureStore:
         self.ring.close()
 
 
-# -- replay-side helpers (benchmarks/replay.py, tests) ------------------------
+# -- replay-side helpers (obs.replay, tests) ----------------------------------
 
 
 def merge_streams(streams: list[list[dict]]) -> list[dict]:

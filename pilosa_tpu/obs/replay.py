@@ -1,14 +1,13 @@
 """Deterministic workload replay + shadow diff (obs.capture consumers).
 
-The engine behind ``pilosa-tpu replay`` and ``benchmarks/replay.py``:
-re-issues a captured (or merged multi-node) record stream against any
+The engine behind ``pilosa-tpu replay``: re-issues a captured (or merged multi-node) record stream against any
 cluster as a **multi-process open-loop driver** — each record fires at
 its recorded arrival offset (scaled by ``--rate xN``) regardless of
 completions, so queueing delay shows up as latency exactly like the
 live traffic it was recorded from. Tenant headers, lanes, and the
 effective ``?timeout=``/``?partial=`` options replay verbatim;
-latency counts from the SCHEDULED send time (open-loop accounting,
-the latency_under_load.py discipline).
+latency counts from the SCHEDULED send time (open-loop accounting:
+a late send is the server's queueing, not the driver's).
 
 Records with ``kind == "import"`` mark state mutations whose payload
 the capture ring does not hold (only the ack is recorded); replay

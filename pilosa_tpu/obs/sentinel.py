@@ -1,9 +1,9 @@
 """Regression sentinel: the trajectory watcher that tells you the
 perf cliff happened while it is still happening.
 
-Perf regressions were only caught when someone manually re-ran
-``bench.py`` against MANIFEST.json. The sentinel closes that loop on a
-slow cadence against the live metric history (obs.history):
+Perf regressions were only caught when someone re-ran a benchmark by
+hand. The sentinel closes that loop on a slow cadence against the
+live metric history (obs.history):
 
 - **Robust-z rules**: for every watched series (by default the query
   latency ``:p50``/``:p99`` and ``:rate`` derivations per lane/call),
@@ -12,10 +12,11 @@ slow cadence against the live metric history (obs.history):
   past the threshold AND a minimum effect ratio → a finding. MAD, not
   stddev — one old outlier must not widen the band until a real cliff
   hides inside it.
-- **Manifest envelope rules**: the committed benchmark artifacts
-  (benchmarks/MANIFEST.json) define what this build measured on this
-  class of hardware; live medians sustained past ``manifest_tolerance``
-  × the committed number breach the envelope, whatever the local
+- **Manifest envelope rules**: a JSON file the operator names
+  (``[sentinel] manifest``; its ``metrics`` table holds what this
+  build measured on this class of hardware); live medians sustained
+  past ``manifest_tolerance`` × the recorded number breach the
+  envelope, whatever the local
   baseline drifted to (a slow regression that re-baselines itself
   every hour still trips this one).
 

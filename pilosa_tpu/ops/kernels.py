@@ -15,9 +15,6 @@ Conventions:
   in Python ints, or via psum on the mesh (pilosa_tpu.parallel).
 - all entry points are jit-compiled with the op name static, so each
   (op, shape) pair compiles once and is cached.
-
-A fused Pallas variant of the count kernels lives in
-pilosa_tpu.ops.pallas_kernels; `op_count` auto-selects it on TPU.
 """
 
 from __future__ import annotations
@@ -91,17 +88,6 @@ def row_block_op_count(op: str, rows: jax.Array, other: jax.Array
     """
     words = _BITWISE[op](rows, other[None, :])
     return jnp.sum(jax.lax.population_count(words).astype(jnp.int32), axis=-1)
-
-
-def op_count(op: str, a: jax.Array, b: jax.Array) -> jax.Array:
-    """Fused count, auto-selecting the Pallas kernel on TPU (interpret
-    mode when forced via PILOSA_TPU_PALLAS=interpret for CPU tests)."""
-    from . import pallas_kernels
-    mode = pallas_kernels.pallas_mode(pallas_kernels.platform_of(a))
-    if mode is not None:
-        return pallas_kernels.op_count_rows_pallas(
-            op, a, b, interpret=(mode == "interpret"))
-    return op_count_rows(op, a, b)
 
 
 # -- BSI bit-plane comparison circuit (storage.bsi row layout) ----------------

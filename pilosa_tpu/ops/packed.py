@@ -268,9 +268,9 @@ def sparse_gate(rows: list, n_words: int,
     (use_sparse, plan) — pass ``plan`` to bucket_prepared to reuse the
     grouping pass. Sparse pays when the bucketed payload —
     ``T * n_words/16 * G`` bytes — is under ``dense/margin`` and G is
-    within the kernel's VMEM envelope; the measured crossover
-    (benchmarks/DENSIFY.json) shows 3-6x wins at G<=16 and a 0.5x LOSS
-    at G=128, so the gate is deliberately conservative."""
+    within the kernel's VMEM envelope; sparse wins at small G and
+    loses outright by G=128 (every slot of every group is shipped and
+    OR-ed), so the gate is deliberately conservative."""
     subs = n_words // _DENSIFY_LANES
     plan = _bucket_plan(rows, subs)
     g_pad = plan[0]

@@ -15,17 +15,17 @@ Axis conventions:
   analogue); per-row counts are psum'd over ``slices``, gathered over
   ``rows`` for the final top-k.
 
-Program forms: the XLA serving path (the recorded A/B winner, and the
-only path off-TPU) compiles through the shape-stable **global-view
-catalogue** in ``parallel.programs`` — plain ``jax.jit`` over globally
-sharded arrays with explicit ``NamedSharding`` placement, slice axes
-padded to canonical buckets (``programs.slice_bucket``) so the compile
-count is bucket-bound instead of scaling with slice count, and the
-final Count/TopN reduction is an in-program all-reduce. The Pallas
-fused kernels keep their per-shard ``shard_map`` form here
-(``pallas_call`` is a per-shard primitive); the dispatch entry points
-pick per backend. Both forms share one compile-accounting wrapper
-(``_finalize_program``) and one public entry-point surface.
+Program forms: every query program is built by the shape-stable
+**global-view catalogue** in ``parallel.programs`` — plain ``jax.jit``
+over globally sharded arrays with explicit ``NamedSharding`` placement,
+slice axes padded to canonical buckets (``programs.slice_bucket``) so
+the compile count is bucket-bound instead of scaling with slice count,
+and the final Count/TopN reduction is an in-program all-reduce. This
+module holds the dispatch entry points, the expression bodies those
+programs share, and the compile-accounting wrapper
+(``_finalize_program``). The ``shard_map`` builders left here are the
+sparse-upload ``densify`` (a Pallas kernel is a per-shard primitive)
+and the fixed-shape ``count_op`` / ``topn_counts`` / ``query_step``.
 """
 
 from __future__ import annotations
@@ -199,9 +199,9 @@ def fair_dispatch_state() -> "dict | None":
 
 def _fair_dispatch(fn):
     """Entry-point wrapper: hold one fair slot for the duration of the
-    dispatch call. Reentrant per thread (topn_topk_sharded's Pallas
-    path calls topn_exact_sharded — the outer slot covers both), and
-    a straight pass-through until install_fair_dispatch arms it."""
+    dispatch call. Reentrant per thread (an entry point called from
+    inside another shares the outer slot), and a straight pass-through
+    until install_fair_dispatch arms it."""
     @functools.wraps(fn)
     def gated(*args, **kwargs):
         q = _FAIR
@@ -388,29 +388,8 @@ def compile_stats() -> dict:
             **stats}
 
 
-def _mesh_pallas_mode(mesh: Mesh) -> str | None:
-    """Pallas dispatch mode for programs compiled onto ``mesh`` —
-    "compiled" on TPU meshes, "interpret" when forced for tests, None
-    for the XLA fusion path (ops.pallas_kernels.pallas_mode)."""
-    from ..ops import pallas_kernels
-    return pallas_kernels.pallas_mode(mesh.devices.flat[0].platform)
-
-
-# The Pallas kernels hold every leaf's tile in VMEM at once; beyond
-# this the XLA path (which fuses the fold without materializing all
-# leaves) is both safer and faster.
-_PALLAS_MAX_LEAVES = 16
-
-
-def _rows_popcount(expr, leaves, mode):
-    """Per-slice-row int32 counts of ``expr`` over ``leaves`` [L, S, W],
-    via the fused Pallas kernel when ``mode`` says so, else XLA."""
-    if mode is not None and leaves.shape[0] > _PALLAS_MAX_LEAVES:
-        mode = None
-    if mode is not None:
-        from ..ops import pallas_kernels
-        return pallas_kernels.expr_count_rows_pallas(
-            expr, leaves, interpret=(mode == "interpret"))
+def _rows_popcount(expr, leaves):
+    """Per-slice-row int32 counts of ``expr`` over ``leaves`` [L, S, W]."""
     words = _eval_expr(expr, leaves)
     pc = jax.lax.population_count(words).astype(jnp.int32)
     return jnp.sum(pc, axis=-1)
@@ -588,50 +567,10 @@ def count_op(mesh: Mesh, op: str, a: jax.Array, b: jax.Array) -> int:
     return (int(hilo[0]) << 16) + int(hilo[1])
 
 
-@functools.lru_cache(maxsize=256)  # keyed on query-shaped exprs: bound it
-def _count_expr_fn_cached(mesh: Mesh, expr: tuple, mode: str | None):
-    def per_shard(leaves):  # leaves: [L, S/n, W]
-        his, los = _exprs_hi_lo((expr,), leaves, mode)
-        return jnp.stack([jax.lax.psum(his[0], AXIS_SLICES),
-                          jax.lax.psum(los[0], AXIS_SLICES)])
-
-    # check_vma off when Pallas is in the shard body: pallas_call's
-    # out_shape carries no varying-axis info, which trips the inference.
-    return _finalize_program(jax.shard_map(
-        per_shard, mesh=mesh,
-        in_specs=(P(None, AXIS_SLICES),), out_specs=P(),
-        check_vma=(mode is None)), "count_expr_block_pallas")
-
-
-def count_expr_fn(mesh: Mesh, expr: tuple):
-    """[L, S, W] leaf blocks → stacked (hi, lo) 16-bit halves of
-    the expression bitmap's count (decode via hilo_combine — ONE
-    output array = one host fetch).
-
-    ``expr`` is a hashable tree: ``("leaf", i)`` selects leaf block i,
-    ``(op, a, b)`` combines subtrees with a bitwise op from kernels._BITWISE.
-    One jitted SPMD program per (mesh, expr) — the whole PQL bitmap
-    expression (e.g. Count(Intersect(Bitmap, Bitmap))) is evaluated
-    elementwise over every slice at once and reduced in-program,
-    replacing the reference's per-slice goroutine map + sum reduce
-    (executor.go:568-597,1103-1236). On TPU the per-shard body is the
-    fused Pallas expression-count kernel (ops.pallas_kernels); elsewhere
-    the global-view catalogue program (parallel.programs). Public: the
-    pod layer (parallel.multihost) feeds these programs process-local
-    shards.
-    """
-    mode = _mesh_pallas_mode(mesh)
-    if mode is None:
-        from . import programs as programs_mod
-        return programs_mod.count_exprs_block_program(mesh, (expr,))
-    return _count_expr_fn_cached(mesh, expr, mode)
-
-
-def _exprs_hi_lo(exprs, leaves, mode):
+def _exprs_hi_lo(exprs, leaves):
     """Per-expression (hi, lo) 16-bit count halves over one leaf block
-    [L, S/n, W] — each expression reads only ITS leaves (no redundant
-    HBM traffic; the Pallas leaf-tile cap applies per expression).
-    Shared body of the batched-count programs."""
+    [L, S, W] — each expression reads only ITS leaves (no redundant
+    HBM traffic). Shared body of the count programs."""
     his, los = [], []
     n = leaves.shape[0]
     for expr in exprs:
@@ -642,34 +581,10 @@ def _exprs_hi_lo(exprs, leaves, mode):
             sub = leaves[jnp.asarray(ids)]
             local = remap_expr_leaves(
                 expr, {g: li for li, g in enumerate(ids)})
-        row = _rows_popcount(local, sub, mode).ravel()
+        row = _rows_popcount(local, sub).ravel()
         his.append(jnp.sum(row >> 16))
         los.append(jnp.sum(row & 0xFFFF))
     return jnp.stack(his), jnp.stack(los)
-
-
-@functools.lru_cache(maxsize=256)
-def _count_exprs_fn_cached(mesh: Mesh, exprs: tuple, mode: str | None):
-    def per_shard(leaves):  # leaves: [L, S/n, W]
-        his, los = _exprs_hi_lo(exprs, leaves, mode)
-        return jnp.stack([jax.lax.psum(his, AXIS_SLICES),
-                          jax.lax.psum(los, AXIS_SLICES)])
-
-    return _finalize_program(jax.shard_map(
-        per_shard, mesh=mesh,
-        in_specs=(P(None, AXIS_SLICES),), out_specs=P(),
-        check_vma=(mode is None)), f"count_exprs_block_pallas_n{len(exprs)}")
-
-
-def count_exprs_fn(mesh: Mesh, exprs: tuple):
-    """K-expression batch form of count_expr_fn: ``[L, S, W]`` shared
-    leaf block → stacked [2, K] (hi, lo) 16-bit halves, one program =
-    one host fetch. Public for the pod layer (parallel.multihost)."""
-    mode = _mesh_pallas_mode(mesh)
-    if mode is None:
-        from . import programs as programs_mod
-        return programs_mod.count_exprs_block_program(mesh, exprs)
-    return _count_exprs_fn_cached(mesh, exprs, mode)
 
 
 def slice_chunk_bound(n_dev: int) -> int:
@@ -692,7 +607,7 @@ def count_expr(mesh: Mesh, expr: tuple, leaves: np.ndarray) -> int:
     _dispatch_gate()
     from . import programs as programs_mod
     n_dev = mesh.shape[AXIS_SLICES]
-    fn = count_expr_fn(mesh, expr)
+    fn = programs_mod.count_exprs_block_program(mesh, (expr,))
     total = 0
     step = slice_chunk_bound(n_dev)
     for off in range(0, leaves.shape[1], step):
@@ -744,21 +659,6 @@ def remap_expr_leaves(expr, remap: dict[int, int]) -> tuple:
     return done[id(expr)]
 
 
-@functools.lru_cache(maxsize=256)
-def _count_exprs_sharded_fn(mesh: Mesh, exprs: tuple, n_leaves: int,
-                            mode: str | None):
-    def per_shard(*leaf_shards):  # each [S/n, W]
-        his, los = _exprs_hi_lo(exprs, jnp.stack(leaf_shards), mode)
-        return jnp.stack([jax.lax.psum(his, AXIS_SLICES),
-                          jax.lax.psum(los, AXIS_SLICES)])
-
-    return _finalize_program(jax.shard_map(
-        per_shard, mesh=mesh,
-        in_specs=(P(AXIS_SLICES),) * n_leaves, out_specs=P(),
-        check_vma=(mode is None)),
-        f"count_exprs_pallas_n{len(exprs)}_k{n_leaves}")
-
-
 @_fair_dispatch
 def count_exprs_sharded(mesh: Mesh, exprs: tuple,
                         leaf_arrays: list[jax.Array]) -> list[int]:
@@ -774,14 +674,8 @@ def count_exprs_sharded(mesh: Mesh, exprs: tuple,
             mesh.shape[AXIS_SLICES]):
         raise ValueError("count_exprs_sharded: slice count above the"
                          " int32 hi/lo bound")
-    mode = _mesh_pallas_mode(mesh)
-    if mode is None:
-        from . import programs as programs_mod
-        fn = programs_mod.count_exprs_program(mesh, exprs,
-                                              len(leaf_arrays))
-    else:
-        fn = _count_exprs_sharded_fn(mesh, exprs, len(leaf_arrays),
-                                     mode)
+    from . import programs as programs_mod
+    fn = programs_mod.count_exprs_program(mesh, exprs, len(leaf_arrays))
     _note_dispatch(mesh, *leaf_arrays)
     return _run_hilo(fn, *leaf_arrays)
 
@@ -812,8 +706,6 @@ def fused_tree_sharded(mesh: Mesh, count_exprs: tuple,
     block. Returns (count values, per-TopN count lists).
 
     The old lane paid one host↔device sync per *call*; a tree pays one.
-    XLA-path only — the executor's batch lane falls back per call on
-    Pallas meshes (where the per-kind shard_map programs serve).
     """
     _dispatch_gate()
     if leaf_arrays and leaf_arrays[0].shape[0] > slice_chunk_bound(
@@ -838,33 +730,10 @@ def fused_tree_sharded(mesh: Mesh, count_exprs: tuple,
     return counts, out_topn
 
 
-@functools.lru_cache(maxsize=256)
-def _topn_exact_sharded_fn(mesh: Mesh, expr, n_leaves: int,
-                           mode: str | None):
-    def per_shard(rows, *leaf_shards):  # rows [S/n, R, W]
-        if n_leaves:
-            leaves = jnp.stack(leaf_shards)  # [L, S/n, W]
-        else:
-            leaves = jnp.zeros((0,) + rows.shape[::2], dtype=rows.dtype)
-        return _psum_hi_lo_rows(
-            _shard_topn_inter(expr, rows, leaves, mode))
-
-    return _finalize_program(jax.shard_map(
-        per_shard, mesh=mesh,
-        in_specs=(P(AXIS_SLICES),) * (n_leaves + 1),
-        out_specs=P(), check_vma=(mode is None)),
-        f"topn_exact_pallas_k{n_leaves}")
-
-
-def _shard_topn_inter(expr, rows, leaves, mode):
-    """Per-(slice, row) intersection counts for one shard — the shared
-    count body of the TopN programs (Pallas kernel or XLA fusion)."""
-    if mode is not None and leaves.shape[0] > _PALLAS_MAX_LEAVES:
-        mode = None
-    if mode is not None:
-        from ..ops import pallas_kernels
-        return pallas_kernels.topn_block_count_pallas(
-            expr, rows, leaves, interpret=(mode == "interpret"))
+def _shard_topn_inter(expr, rows, leaves):
+    """[S, R] per-(slice, row) intersection counts of candidate ``rows``
+    [S, R, W] against ``expr`` over ``leaves`` [L, S, W] — the shared
+    count body of the TopN programs."""
     words = rows
     if expr is not None:
         src = _eval_expr(expr, leaves)
@@ -880,26 +749,18 @@ def hilo_combine(hilo) -> list[int]:
     return ((arr[0] << 16) + arr[1]).ravel().tolist()
 
 
-def _psum_hi_lo_rows(per_slice):
-    """[S/n, R] per-slice counts → stacked [2, R] (hi, lo) 16-bit
-    halves, psum'd over the slice axis (the int32-safe reduction
-    split). ONE output array: each separate device output fetched
-    host-side costs its own round trip — returning (hi, lo) as two
-    arrays doubles every count/TopN query's sync cost."""
-    hi = jax.lax.psum(jnp.sum(per_slice >> 16, axis=0), AXIS_SLICES)
-    lo = jax.lax.psum(jnp.sum(per_slice & 0xFFFF, axis=0), AXIS_SLICES)
-    return jnp.stack([hi, lo])
-
-
-def _filtered_counts(expr, rows, leaves, threshold, tanimoto, mode):
-    """[S/n, R] intersection counts with the reference's per-slice
-    threshold/Tanimoto pruning applied (fragment.go:560-614 — a slice's
-    contribution drops when that slice's row count or intersection
-    count fails the bar; exact integer forms of the float comparisons)."""
-    inter = _shard_topn_inter(expr, rows, leaves, mode)   # [S/n, R]
-    rowc = _shard_topn_inter(None, rows, leaves[:0], mode)
-    srcc = _rows_popcount(expr, leaves, mode)             # [S/n]
-    s = srcc[:, None]                                     # [S/n, 1]
+def _filtered_counts(expr, rows, leaves, threshold, tanimoto):
+    """[S, R] intersection counts with the reference's per-slice
+    threshold/Tanimoto pruning applied BEFORE the slice reduction
+    (fragment.go:560-614 — a slice's contribution drops when that
+    slice's row count or intersection count fails the bar, then the
+    executor sums the survivors; exact integer forms of the float
+    comparisons, identical results). threshold/tanimoto are runtime
+    scalars — one compiled program per (mesh, expr)."""
+    inter = _shard_topn_inter(expr, rows, leaves)         # [S, R]
+    rowc = _shard_topn_inter(None, rows, leaves[:0])
+    srcc = _rows_popcount(expr, leaves)                   # [S]
+    s = srcc[:, None]                                     # [S, 1]
     # cnt > srcc·t/100  ∧  cnt < srcc·100/t  ∧  inter > 0
     # ∧  ceil(100·inter / (cnt + srcc − inter)) > t
     keep_tan = ((100 * rowc > s * tanimoto)
@@ -911,49 +772,21 @@ def _filtered_counts(expr, rows, leaves, threshold, tanimoto, mode):
     return jnp.where(keep, inter, 0)
 
 
-@functools.lru_cache(maxsize=256)
-def _topn_filtered_sharded_fn(mesh: Mesh, expr, n_leaves: int,
-                              mode: str | None):
-    """Per-row counts with the reference's per-slice threshold/Tanimoto
-    pruning applied BEFORE the slice reduction (fragment.go:560-614 —
-    the per-slice algorithm drops a slice's contribution when that
-    slice's row count or intersection count fails the bar, then the
-    executor sums the survivors; exact integer forms of the float
-    comparisons, identical results). threshold/tanimoto are runtime
-    scalars — one compiled program per (mesh, expr)."""
-
-    def per_shard(threshold, tanimoto, rows, *leaf_shards):
-        return _psum_hi_lo_rows(_filtered_counts(
-            expr, rows, jnp.stack(leaf_shards), threshold, tanimoto,
-            mode))
-
-    return _finalize_program(jax.shard_map(
-        per_shard, mesh=mesh,
-        in_specs=(P(), P()) + (P(AXIS_SLICES),) * (n_leaves + 1),
-        out_specs=P(), check_vma=(mode is None)),
-        f"topn_filtered_pallas_k{n_leaves}")
-
-
 @_fair_dispatch
 def topn_filtered_sharded(mesh: Mesh, expr, rows: jax.Array,
                           leaf_arrays: list[jax.Array],
                           threshold: int = 1,
                           tanimoto: int = 0) -> list[int]:
     """TopN counts with per-slice threshold/Tanimoto pruning on device
-    (see _topn_filtered_sharded_fn). Same residency contract as
+    (see _filtered_counts). Same residency contract as
     topn_exact_sharded."""
     _dispatch_gate()
     if rows.shape[0] > slice_chunk_bound(mesh.shape[AXIS_SLICES]):
         raise ValueError("topn_filtered_sharded: slice count above the"
                          " int32 hi/lo bound")
-    mode = _mesh_pallas_mode(mesh)
-    if mode is None:
-        from . import programs as programs_mod
-        fn = programs_mod.topn_program(mesh, expr, len(leaf_arrays),
-                                       filtered=True)
-    else:
-        fn = _topn_filtered_sharded_fn(mesh, expr, len(leaf_arrays),
-                                       mode)
+    from . import programs as programs_mod
+    fn = programs_mod.topn_program(mesh, expr, len(leaf_arrays),
+                                   filtered=True)
     threshold = min(threshold, 2**31 - 1)  # counts never exceed 2^31
     _note_dispatch(mesh, rows, *leaf_arrays)
     return _run_hilo(fn, jnp.int32(threshold), jnp.int32(tanimoto),
@@ -972,13 +805,9 @@ def topn_exact_sharded(mesh: Mesh, expr, rows: jax.Array,
     if rows.shape[0] > slice_chunk_bound(mesh.shape[AXIS_SLICES]):
         raise ValueError("topn_exact_sharded: slice count above the"
                          " int32 hi/lo bound — use topn_exact")
-    mode = _mesh_pallas_mode(mesh)
-    if mode is None:
-        from . import programs as programs_mod
-        fn = programs_mod.topn_program(mesh, expr, len(leaf_arrays),
-                                       filtered=False)
-    else:
-        fn = _topn_exact_sharded_fn(mesh, expr, len(leaf_arrays), mode)
+    from . import programs as programs_mod
+    fn = programs_mod.topn_program(mesh, expr, len(leaf_arrays),
+                                   filtered=False)
     _note_dispatch(mesh, rows, *leaf_arrays)
     return _run_hilo(fn, rows, *leaf_arrays)[:rows.shape[1]]
 
@@ -992,19 +821,12 @@ def topn_topk_sharded(mesh: Mesh, expr, rows: jax.Array,
     (programs.topn_topk_program), so the host fetches [3, k] instead
     of the whole [2, R] count table. Returns (counts, row indices),
     count-descending with ascending-index tie-break — the host
-    pairs_sort order. Pallas meshes have no top-k kernel; there the
-    exact-count program runs and the selection folds host-side, same
-    contract."""
+    pairs_sort order."""
     _dispatch_gate()
     if rows.shape[0] > slice_chunk_bound(mesh.shape[AXIS_SLICES]):
         raise ValueError("topn_topk_sharded: slice count above the"
                          " int32 hi/lo bound")
     k = max(1, min(int(k), int(rows.shape[1])))
-    if _mesh_pallas_mode(mesh) is not None:
-        counts = topn_exact_sharded(mesh, expr, rows, leaf_arrays)
-        order = np.lexsort((np.arange(len(counts)),
-                            -np.asarray(counts)))[:k]
-        return [counts[i] for i in order.tolist()], order.tolist()
     from . import programs as programs_mod
     fn = programs_mod.topn_topk_program(mesh, expr, len(leaf_arrays), k)
     _note_dispatch(mesh, rows, *leaf_arrays)
@@ -1069,66 +891,6 @@ def _eval_expr(expr, leaves):
                              _eval_expr(expr[2], leaves))
 
 
-@functools.lru_cache(maxsize=256)
-def _topn_exact_fn_cached(mesh: Mesh, expr, mode: str | None):
-    def per_shard(rows, leaves):  # rows: [S/n, R, W]; leaves: [L, S/n, W]
-        return _psum_hi_lo_rows(
-            _shard_topn_inter(expr, rows, leaves, mode))
-
-    return _finalize_program(jax.shard_map(
-        per_shard, mesh=mesh,
-        in_specs=(P(AXIS_SLICES), P(None, AXIS_SLICES)),
-        out_specs=P(), check_vma=(mode is None)), "topn_exact_block_pallas")
-
-
-@functools.lru_cache(maxsize=256)
-def _topn_filtered_fn_cached(mesh: Mesh, expr, mode: str | None):
-    def per_shard(threshold, tanimoto, rows, leaves):
-        return _psum_hi_lo_rows(_filtered_counts(
-            expr, rows, leaves, threshold, tanimoto, mode))
-
-    return _finalize_program(jax.shard_map(
-        per_shard, mesh=mesh,
-        in_specs=(P(), P(), P(AXIS_SLICES), P(None, AXIS_SLICES)),
-        out_specs=P(), check_vma=(mode is None)), "topn_filtered_block_pallas")
-
-
-def topn_filtered_fn(mesh: Mesh, expr):
-    """The streaming-layout filtered TopN program: ``(threshold,
-    tanimoto, rows [S, R, W], leaves [L, S, W]) → stacked [2, R]
-    per-row (hi, lo)`` (decode via hilo_combine),
-    with per-slice threshold/Tanimoto pruning before the reduction.
-    Public for the pod layer (parallel.multihost), like topn_exact_fn."""
-    mode = _mesh_pallas_mode(mesh)
-    if mode is None:
-        from . import programs as programs_mod
-        return programs_mod.topn_block_program(mesh, expr,
-                                               filtered=True)
-    return _topn_filtered_fn_cached(mesh, expr, mode)
-
-
-def topn_exact_fn(mesh: Mesh, expr):
-    """Exact candidate counts across slices, one psum-reduced program.
-
-    rows [S, R, W] (candidate row blocks per slice) → stacked [2, R]
-    per-row (hi, lo) — decode via hilo_combine
-    16-bit halves of ``popcount(row ∩ expr)`` (or plain row popcount
-    when expr is None), summed over every slice — the device form of
-    the executor's TopN exact-count re-query (executor.go:273-310
-    second phase). Per-(slice, row) counts ≤ 2^20 are split 16/16
-    before the psum so int32 holds up to 2^15 slices per call (callers
-    chunk above that). On TPU the per-shard body is the fused Pallas
-    TopN block kernel. Public: the pod layer (parallel.multihost)
-    feeds these programs process-local shards.
-    """
-    mode = _mesh_pallas_mode(mesh)
-    if mode is None:
-        from . import programs as programs_mod
-        return programs_mod.topn_block_program(mesh, expr,
-                                               filtered=False)
-    return _topn_exact_fn_cached(mesh, expr, mode)
-
-
 @_fair_dispatch
 def materialize_expr_sharded(mesh: Mesh, expr,
                              leaf_arrays: list[jax.Array]) -> np.ndarray:
@@ -1137,8 +899,7 @@ def materialize_expr_sharded(mesh: Mesh, expr,
     BASELINE config 2's Union/Difference over many rows), fetched to
     host for roaring repack. No count reduction → no slice-count bound;
     wide folds reduce associatively on device (_eval_expr's lax.reduce
-    path). Always the global-view catalogue program (no Pallas body
-    exists for materialization).
+    path).
     """
     _dispatch_gate()
     from . import programs as programs_mod
@@ -1194,17 +955,18 @@ def topn_exact(mesh: Mesh, expr, rows: np.ndarray,
     any tiling is exact.
     """
     _dispatch_gate()
+    from . import programs as programs_mod
     n_dev = mesh.shape[AXIS_SLICES]
     filtered = threshold > 1 or tanimoto > 0
     if filtered:
         # Counts never exceed 2^31, so clamping is semantically exact
         # (and jnp.int32 would raise on larger Python ints).
         threshold = min(threshold, 2**31 - 1)
-        fn = functools.partial(topn_filtered_fn(mesh, expr),
-                               jnp.int32(threshold), jnp.int32(tanimoto))
+        fn = functools.partial(
+            programs_mod.topn_block_program(mesh, expr, filtered=True),
+            jnp.int32(threshold), jnp.int32(tanimoto))
     else:
-        fn = topn_exact_fn(mesh, expr)
-    from . import programs as programs_mod
+        fn = programs_mod.topn_block_program(mesh, expr, filtered=False)
     n_slices, n_rows, n_words = rows.shape
     slice_chunk = min(slice_chunk_bound(n_dev), n_slices) or 1
     row_chunk = max(1, TOPN_BLOCK_BYTES // (slice_chunk * n_words * 4))
@@ -1313,10 +1075,5 @@ def query_step(mesh: Mesh, a: jax.Array, b: jax.Array, rows: jax.Array,
 # compile_stats()'s hit/miss aggregation (the global-view catalogue's
 # caches live in parallel.programs.PROGRAM_CACHES and are folded in by
 # _all_program_caches()).
-_PROGRAM_CACHES = (
-    _densify_sharded_fn, _count_fn, _count_expr_fn_cached,
-    _count_exprs_fn_cached, _count_exprs_sharded_fn,
-    _topn_exact_sharded_fn, _topn_filtered_sharded_fn,
-    _topn_exact_fn_cached, _topn_filtered_fn_cached, _topn_fn,
-    _query_step_fn,
-)
+_PROGRAM_CACHES = (_densify_sharded_fn, _count_fn, _topn_fn,
+                   _query_step_fn)

@@ -16,8 +16,9 @@ Design (scaling-book recipe):
   (``jax.make_array_from_process_local_data``) — slice placement is
   aligned so the slices a host serves are the slices its chips hold;
 - the jitted programs are the SAME ones the single-host executor uses
-  (parallel.mesh.count_expr_fn / topn_exact_fn): under SPMD every
-  process runs the identical program and the psum spans the pod.
+  (parallel.programs.count_exprs_block_program / topn_block_program):
+  under SPMD every process runs the identical program and the
+  reduction spans the pod.
 
 The coordinator/membership control plane stays host-side HTTP/gossip —
 metadata is not bandwidth-bound (SURVEY.md §5).
@@ -50,6 +51,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from . import mesh as mesh_mod
+from . import programs
 
 _initialized = False
 
@@ -144,7 +146,6 @@ def _pad_local(local: np.ndarray, axis: int) -> np.ndarray:
     global order. Deterministic from the shard length alone, so every
     process picks the same bucket (the shard-uniformity allgather has
     already pinned the lengths equal)."""
-    from . import programs
     per_dev = len(jax.devices()) // jax.process_count()
     target = programs.slice_bucket(local.shape[axis], per_dev)
     # The GLOBAL row count (target × n_procs) must stay within the
@@ -186,7 +187,7 @@ def count_exprs(mesh: Mesh, exprs: tuple,
     leaf shard, one collective program per chunk (the pod form of
     mesh.count_exprs_sharded — K counts, one dispatch)."""
     _assert_uniform_shards(*local_leaves.shape, len(exprs))
-    fn = mesh_mod.count_exprs_fn(mesh, tuple(exprs))
+    fn = programs.count_exprs_block_program(mesh, tuple(exprs))
     totals = [0] * len(exprs)
     step = _local_chunk()
     for off in range(0, max(local_leaves.shape[1], 1), step):
@@ -203,7 +204,7 @@ def topn_exact(mesh: Mesh, expr, local_rows: np.ndarray,
                tanimoto: int = 0) -> list[int]:
     """Pod-wide TopN exact counts: local shards in, global counts out.
     threshold>1 / tanimoto engage the per-slice pruning program
-    (mesh.topn_filtered_fn) — masks are per-slice, so shard-local
+    (programs.topn_block_program) — masks are per-slice, so shard-local
     evaluation composes exactly.
 
     Chunks slices (int32 bound) and candidate rows (device-block byte
@@ -219,10 +220,11 @@ def topn_exact(mesh: Mesh, expr, local_rows: np.ndarray,
     filtered = threshold > 1 or tanimoto > 0
     if filtered:
         threshold = min(threshold, 2**31 - 1)  # counts never exceed 2^31
-        fn = functools.partial(mesh_mod.topn_filtered_fn(mesh, expr),
-                               jnp.int32(threshold), jnp.int32(tanimoto))
+        fn = functools.partial(
+            programs.topn_block_program(mesh, expr, filtered=True),
+            jnp.int32(threshold), jnp.int32(tanimoto))
     else:
-        fn = mesh_mod.topn_exact_fn(mesh, expr)
+        fn = programs.topn_block_program(mesh, expr, filtered=False)
     s_step = _local_chunk()
     r_step = max(1, mesh_mod.TOPN_BLOCK_BYTES
                  // (max(s_step, 1) * n_words * 4))

@@ -34,11 +34,9 @@ global array assembled from its local shard
 (``jax.make_array_from_process_local_data``), and the in-program
 reduction spans the pod.
 
-Pallas-bodied variants (the TPU fused kernels) keep their ``shard_map``
-form in ``parallel.mesh`` — ``pallas_call`` is a per-shard primitive —
-and the dispatch layer picks per backend; this catalogue is the XLA
-serving path (the recorded A/B winner) and the one tests exercise on
-the virtual CPU mesh.
+This catalogue is the only family of query programs: the dispatch
+entry points in ``parallel.mesh`` build from it on every backend. On
+the chip its fusions stream at ~90 % of the HBM roofline (PERF.md).
 
 Every builder is ``lru_cache``'d and finalized through
 ``mesh._finalize_program`` so the compile-cache counters
@@ -142,7 +140,7 @@ def count_exprs_program(mesh, exprs: tuple, n_leaves: int):
         leaves = jnp.stack([
             jax.lax.with_sharding_constraint(a, sh)
             for a in leaf_shards])
-        his, los = mesh_mod._exprs_hi_lo(exprs, leaves, None)
+        his, los = mesh_mod._exprs_hi_lo(exprs, leaves)
         return jnp.stack([his, los])
 
     return mesh_mod._finalize_program(
@@ -153,13 +151,23 @@ def count_exprs_program(mesh, exprs: tuple, n_leaves: int):
 def count_exprs_block_program(mesh, exprs: tuple):
     """The streaming-block form: one [L, S_b, W] stacked leaf block
     (freshly packed per query — the operand is DONATED on accelerators)
-    → [2, K]. Public shape contract of mesh.count_expr_fn, reused by
-    the multi-host pod path with process-local shards."""
+    → stacked [2, K] (hi, lo) 16-bit halves of each expression
+    bitmap's count, one output = one host fetch (decode via
+    mesh.hilo_combine).
+
+    An expr is a hashable tree: ``("leaf", i)`` selects leaf block i,
+    ``(op, a, b)`` combines subtrees with a bitwise op from
+    kernels._BITWISE. The whole PQL bitmap expression (e.g.
+    Count(Intersect(Bitmap, Bitmap))) is evaluated elementwise over
+    every slice at once and reduced in-program, replacing the
+    reference's per-slice goroutine map + sum reduce
+    (executor.go:568-597,1103-1236). The pod layer
+    (parallel.multihost) feeds this program process-local shards."""
     sh = NamedSharding(mesh, P(None, mesh_mod.AXIS_SLICES))
 
     def fn(leaves):
         leaves = jax.lax.with_sharding_constraint(leaves, sh)
-        his, los = mesh_mod._exprs_hi_lo(exprs, leaves, None)
+        his, los = mesh_mod._exprs_hi_lo(exprs, leaves)
         return jnp.stack([his, los])
 
     return mesh_mod._finalize_program(
@@ -186,12 +194,12 @@ def topn_program(mesh, expr, n_leaves: int, filtered: bool):
             rows = jax.lax.with_sharding_constraint(rows, sh)
             return _hi_lo_rows(mesh_mod._filtered_counts(
                 expr, rows, stack_leaves(rows, leaf_shards),
-                threshold, tanimoto, None))
+                threshold, tanimoto))
     else:
         def fn(rows, *leaf_shards):
             rows = jax.lax.with_sharding_constraint(rows, sh)
             return _hi_lo_rows(mesh_mod._shard_topn_inter(
-                expr, rows, stack_leaves(rows, leaf_shards), None))
+                expr, rows, stack_leaves(rows, leaf_shards)))
 
     return mesh_mod._finalize_program(
         fn, f"topn_{'filtered' if filtered else 'exact'}_k{n_leaves}")
@@ -200,8 +208,16 @@ def topn_program(mesh, expr, n_leaves: int, filtered: bool):
 @functools.lru_cache(maxsize=256)
 def topn_block_program(mesh, expr, filtered: bool):
     """Streaming TopN form: rows [S_b, R, W] + one [L, S_b, W] leaf
-    block, both freshly packed per query (donated on accelerators).
-    The pod path's shape contract (mesh.topn_exact_fn)."""
+    block, both freshly packed per query (donated on accelerators) →
+    stacked [2, R] per-row (hi, lo) 16-bit halves of
+    ``popcount(row ∩ expr)`` (plain row popcount when expr is None)
+    summed over every slice — the device form of the executor's TopN
+    exact-count re-query (executor.go:273-310 second phase). Counts
+    ≤ 2^20 per (slice, row) are split 16/16 before the reduction so
+    int32 holds up to 2^15 slices per call (callers chunk above that).
+    ``filtered`` takes ``(threshold, tanimoto)`` first and prunes per
+    slice before the reduction (mesh._filtered_counts). The pod layer
+    (parallel.multihost) feeds this program process-local shards."""
     sh = _slice_sharding(mesh)
     lsh = NamedSharding(mesh, P(None, mesh_mod.AXIS_SLICES))
 
@@ -210,14 +226,14 @@ def topn_block_program(mesh, expr, filtered: bool):
             rows = jax.lax.with_sharding_constraint(rows, sh)
             leaves = jax.lax.with_sharding_constraint(leaves, lsh)
             return _hi_lo_rows(mesh_mod._filtered_counts(
-                expr, rows, leaves, threshold, tanimoto, None))
+                expr, rows, leaves, threshold, tanimoto))
         donate = _donate_kw(mesh, 2, skip=2)
     else:
         def fn(rows, leaves):
             rows = jax.lax.with_sharding_constraint(rows, sh)
             leaves = jax.lax.with_sharding_constraint(leaves, lsh)
             return _hi_lo_rows(mesh_mod._shard_topn_inter(
-                expr, rows, leaves, None))
+                expr, rows, leaves))
         donate = _donate_kw(mesh, 2)
 
     return mesh_mod._finalize_program(
@@ -247,7 +263,7 @@ def topn_topk_program(mesh, expr, n_leaves: int, k: int):
                 for a in leaf_shards])
         else:
             leaves = jnp.zeros((0,) + rows.shape[::2], dtype=rows.dtype)
-        per_slice = mesh_mod._shard_topn_inter(expr, rows, leaves, None)
+        per_slice = mesh_mod._shard_topn_inter(expr, rows, leaves)
         hi = jnp.sum(per_slice >> 16, axis=0).astype(jnp.int32)
         lo = jnp.sum(per_slice & 0xFFFF, axis=0).astype(jnp.int32)
         # Normalize the halves before the sort: the lo-sum reaches
@@ -333,13 +349,12 @@ def fused_program(mesh, count_exprs: tuple, topn_exprs: tuple,
                                dtype=rows_blocks[0].dtype)
         parts_hi, parts_lo = [], []
         if count_exprs:
-            his, los = mesh_mod._exprs_hi_lo(count_exprs, leaves, None)
+            his, los = mesh_mod._exprs_hi_lo(count_exprs, leaves)
             parts_hi.append(his)
             parts_lo.append(los)
         for (expr_t, _n_rows), rows in zip(topn_exprs, rows_blocks):
             rows = jax.lax.with_sharding_constraint(rows, sh)
-            per_slice = mesh_mod._shard_topn_inter(expr_t, rows,
-                                                   leaves, None)
+            per_slice = mesh_mod._shard_topn_inter(expr_t, rows, leaves)
             parts_hi.append(jnp.sum(per_slice >> 16, axis=0))
             parts_lo.append(jnp.sum(per_slice & 0xFFFF, axis=0))
         return jnp.stack([jnp.concatenate(parts_hi),

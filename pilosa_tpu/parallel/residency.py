@@ -252,8 +252,8 @@ def candidate_block(mesh, key: tuple, frags,
         n = _bucketed_slices(mesh, len(frags))
         # Extract once as sparse (word idx, value) pairs; the gate
         # then picks the transfer representation — bucketed sparse +
-        # device densify (3-6x cold-upload win at sparse shapes,
-        # benchmarks/DENSIFY.json) or host dense scatter.
+        # device densify (far fewer bytes to pack and ship at sparse
+        # shapes) or host dense scatter.
         mode = mesh_mod.densify_mode()
         with sched_context.stage("pack"):
             pairs: list = []

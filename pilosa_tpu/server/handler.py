@@ -1324,7 +1324,7 @@ class Handler:
         page's cursor is the returned ``next``. ``?scope=cluster``
         fans out to every node and merges the streams by arrival
         wall-clock (obs.capture.merge_streams) — the merged form
-        benchmarks/replay.py re-issues."""
+        ``pilosa-tpu replay`` re-issues."""
         try:
             since = int(req.query.get("since", "0"))
             limit = int(req.query.get("limit", "500"))

@@ -84,8 +84,7 @@ HASH_BLOCK_SIZE = 100
 # Run the cardinality-adaptive container-representation pass
 # (roaring.Bitmap.optimize — array/bitmap/run selection per the Roaring
 # papers) after bulk imports. On by default; settable off to pin the
-# two-kind vintage behavior for comparisons (benchmarks/suite.py
-# container_mix measures exactly this delta).
+# two-kind vintage behavior for comparisons.
 _RUN_OPTIMIZE = os.environ.get("PILOSA_TPU_RUN_CONTAINERS", "1") != "0"
 
 

@@ -294,10 +294,10 @@ class SentinelConfig:
     ``interval`` paces evaluation; a robust-z rule fires when the
     recent ``window`` median sits ``zscore`` MAD-scaled deviations
     past the trailing ``baseline`` median AND at least ``min_ratio``
-    times it; ``manifest`` points at a committed benchmarks/
-    MANIFEST.json whose envelope (× ``manifest_tolerance``) live
-    medians must stay inside; ``retrip`` rate-limits re-fires per
-    series."""
+    times it; ``manifest`` is the path of a JSON file of recorded
+    metrics whose envelope (× ``manifest_tolerance``) live medians
+    must stay inside (empty: no envelope rule); ``retrip`` rate-limits
+    re-fires per series."""
     enabled: bool = True
     interval: float = 30.0
     window: float = 120.0

@@ -1089,8 +1089,8 @@ class TestSparseUploadPath:
         assert got_dense == want
 
     def test_gate_rejects_dense_blocks(self):
-        """A block with a dense row must take the dense path (the
-        measured 0.5x sparse LOSS at G=128, benchmarks/DENSIFY.json)."""
+        """A block with a dense row must take the dense path (sparse
+        loses outright by G=128)."""
         import numpy as np
         from pilosa_tpu.ops import packed
         dense_row = (np.arange(0, 32768, dtype=np.int32),

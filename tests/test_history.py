@@ -717,9 +717,10 @@ class TestSentinelEndToEnd:
         blackbox = Blackbox(str(tmp_path / "bb"),
                             state_fn=lambda: {"ok": True},
                             interval_s=3600, node="local")
-        # min_ratio 3: real write timings jitter across adjacent
+        # min_ratio 3: write timings jitter across adjacent
         # power-of-2 histogram buckets (a 2x "shift"); the injected
-        # 60ms cliff is ~64x, so the rule still fires loudly.
+        # cliff, 2 ms to 60 ms, is four buckets up (16x), so the rule
+        # still fires loudly.
         sentinel = Sentinel(
             hist, registry=handler.registry, tracer=handler.tracer,
             sampler=sampler, blackbox=blackbox, interval_s=3600,
@@ -732,14 +733,25 @@ class TestSentinelEndToEnd:
                 f'SetBit(rowID=1, frame="f", columnID={n})'.encode())
             assert st == 200
 
-        # Baseline: fast writes, one history tick per (fake) second.
+        # The history samples the process-wide registry, which holds
+        # what earlier tests of this worker left in it. Counters and
+        # histograms enter as deltas between ticks, but a gauge enters
+        # as its value: a tenant burn rate that a server test left
+        # high would be an (honest) finding of the quiet baseline.
+        for _labels, child in obs_metrics.TENANT_SLO_BURN._label_dicts():
+            child.set(0.0)
+        # Baseline: one history tick per (fake) second. Both levels of
+        # the step come from failpoints, 2 ms here and 60 ms below: a
+        # bare write sits in the histogram's first bucket (1 ms), where
+        # a loaded machine's 2 ms stalls alone are a 3x step.
         now = time.time()
         col = 0
-        for _ in range(100):
-            write(col)
-            col += 1
-            hist.sample(now=now)
-            now += 1
+        with failpoints.injected("wal.append", "delay(2ms)"):
+            for _ in range(100):
+                write(col)
+                col += 1
+                hist.sample(now=now)
+                now += 1
         assert sentinel.check(now=now) == []
         # The cliff: every WAL append pays an injected 60ms delay.
         with failpoints.injected("wal.append", "delay(60ms)"):

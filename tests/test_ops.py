@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from pilosa_tpu import SLICE_WIDTH
-from pilosa_tpu.ops import kernels, packed, pallas_kernels
+from pilosa_tpu.ops import kernels, packed
 from pilosa_tpu.storage.roaring import Bitmap
 
 
@@ -104,28 +104,6 @@ class TestKernelParity:
         assert int(np.asarray(kernels.popcount_rows(w))) == b.count()
         m = np.stack([w, np.zeros_like(w)])
         assert list(np.asarray(kernels.popcount_rows(m))) == [b.count(), 0]
-
-
-class TestPallas:
-    """Pallas kernels run in interpret mode off-TPU; parity vs XLA path."""
-
-    @pytest.mark.parametrize("op", kernels.OPS)
-    def test_pallas_count_parity(self, op):
-        rng = np.random.default_rng(11)
-        a = rng.integers(0, 1 << 32, (17, 5000), dtype=np.uint32)
-        b = rng.integers(0, 1 << 32, (17, 5000), dtype=np.uint32)
-        got = np.asarray(pallas_kernels.op_count_rows_pallas(
-            op, a, b, interpret=True))
-        want = np.asarray(kernels.op_count_rows(op, a, b))
-        assert np.array_equal(got, want)
-
-    def test_pallas_1d(self):
-        rng = np.random.default_rng(12)
-        a = rng.integers(0, 1 << 32, 4096, dtype=np.uint32)
-        b = rng.integers(0, 1 << 32, 4096, dtype=np.uint32)
-        got = int(np.asarray(pallas_kernels.op_count_rows_pallas(
-            "and", a, b, interpret=True)))
-        assert got == int(np.bitwise_count(a & b).sum())
 
 
 class TestCountTotal:

@@ -110,6 +110,122 @@ class TestTopKProgram:
         assert counts == [131070, 65536]
 
 
+EXPR = ("or", ("and", ("leaf", 0), ("leaf", 1)),
+        ("andnot", ("leaf", 2), ("leaf", 0)))
+
+
+def _eval(expr, leaves):
+    if expr[0] == "leaf":
+        return leaves[expr[1]]
+    f = {"and": np.bitwise_and, "or": np.bitwise_or,
+         "xor": np.bitwise_xor,
+         "andnot": lambda a, b: a & ~b}[expr[0]]
+    return f(_eval(expr[1], leaves), _eval(expr[2], leaves))
+
+
+def _block(seed=7, L=3, S=16, R=9, W=384):
+    rng = np.random.default_rng(seed)
+    leaves = rng.integers(0, 2**32, size=(L, S, W), dtype=np.uint32)
+    rows = rng.integers(0, 2**32, size=(S, R, W), dtype=np.uint32)
+    return leaves, rows
+
+
+def _filtered_want(expr, rows, leaves, threshold, tanimoto):
+    """Per-slice threshold/Tanimoto pruning, then the slice sum — the
+    host reference of mesh._filtered_counts."""
+    src = _eval(expr, leaves)
+    inter = np.bitwise_count(rows & src[:, None, :]).sum(axis=-1)
+    rowc = np.bitwise_count(rows).sum(axis=-1)
+    srcc = np.bitwise_count(src).sum(axis=-1)[:, None]
+    if tanimoto:
+        keep = ((100 * rowc > srcc * tanimoto)
+                & (rowc * tanimoto < srcc * 100) & (inter > 0)
+                & (100 * inter > tanimoto * (rowc + srcc - inter)))
+    else:
+        keep = (rowc >= threshold) & (inter >= threshold)
+    return np.where(keep, inter, 0).sum(axis=0).tolist()
+
+
+class TestDispatchEntryPoints:
+    """The mesh entry points against numpy at program level, on the
+    8-device mesh: each builds its one catalogue program."""
+
+    @pytest.mark.parametrize("expr,shape", [
+        (EXPR, (3, 16, 384)),
+        (("leaf", 2), (3, 16, 384)),
+        # Slices that do not divide the mesh and words that fill no
+        # vector lane must pad losslessly.
+        (("xor", ("leaf", 0), ("leaf", 1)), (2, 5, 130)),
+        # A wide fold takes _eval_expr's lax.reduce form.
+        (("or", ("or", ("or", ("leaf", 0), ("leaf", 1)), ("leaf", 2)),
+          ("leaf", 3)), (4, 13, 257)),
+    ], ids=["multi_leaf", "single_leaf", "unaligned", "wide_fold"])
+    def test_count_expr(self, expr, shape):
+        leaves, _ = _block(8, *shape[:2], R=1, W=shape[2])
+        m = mesh_mod.make_mesh(8)
+        assert mesh_mod.count_expr(m, expr, leaves) == _popcount(
+            _eval(expr, leaves))
+
+    @pytest.mark.parametrize("expr,threshold,tanimoto", [
+        (EXPR, 1, 0), (None, 1, 0), (EXPR, 3, 0), (EXPR, 1, 50),
+    ], ids=["expr", "plain_popcount", "threshold", "tanimoto"])
+    def test_topn_exact(self, expr, threshold, tanimoto):
+        leaves, rows = _block()
+        m = mesh_mod.make_mesh(8)
+        if expr is None:
+            want = np.bitwise_count(rows).sum(axis=(0, 2)).tolist()
+            leaves = None
+        else:
+            want = _filtered_want(expr, rows, leaves, threshold,
+                                  tanimoto)
+        assert mesh_mod.topn_exact(m, expr, rows, leaves,
+                                   threshold, tanimoto) == want
+
+    @pytest.mark.parametrize("threshold,tanimoto", [
+        (1, 0), (3, 0), (10**6, 0), (1, 5), (1, 50), (1, 99)])
+    def test_topn_filtered_sharded(self, threshold, tanimoto):
+        leaves, rows = _block()
+        m = mesh_mod.make_mesh(8)
+        got = mesh_mod.topn_filtered_sharded(
+            m, EXPR, mesh_mod.shard_slices(m, rows),
+            [mesh_mod.shard_slices(m, leaf) for leaf in leaves],
+            threshold=threshold, tanimoto=tanimoto)
+        assert got == _filtered_want(EXPR, rows, leaves, threshold,
+                                     tanimoto)
+
+    def test_topn_topk_with_source_selects_in_program(self):
+        leaves, rows = _block()
+        rows[:, 4] = rows[:, 2]  # a tie: the lower index wins
+        m = mesh_mod.make_mesh(8)
+        counts, idx = mesh_mod.topn_topk_sharded(
+            m, EXPR, mesh_mod.shard_slices(m, rows),
+            [mesh_mod.shard_slices(m, leaf) for leaf in leaves], 4)
+        want = _filtered_want(EXPR, rows, leaves, 1, 0)
+        order = np.lexsort((np.arange(len(want)), -np.asarray(want)))[:4]
+        assert idx == order.tolist()
+        assert counts == [want[i] for i in order]
+
+    def test_one_program_family_whatever_the_environment(
+            self, monkeypatch):
+        """There is one program per query kind and no variable picks
+        another: the name that used to select the Pallas twins (spelt
+        in two halves, so a search for its readers finds none) changes
+        neither the answers nor the programs that are compiled."""
+        monkeypatch.setenv("PILOSA_TPU_" + "PALLAS", "interpret")
+        leaves, rows = _block(11, W=136)  # shapes no other test compiles
+        m = mesh_mod.make_mesh(8)
+        arrs = [mesh_mod.shard_slices(m, leaf) for leaf in leaves]
+        exprs = (EXPR, ("xor", ("leaf", 1), ("leaf", 2)))
+        assert mesh_mod.count_exprs_sharded(m, exprs, arrs) == [
+            _popcount(_eval(e, leaves)) for e in exprs]
+        assert mesh_mod.topn_exact_sharded(
+            m, EXPR, mesh_mod.shard_slices(m, rows), arrs) \
+            == _filtered_want(EXPR, rows, leaves, 1, 0)
+        names = [e["program"] for e in mesh_mod.compile_log()]
+        assert "count_exprs_n2_k3" in names and "topn_exact_k3" in names
+        assert not [n for n in names if "pallas" in n]
+
+
 class TestExecutorFusedTree:
     """Count+TopN multi-op queries lower into ONE fused device program
     through the executor, and agree with the host path exactly."""

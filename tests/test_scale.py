@@ -69,10 +69,11 @@ def test_topn_exact_1b_columns_row_chunk_triggers(mesh, leaves):
     assert row_chunk == 2  # the guard is live at this scale
 
     calls = []
-    orig = mesh_mod.topn_exact_fn
+    from pilosa_tpu.parallel import programs
+    orig = programs.topn_block_program
 
-    def spy(mesh_, expr_):
-        fn = orig(mesh_, expr_)
+    def spy(mesh_, expr_, filtered):
+        fn = orig(mesh_, expr_, filtered=filtered)
 
         def wrapped(*a):
             calls.append(1)
@@ -84,7 +85,7 @@ def test_topn_exact_1b_columns_row_chunk_triggers(mesh, leaves):
     want = np.bitwise_count(
         rows & leaves[0][:, None, :]).sum(axis=(0, 2)).tolist()
     import unittest.mock as mock
-    with mock.patch.object(mesh_mod, "topn_exact_fn", spy):
+    with mock.patch.object(programs, "topn_block_program", spy):
         got = mesh_mod.topn_exact(mesh, expr, rows, src)
     assert got == want
     assert len(calls) == -(-n_rows // row_chunk)  # 3 chunked programs
