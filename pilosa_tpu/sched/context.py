@@ -29,10 +29,11 @@ is gone. The context travels between executor worker threads via
 
 from __future__ import annotations
 
+import os
+import random
 import sys
 import threading
 import time
-import uuid
 from contextlib import contextmanager
 from typing import Optional
 
@@ -378,6 +379,32 @@ def background_tick(loop: str):
 
 
 
+# -- query ids ---------------------------------------------------------------
+# A coordinator read's id is 64 bits from a generator seeded ONCE a
+# process from the kernel's pool: as unlikely to collide across the
+# nodes of a cluster as the truncated uuid4 it replaces, and drawn
+# without a system call. ``uuid.uuid4()`` is ``os.urandom(16)``, a
+# ``getrandom`` that CPython makes with the interpreter lock released:
+# alone 20 us, under eight serving threads the only point of a read's
+# ``setup`` stage at which the connection thread let go of the
+# interpreter and queued to get it back (1.58 of the stage's 1.65 ms;
+# PERF.md, PR 30). ``getrandbits`` is one C call under the lock, so
+# concurrent draws need no lock of their own. A forked child reseeds:
+# it must not replay its parent's sequence.
+def _seed_ids() -> None:
+    global _id_bits
+    _id_bits = random.Random(os.urandom(16)).getrandbits
+
+
+_seed_ids()
+os.register_at_fork(after_in_child=_seed_ids)
+
+
+def new_query_id() -> str:
+    """16 hex characters, 64 fresh bits."""
+    return "%016x" % _id_bits(64)
+
+
 class QueryContext:
     """Lifecycle state of one in-flight query."""
 
@@ -387,7 +414,7 @@ class QueryContext:
                  id: Optional[str] = None, remote: bool = False,
                  node: str = "", tenant: str = "",
                  clock: Optional[StageClock] = None):
-        self.id = id or uuid.uuid4().hex[:16]
+        self.id = id or new_query_id()
         self.pql = pql
         self.index = index
         self.lane = lane

@@ -32,6 +32,7 @@ from __future__ import annotations
 import io
 import re
 import socket
+import struct
 import sys
 import threading
 import time
@@ -143,7 +144,18 @@ class HTTPServer:
     IDLE_TIMEOUT_S = 120.0
 
     def _serve_conn(self, conn: socket.socket) -> None:
-        conn.settimeout(self.IDLE_TIMEOUT_S)
+        # The timeout is the kernel's, on a blocking socket, not
+        # Python's: under ``settimeout`` every recv and every send is
+        # TWO system calls (a poll, then the call), each made with the
+        # interpreter lock released, so a served read queued for the
+        # interpreter twice on the way in and twice on the way out
+        # where it has to once (PERF.md, PR 30). A timeout then
+        # surfaces as EAGAIN (BlockingIOError), not TimeoutError.
+        conn.settimeout(None)
+        whole, frac = divmod(self.IDLE_TIMEOUT_S, 1.0)
+        tv = struct.pack("ll", int(whole), int(frac * 1e6))
+        conn.setsockopt(socket.SOL_SOCKET, socket.SO_RCVTIMEO, tv)
+        conn.setsockopt(socket.SOL_SOCKET, socket.SO_SNDTIMEO, tv)
         buf = bytearray()
         need = 0
         t_recv = None   # clock reading of the recv that came last
@@ -156,7 +168,7 @@ class HTTPServer:
                     # quadratic in header finds).
                     try:
                         data = conn.recv(1 << 20)
-                    except TimeoutError:
+                    except BlockingIOError:
                         return
                     t_recv = time.perf_counter()
                     if not data:
@@ -183,7 +195,7 @@ class HTTPServer:
                     continue
                 try:
                     data = conn.recv(1 << 16)
-                except TimeoutError:
+                except BlockingIOError:
                     return  # idle past IDLE_TIMEOUT_S
                 t_recv = time.perf_counter()
                 if not data:

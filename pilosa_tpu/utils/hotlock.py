@@ -21,6 +21,24 @@ mutex to a thread that cannot use it at once. Use it where the critical
 section is a few bytecodes and never blocks; a section that can wait
 (I/O, another lock, a device) would make the waiters spin and keeps
 ``threading.Lock``. Context manager only, on purpose.
+
+When NOT to use it: on a lock that is merely taken often. The convoy
+needs waiters, i.e. a critical section long or frequent enough that
+threads arrive while it is held (the planner's, 16-32 times a request).
+A ``threading.Lock`` that a read takes once or twice around a few
+bytecodes is not one: under eight clients on the chip's host
+``FairDispatchQueue.acquire`` / ``release`` read 3.4 / 3.9 us a read and
+``CostModel.record``, which sorts a 64-deque under its lock, 12.8 us
+(segment timing, PERF.md PR 30), where the same read lost 1.6-2.0 ms at
+each of three places that let go of the interpreter WITHOUT any lock (a
+``getrandom``, a buffer's destructor, a ``poll``). Look there first: a
+stage that swells under load has a GIL release in it, and a mutex is
+only one way to have one. And a ``HotLock``'s waiters spin through the
+interpreter (``sleep(0)`` is a release and a re-acquire each turn): with
+many threads already queued for the GIL every turn is another trip to
+the back of that queue. Seven read-path locks swapped at once read
+worse in a CPU rehearsal (PR 30's issue); the chip was not asked, since
+its timing had cleared the locks.
 """
 
 from __future__ import annotations

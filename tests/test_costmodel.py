@@ -10,6 +10,7 @@ ends up on the device once its host answers have been observed.
 """
 
 import numpy as np
+import pytest
 
 from pilosa_tpu.ops.packed import WORDS_PER_SLICE
 from pilosa_tpu.parallel.costmodel import Calibration, CostModel
@@ -87,7 +88,6 @@ class TestDecision:
                              ).device_pays(bytes_)
 
     def test_every_constant_must_be_given(self):
-        import pytest
         with pytest.raises(TypeError):
             Calibration(sync_s=0.001, host_bps=1e9)  # no assumed rates
 
@@ -240,6 +240,40 @@ class TestFeedbackLoop:
         # After convergence the host cost is priced ~100x higher and
         # the device serves the query.
         assert m.device_pays(nbytes)
+
+    @pytest.mark.parametrize("leg,attr", sorted(CostModel._SCALE_ATTR.items()))
+    def test_the_drift_loop_folds_after_the_same_samples_to_the_same_scale(
+            self, leg, attr):
+        """The router's settling is part of ``setup_s``: a leg is re-priced
+        at its DRIFT_MIN_SAMPLES-th observation, not before, by the median
+        of the window ((n // 2)-th of the sorted ratios), the window starts
+        over, and a median inside the bound folds nothing."""
+        from pilosa_tpu.parallel import costmodel as cm
+        c = cal(sync_s=0.001)
+        m = CostModel(c)
+        ratios = [3.0 + 0.25 * i for i in range(cm.DRIFT_MIN_SAMPLES)]
+        for r in ratios[:-1]:
+            m.record(leg, 1.0, r)
+            assert getattr(c, attr) == 1.0 and m.recalibrations == 0
+        m.record(leg, 1.0, ratios[-1])
+        want = sorted(ratios)[len(ratios) // 2]
+        assert getattr(c, attr) == want and m.recalibrations == 1
+        assert m.drift_snapshot()[leg]["n"] == 0
+        # in bound: a full window and more, and nothing moves
+        for i in range(100):
+            m.record(leg, 1.0, 0.6 + (i % 14) * 0.1)
+        assert getattr(c, attr) == want and m.recalibrations == 1
+        assert m.drift_snapshot()[leg]["n"] == 64
+        # the window's median leaves the bound once 33 of its 64 have
+        for i in range(32):
+            m.record(leg, 1.0, 0.25)
+            assert m.recalibrations == 1
+        m.record(leg, 1.0, 0.25)
+        assert m.recalibrations == 2 and getattr(c, attr) == want * 0.25
+        # and the clamp holds both ways
+        for _ in range(10 * cm.DRIFT_MIN_SAMPLES):
+            m.record(leg, 1.0, 1e-9)
+        assert getattr(c, attr) == 1.0 / cm._SCALE_CLAMP
 
     def test_scales_clamped(self):
         from pilosa_tpu.parallel import costmodel as cm

@@ -762,6 +762,72 @@ class TestFairDispatch:
             t.join(timeout=5)
         assert order == ["b", "a", "b", "a", "a"]
 
+    def test_a_free_slot_is_never_waited_for_by_eight_threads_at_once(self):
+        """8 slots for 8 callers (every cell): ``waits`` stays 0, every
+        dispatch is counted, nothing is left in flight."""
+        from pilosa_tpu.parallel.mesh import FairDispatchQueue
+        q = FairDispatchQueue(8)
+
+        def caller():
+            for _ in range(500):
+                q.acquire("i")
+                q.release()
+
+        threads = [threading.Thread(target=caller) for _ in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+        assert q.state() == {"slots": 8, "inFlight": 0, "queued": 0,
+                             "dispatches": 4000, "waits": 0}
+
+    def test_a_cancelled_waiter_hands_its_slot_on(self):
+        """A query killed while queued leaves the queue without a slot,
+        and the slot it would have had goes to the waiter behind it."""
+        from pilosa_tpu.errors import QueryCancelledError
+        from pilosa_tpu.parallel.mesh import FairDispatchQueue
+        from pilosa_tpu.sched import QueryContext
+        from pilosa_tpu.sched import context as sched_context
+        q = FairDispatchQueue(1)
+        q.acquire("hold")
+        ctx = QueryContext(pql="Count()")
+        raised, order = [], []
+
+        def doomed():
+            with sched_context.use(ctx):
+                try:
+                    q.acquire("a")
+                    order.append("a")
+                except QueryCancelledError as e:
+                    raised.append(e)
+
+        def patient():
+            q.acquire("b")
+            order.append("b")
+
+        ta = threading.Thread(target=doomed)
+        ta.start()
+        deadline = time.monotonic() + 5
+        while q.state()["queued"] < 1:
+            assert time.monotonic() < deadline
+            time.sleep(0.001)
+        tb = threading.Thread(target=patient)
+        tb.start()
+        while q.state()["queued"] < 2:
+            assert time.monotonic() < deadline
+            time.sleep(0.001)
+        ctx.cancel("test")
+        ta.join(timeout=5)
+        assert raised and not ta.is_alive()
+        assert q.state()["queued"] == 1 and order == []
+        q.release()                     # the held slot goes to b, not to a
+        tb.join(timeout=5)
+        assert order == ["b"] and q.state()["inFlight"] == 1
+        q.release()
+        assert q.state() == {"slots": 1, "inFlight": 0, "queued": 0,
+                             "dispatches": 3, "waits": 2}
+
     def test_server_installs_and_uninstalls(self, tiered_solo):
         from pilosa_tpu.parallel import mesh as mesh_mod
         st = mesh_mod.fair_dispatch_state()
