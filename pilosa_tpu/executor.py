@@ -152,6 +152,60 @@ def _parse_timestamp(c: Call, key: str = "timestamp"
         raise PilosaError(f"invalid date: {v}")
 
 
+_VISIT_PROBE_SLICES = 16
+
+
+def _measure_host_visit_s() -> float:
+    """What the host path pays for one leaf row of one slice before it
+    reads a word (Calibration.host_visit_s). Timed on the executor's
+    own host fan-out — fragment look-up, row extraction, one slice-pool
+    task a slice, one native count a pair of rows — over a scratch
+    index of near-empty rows, so that the byte term is nothing and the
+    walk is all there is; a 2-leaf Count, the cheapest host form (three
+    leaves and more fold a container at a time in Python and cost
+    more a visit: the price is a floor). Each timed Count names rows no
+    earlier one did, so no result cache can answer it, and runs with no
+    query bound: the cost tree and stage clock of the query whose
+    thread calibrates see none of it."""
+    import tempfile
+
+    from .models.holder import Holder
+
+    n, reps = _VISIT_PROBE_SLICES, 4        # the first rep warms
+    # one bit in every 65536-column container of every slice, as any
+    # row of a real index with more than a handful of bits has
+    per = SLICE_WIDTH >> 16
+    n_rows = 2 * reps
+    rows = np.repeat(np.arange(n_rows, dtype=np.uint64), n * per)
+    cols = np.tile(np.arange(n * per, dtype=np.uint64)
+                   * np.uint64(1 << 16), n_rows)
+    best = float("inf")
+    with sched_context.use(None), \
+            tempfile.TemporaryDirectory(prefix="pilosa_visit_") as tmp:
+        holder = Holder(tmp)
+        holder.open()
+        ex = None
+        try:
+            holder.create_index("w").create_frame("f").import_bits(
+                rows, cols)
+            ex = Executor(holder, host="probe", use_mesh=False,
+                          result_cache_entries=0,
+                          cluster_cache_entries=0)
+            for rep in range(reps):
+                q = ("Count(Intersect(Bitmap(frame=\"f\", rowID=%d),"
+                     " Bitmap(frame=\"f\", rowID=%d)))"
+                     % (2 * rep, 2 * rep + 1))
+                t0 = time.perf_counter()
+                ex.execute("w", q, list(range(n)))
+                if rep:
+                    best = min(best, time.perf_counter() - t0)
+        finally:
+            if ex is not None:
+                ex.close()
+            holder.close()
+    return max(best / (2 * n), 1e-7)
+
+
 class Executor:
     """Executes PQL queries against a Holder, fanning out across a Cluster.
 
@@ -234,6 +288,9 @@ class Executor:
         # Deliberate host routings by the cost model (observability —
         # distinct from device_fallbacks, which count failures).
         self.cost_vetoes = 0
+        # The timed legs running now (_timed_leg): a set, because add
+        # and discard are atomic under the interpreter lock.
+        self._timed_legs: set = set()
         self._mesh = None  # lazy: built on first device-batched call
         self._mesh_failed_until = None  # backoff after backend failure
         # /debug/vars.mesh: how often make_mesh gave this executor its
@@ -1813,18 +1870,20 @@ class Executor:
             if (self.pod is not None and self.pod.is_coordinator
                     and not opt.pod_local):
                 return NotImplemented  # pod fan-out is not a host leg
-            t0 = time.perf_counter()
-            r = self._mapper_local(batch_slices, map_fn,
-                                   lambda prev, v: (prev or 0) + v)
-            note["host_elapsed"] = (note.get("host_elapsed", 0.0)
-                                    + time.perf_counter() - t0)
+            r, own_s, wall_s = self._timed_leg(
+                lambda: self._mapper_local(
+                    batch_slices, map_fn,
+                    lambda prev, v: (prev or 0) + v))
+            note["host_elapsed"] = note.get("host_elapsed", 0.0) + own_s
+            note["host_wall"] = note.get("host_wall", 0.0) + wall_s
             return r
 
         result = self._map_reduce(index, slices, c, opt, map_fn,
                                   lambda prev, v: (prev or 0) + v,
                                   local_fn=local_host_fn)
         if "host_elapsed" in note:
-            self._record_host_leg(note, note["host_elapsed"])
+            self._record_host_leg(note, note["host_elapsed"],
+                                  note["host_wall"])
         return result or 0
 
     # -- device-batched Count (TPU fast path) --------------------------------
@@ -1884,6 +1943,7 @@ class Executor:
         plan: list[tuple] = []       # ("count", expr) | ("topn", ...)
         topn_items: list[tuple] = []  # (expr, frame_name, ids)
         host_rows = 0  # per-call leaf rows: the host path's real bytes
+        count_rows = 0  # those of the Counts: their host walk is priced
         rows_bytes = 0  # accumulated candidate-block bytes in the plan
         j = start
 
@@ -1915,6 +1975,7 @@ class Executor:
                     break  # fuse the prefix that fits; rest per call
                 plan.append(("count", absorb(call_leaves, expr)))
                 host_rows += len(call_leaves)
+                count_rows += len(call_leaves)
                 j += 1
                 continue
             if c.name == "TopN" and self.pod is None:
@@ -1969,7 +2030,8 @@ class Executor:
         device_rows = (len(leaves)
                        + sum(len(ids) for _, _, ids in topn_items))
         if not self._device_pays(mesh, device_rows, len(slices),
-                                 cold_rows=cold, host_rows=host_rows):
+                                 cold_rows=cold, host_rows=host_rows,
+                                 host_visits=count_rows * len(slices)):
             return None
         try:
             arrs = self._leaf_device_arrays(mesh, index, leaves, slices,
@@ -2197,6 +2259,7 @@ class Executor:
             return None  # pod host legs own pod materialization
         pnode = getattr(c, "_plan_node", None)
         if pnode is not None and pnode.placement == "host":
+            self.cost_vetoes += 1   # the model's prices, the planner's hint
             return None  # planner priced the subtree cheaper on host
         if c.name == "Range" and c.condition_arg() is not None:
             return self._field_range_local_device_fn(index, c)
@@ -2318,7 +2381,15 @@ class Executor:
             return None
         pnode = getattr(child, "_plan_node", None)
         if pnode is not None and pnode.placement == "host":
-            return None  # planner priced the subtree cheaper on host
+            # The planner priced the subtree cheaper on the host, with
+            # the executor's constants: a veto like _device_pays', so
+            # counted with them, and its host leg is held against the
+            # planner's prediction (the hint is then corrected by the
+            # same loop, not trusted for ever).
+            self.cost_vetoes += 1
+            if note is not None and pnode.est_cost_s:
+                note["host_pred"] = pnode.est_cost_s
+            return None
         leaves: list[tuple] = []
         expr = self._compile_device_expr(index, child, leaves)
         if expr is None:
@@ -2346,36 +2417,39 @@ class Executor:
             mesh = self._mesh_or_none()  # backend init only past threshold
             if mesh is None:
                 return NotImplemented
+            from .parallel import mesh as mesh_mod
+            # Above the chunk bound the block is re-packed every query.
+            streaming = len(slices) > mesh_mod.slice_chunk_bound(
+                mesh.shape[mesh_mod.AXIS_SLICES])
             # One ``route`` entry for the whole decision (the callees'
             # own route stages collapse into it).
             with sched_context.stage("route"):
                 keys, found, cold = self._leaf_lookup(mesh, index,
                                                       leaves, slices)
-                if not self._device_pays(mesh, len(leaves), len(slices),
-                                         cold_rows=cold, note=note):
+                if not self._device_pays(
+                        mesh, len(leaves), len(slices), cold_rows=cold,
+                        note=note, streaming=streaming,
+                        host_visits=len(leaves) * len(slices)):
                     return NotImplemented  # calibrated: host faster
                 shard, budget = self._count_budget(slices)
                 if self._leaf_block_bytes(len(leaves), shard) > budget:
                     return NotImplemented  # oversized leaf set: host
-            from .parallel import mesh as mesh_mod
             try:
                 def run():
-                    if len(slices) <= mesh_mod.slice_chunk_bound(
-                            mesh.shape[mesh_mod.AXIS_SLICES]):
+                    if not streaming:
                         # Residency fast path: leaf slabs stay device-
-                        # resident across queries (budgeted HBM cache).
+                        # resident across queries (budgeted HBM cache);
+                        # the cold ones are filled here, once.
                         arrs = self._leaf_device_arrays(
                             mesh, index, leaves, slices, (keys, found))
                         return mesh_mod.count_expr_sharded(mesh, expr,
                                                            arrs)
                     block = self._pack_leaf_block(index, leaves, slices)
                     return mesh_mod.count_expr(mesh, expr, block)
-                # Feed the SAME cold-row estimate into the drift
-                # prediction — omitting it made every cold query look
-                # like drift and inflated device_scale.
                 return self._timed_device_leg(run, len(leaves),
                                               len(slices),
-                                              cold_rows=cold)
+                                              cold_rows=cold,
+                                              streaming=streaming)
             except Exception as e:  # noqa: BLE001 - device trouble ≠ node down
                 self._note_device_fallback("count_expr", e)
                 return NotImplemented
@@ -2392,7 +2466,8 @@ class Executor:
             from .parallel import costmodel
             try:
                 self.cost_model = costmodel.get_model(
-                    mesh, margin=self._cost_margin)
+                    mesh, _measure_host_visit_s,
+                    margin=self._cost_margin)
             except Exception:  # noqa: BLE001 - never fail a query on this
                 self._cost_model_enabled = False
                 return False
@@ -2405,44 +2480,60 @@ class Executor:
     def _device_pays(self, mesh, n_rows: int, n_slices: int,
                      cold_rows: int = 0, note: dict | None = None,
                      streaming: bool = False,
-                     host_rows: int | None = None) -> bool:
+                     host_rows: int | None = None,
+                     host_visits: int = 0) -> bool:
         """Calibrated routing veto: False when the host path clearly
         wins for a block of ``n_rows × n_slices`` packed rows on this
-        hardware. ``cold_rows`` of those are not device-resident and
-        must be packed + uploaded first — that, not the compute,
-        dominates. ``host_rows`` (fused multi-op trees) is the
+        hardware. ``cold_rows`` of those are not device-resident. A
+        slab the residency cache keeps is filled once, by the leg that
+        finds it cold, and read many times, where the host path is
+        paid on every read: its fill is no part of a read's price, and
+        a read with cold leaves is placed where the same read with
+        resident leaves is. (Priced against the read, the fill vetoed
+        the leg, the host answer filled nothing, and the next read of
+        the row was vetoed again.) Pack + upload stay in the price
+        only where they are paid on every query: a ``streaming`` leg,
+        and a slab larger than the whole residency budget, which is
+        never kept. ``host_rows`` (fused multi-op trees) is the
         PER-CALL leaf-row sum the host alternative would walk — the
         device block deduplicates shared leaves and pays ONE crossing
         for the whole tree, so pricing the host on the deduplicated
         bytes over-charged the mesh leg exactly when fusion helps
-        most."""
+        most. ``host_visits``: the leaves × slices the Counts of the
+        host alternative would walk, whose per-fragment cost the
+        calibration timed (Calibration.host_visit_s); 0 for what else
+        it would run (TopN's ranked cache, the BSI aggregates), which
+        no probe measures: the byte term alone prices that."""
         with sched_context.stage("route"):
             return self._device_pays_priced(
                 mesh, n_rows, n_slices, cold_rows, note, streaming,
-                host_rows)
+                host_rows, host_visits)
 
     def _device_pays_priced(self, mesh, n_rows, n_slices, cold_rows,
-                            note, streaming, host_rows) -> bool:
+                            note, streaming, host_rows,
+                            host_visits) -> bool:
         if not self.calibrate(mesh):
             return True
         from .ops.packed import WORDS_PER_SLICE
         row_bytes = n_slices * WORDS_PER_SLICE * 4
-        host_bytes = (host_rows * row_bytes if host_rows is not None
-                      else None)
-        # host_bytes travels only when it differs — injected test
-        # models (and the pre-fusion interface) take three args.
-        kw = {"host_bytes": host_bytes} if host_bytes is not None else {}
-        pays = self.cost_model.device_pays(
+        host_bytes = (host_rows if host_rows is not None
+                      else n_rows) * row_bytes
+        if cold_rows and not streaming:
+            from .parallel import residency
+            if residency.slab_is_kept(mesh, n_slices):
+                cold_rows = 0   # filled once: not this read's to pay
+        model = self.cost_model
+        pays = model.device_pays(
             n_rows * row_bytes, cold_bytes=cold_rows * row_bytes,
-            streaming=streaming, **kw)
+            streaming=streaming, host_bytes=host_bytes,
+            host_visits=host_visits)
         if not pays:
             self.cost_vetoes += 1
             if note is not None:
                 # Stamp the host leg's prediction for this query; the
                 # _map_reduce caller records actual-vs-predicted.
-                note["host_pred"] = self.cost_model.predict(
-                    "host", host_bytes if host_bytes is not None
-                    else n_rows * row_bytes)
+                note["host_pred"] = model.predict(
+                    "host", host_bytes, host_visits=host_visits)
         return pays
 
     def _timed_device_leg(self, fn, n_rows: int, n_slices: int,
@@ -2452,25 +2543,53 @@ class Executor:
         Streaming legs (block re-packed every query) record under
         their own leg — the prediction prices the packing via
         pack_bps, so they participate in drift correction instead of
-        being excluded."""
+        being excluded. A resident leg that has a slab to fill is not
+        a sample: its fill is no part of its price (_device_pays), and
+        ``device_scale`` multiplies what a read of resident slabs
+        costs — a pack beside seven other packing threads folded it
+        ×70, and the resident reads after it were vetoed (chip run,
+        PR 32). It still counts among the legs in flight."""
         model = self.cost_model
         if model is None:
             return fn()
-        from .ops.packed import WORDS_PER_SLICE
-        leg = "device_stream" if streaming else "device"
-        row_bytes = n_slices * WORDS_PER_SLICE * 4
-        pred = model.predict(leg, n_rows * row_bytes,
-                             cold_rows * row_bytes)
-        t0 = time.perf_counter()
-        out = fn()
-        model.record(leg, pred, time.perf_counter() - t0)
+        out, own_s, wall_s = self._timed_leg(fn)
+        if streaming or not cold_rows:
+            from .ops.packed import WORDS_PER_SLICE
+            leg = "device_stream" if streaming else "device"
+            row_bytes = n_slices * WORDS_PER_SLICE * 4
+            model.record(leg, model.predict(leg, n_rows * row_bytes,
+                                            cold_rows * row_bytes),
+                         own_s, wall_s)
         return out
 
-    def _record_host_leg(self, note: dict, elapsed_s: float) -> None:
+    def _timed_leg(self, fn) -> tuple:
+        """(fn(), the least seconds of it that were its own, its wall).
+        Legs that run at once share one interpreter and one device, so
+        the wall of a leg among N is up to N times what it costs alone,
+        whichever leg it is; the constants it is held against were
+        measured alone. What it cost alone lies between its wall over
+        the timed legs in flight (the mean of the count at its start
+        and at its end) and its wall; the drift loop is given both
+        ends (CostModel.record)."""
+        token = object()
+        legs = self._timed_legs
+        legs.add(token)
+        n0 = len(legs)
+        t0 = time.perf_counter()
+        try:
+            out = fn()
+        finally:
+            elapsed = time.perf_counter() - t0
+            n1 = len(legs)
+            legs.discard(token)
+        return out, elapsed / ((n0 + n1) / 2), elapsed
+
+    def _record_host_leg(self, note: dict, own_s: float,
+                         wall_s: float) -> None:
         """Close the loop for a query the model routed to the host."""
         pred = note.get("host_pred")
         if pred is not None and self.cost_model is not None:
-            self.cost_model.record("host", pred, elapsed_s)
+            self.cost_model.record("host", pred, own_s, wall_s)
 
     @staticmethod
     def _slices_key(slices) -> tuple:
@@ -4387,6 +4506,7 @@ class Executor:
         span.__enter__()
         cost = ctx.cost if ctx is not None else None
         programs_before = cost.device_programs if cost is not None else 0
+        cold_before = cost.cold_leaves if cost is not None else 0
         # The first fan-out of a whole-index read takes its grouping
         # from the route record; a failover re-map walks.
         route = (getattr(slices, "route", None) if not opt.remote
@@ -4471,8 +4591,10 @@ class Executor:
                     processed += len(node_slices)
         finally:
             if cost is not None and cost.device_programs > programs_before:
-                # How wide a mesh this fan-out's device programs ran on.
-                span.tag(mesh_devices=cost.mesh_devices)
+                # How wide a mesh this fan-out's device programs ran
+                # on, and how many of their operand slabs it filled.
+                span.tag(mesh_devices=cost.mesh_devices,
+                         cold_leaves=cost.cold_leaves - cold_before)
             span.__exit__(None, None, None)
             # On an error path, drain what we started: the pool is
             # shared with other queries, and the old per-query pool's

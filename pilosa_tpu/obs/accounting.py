@@ -12,8 +12,9 @@ work at container granularity, not just wall-clock:
 - **word-equivalents scanned** (1024 words per bitmap container
   operand, ``ceil(len/64)`` per array operand);
 - **bits written** (fragment mutate/import paths);
-- **device programs dispatched + device bytes**, and the width of the
-  widest mesh one of them ran on (parallel/mesh entry points), and
+- **device programs dispatched + device bytes**, the width of the
+  widest mesh one of them ran on (parallel/mesh entry points), the
+  operand slabs the query filled (parallel/residency), and
   **XLA compile seconds** attributed to the query whose first call
   paid the trace+compile;
 - **RPC bytes in/out per peer** (cluster/client fan-out legs);
@@ -75,7 +76,7 @@ class QueryCost:
 
     __slots__ = ("node", "container_ops", "words_scanned",
                  "bits_written", "device_programs", "device_bytes",
-                 "mesh_devices", "compile_s", "wal_wait_s",
+                 "mesh_devices", "cold_leaves", "compile_s", "wal_wait_s",
                  "result_cache_hits", "rpc", "children", "_mu")
 
     def __init__(self, node: str = ""):
@@ -88,6 +89,9 @@ class QueryCost:
         # The widest mesh (device count) any of this query's device
         # programs ran on; 0 while none ran.
         self.mesh_devices = 0
+        # Device operand slabs (leaf slabs, candidate blocks) this
+        # query found not resident and built itself: packed, uploaded.
+        self.cold_leaves = 0
         self.compile_s = 0.0
         # Seconds this query's threads spent blocked in WAL group
         # commit (waiting for a leader's flush to cover their records)
@@ -120,6 +124,9 @@ class QueryCost:
         self.device_bytes += nbytes
         if mesh_devices > self.mesh_devices:
             self.mesh_devices = mesh_devices
+
+    def note_cold_leaf(self) -> None:
+        self.cold_leaves += 1
 
     def note_compile(self, seconds: float) -> None:
         self.compile_s += seconds
@@ -173,6 +180,8 @@ class QueryCost:
         }
         if self.mesh_devices:
             out["meshDevices"] = self.mesh_devices
+        if self.cold_leaves:
+            out["coldLeaves"] = self.cold_leaves
         if self.wal_wait_s:
             out["walWaitMs"] = round(self.wal_wait_s * 1e3, 3)
         if self.result_cache_hits:
@@ -204,6 +213,8 @@ class QueryCost:
         }
         if self.mesh_devices:
             out["meshDevices"] = self.mesh_devices
+        if self.cold_leaves:
+            out["coldLeaves"] = self.cold_leaves
         if self.wal_wait_s:
             out["walWaitMs"] = round(self.wal_wait_s * 1e3, 3)
         if self.result_cache_hits:
@@ -297,6 +308,12 @@ def note_device_dispatch(nbytes: int = 0, mesh_devices: int = 0) -> None:
     cost = current_cost()
     if cost is not None:
         cost.note_device_dispatch(nbytes, mesh_devices)
+
+
+def note_cold_leaf() -> None:
+    cost = current_cost()
+    if cost is not None:
+        cost.note_cold_leaf()
 
 
 def note_compile(seconds: float) -> None:

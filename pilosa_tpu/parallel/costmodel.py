@@ -7,7 +7,7 @@ know statically: the host's roaring rate, the host→device link, the
 device's own streaming rate and the fixed cost of one dispatch + fetch.
 A fixed slice threshold therefore mis-routes on one machine or another.
 
-So the executor calibrates at first mesh use — five probes, each a few
+So the executor calibrates at first mesh use — six probes, each a few
 milliseconds beside a local chip, measured in THIS process on THIS
 device and kept nowhere else — and predicts per query:
 
@@ -15,6 +15,11 @@ device and kept nowhere else — and predicts per query:
                    device path's fixed cost);
 - ``host_bps``   — the roaring intersection-count rate on this host
                    (the host path's per-byte cost on packed words);
+- ``host_visit_s`` — what the host path pays for one leaf row of one
+                   slice whatever the row holds (fragment look-up, row
+                   extraction, one slice-pool task): the served host
+                   path is a per-slice walk in Python, and at real
+                   widths the walk, not the popcount, is its cost;
 - ``upload_bps`` — host→device transfer rate of a packed block;
 - ``pack_bps``   — host-side roaring→dense pack rate;
 - ``device_bps`` — the fused popcount kernel's streaming rate on the
@@ -26,6 +31,18 @@ keeps marginal shapes on the device, where residency caching and
 dispatch batching improve repeat queries; the env override
 ``PILOSA_TPU_COST_MARGIN`` tunes it, ``PILOSA_TPU_COST_MODEL=0``
 disables the veto entirely (pre-calibration behavior).
+
+What is priced is what EVERY query of a shape pays. A cold slab that
+the residency cache keeps is filled once, by the leg that finds it
+cold, and read many times, while the host path is paid on every read:
+the executor passes ``cold_bytes`` to ``device_pays`` only for what is
+packed again on every query (a streaming leg's block, a slab larger
+than the whole residency budget) and 0 for slabs that stay. A veto
+that depended on residency would keep itself true: the host answer
+fills nothing, so the next read of the same rows finds them cold
+again. The drift loop's samples follow the same line
+(``Executor._timed_device_leg``): a leg that filled a slab is not a
+sample of what ``device_scale`` prices.
 """
 
 from __future__ import annotations
@@ -45,6 +62,7 @@ class Calibration:
     are no defaults to fall back on."""
     sync_s: float       # one dispatch + fetch round trip, seconds
     host_bps: float     # roaring count throughput, bytes/second
+    host_visit_s: float  # host path's fixed cost of one leaf of one slice
     upload_bps: float   # host→device transfer rate
     pack_bps: float     # host-side roaring→dense pack rate
     device_bps: float   # fused popcount streaming rate on the device
@@ -62,9 +80,12 @@ class Calibration:
     def device_cost(self, total_bytes: int, cold_bytes: int = 0,
                     streaming: bool = False,
                     crossings: int = 1) -> float:
-        # cold_bytes = data not device-resident: it must be PACKED
-        # host-side (roaring → dense words at pack_bps) and shipped at
-        # the measured transfer rate before the kernel can stream it.
+        # cold_bytes = data PACKED host-side (roaring → dense words at
+        # pack_bps) and shipped at the measured transfer rate before
+        # the kernel can stream it. A placement passes what the shape
+        # packs on EVERY query (0 for slabs residency keeps: their one
+        # fill is no read's price); a prediction of one leg's wall
+        # passes what that leg packs.
         # crossings = host↔device round trips the plan actually pays:
         # a fused multi-op tree (executor._device_batch_run) dispatches
         # ONE program for the whole tree, so it pays sync_s once — not
@@ -76,11 +97,18 @@ class Calibration:
             cost *= self.stream_scale
         return cost
 
-    def host_cost(self, total_bytes: int) -> float:
-        return total_bytes / self.host_bps * self.host_scale
+    def host_cost(self, total_bytes: int, visits: int = 0) -> float:
+        # visits = leaves × slices the host path walks, one fragment
+        # look-up and one row extraction each: priced on the byte term
+        # alone, a 2-leaf Count at 256 slices read 4.5 ms where the
+        # served host path took ×170–256 that (chip run, PR 21), and
+        # twelve callers paid for the drift loop's correction.
+        return ((total_bytes / self.host_bps + visits * self.host_visit_s)
+                * self.host_scale)
 
     def to_dict(self) -> dict:
         return {"sync_s": self.sync_s, "host_bps": self.host_bps,
+                "host_visit_s": self.host_visit_s,
                 "upload_bps": self.upload_bps,
                 "pack_bps": self.pack_bps,
                 "device_bps": self.device_bps,
@@ -108,8 +136,18 @@ class CostModel:
     restart, so every routed query records (predicted, actual) for the
     leg it ran; when the median drift of a leg exceeds DRIFT_BOUND x,
     that leg's scale multiplier is folded by the observed median — the
-    model re-converges in-process. Nothing is kept across processes: a
-    restart measures again."""
+    model re-converges in-process. The loop is the safety net, not the
+    way onto the device: the probes price the first query where the
+    hundredth is placed. Its samples are of what a scale prices, held
+    against constants measured ALONE: a leg that filled a slab is no
+    sample, and a leg among N running legs shares one interpreter and
+    one device with them, so what it cost alone lies between its wall
+    over N and its wall (Executor._timed_leg gives both ends). A scale
+    grows only where even the lower ends are over the bound and shrinks
+    only where even the upper ends are under it: load is not drift, and
+    folding it into the scale of whichever leg was running sent the
+    next read to the other leg, whose scale then grew in its turn.
+    Nothing is kept across processes: a restart measures again."""
 
     def __init__(self, cal: Calibration, margin: float = 0.5):
         self.cal = cal
@@ -126,7 +164,7 @@ class CostModel:
     def device_pays(self, total_bytes: int, cold_bytes: int = 0,
                     streaming: bool = False,
                     host_bytes: int | None = None,
-                    crossings: int = 1) -> bool:
+                    crossings: int = 1, host_visits: int = 0) -> bool:
         """False only when the host path is a clear predicted win.
 
         ``host_bytes`` prices the host alternative on ITS real byte
@@ -138,39 +176,60 @@ class CostModel:
         for exactly the multi-op queries fusion accelerates.
         ``crossings`` is the number of device dispatches the plan pays
         (1 for a fused tree, whatever the chunk loop needs otherwise).
+        ``host_visits`` is the leaves × slices the host alternative
+        walks (Calibration.host_cost). ``cold_bytes`` is what the shape
+        packs and ships on EVERY query, not a kept slab's one fill.
         """
         host = self.cal.host_cost(
-            host_bytes if host_bytes is not None else total_bytes)
+            host_bytes if host_bytes is not None else total_bytes,
+            host_visits)
         device = self.cal.device_cost(total_bytes, cold_bytes,
                                       streaming, crossings=crossings)
         return host >= self.margin * device
 
     def predict(self, leg: str, total_bytes: int,
-                cold_bytes: int = 0) -> float:
+                cold_bytes: int = 0, host_visits: int = 0) -> float:
         if leg == "device":
             return self.cal.device_cost(total_bytes, cold_bytes)
         if leg == "device_stream":
             return self.cal.device_cost(total_bytes, cold_bytes,
                                         streaming=True)
-        return self.cal.host_cost(total_bytes)
+        return self.cal.host_cost(total_bytes, host_visits)
 
-    def record(self, leg: str, predicted_s: float,
-               actual_s: float) -> None:
+    def record(self, leg: str, predicted_s: float, actual_s: float,
+               wall_s: float | None = None) -> None:
         """Feed one routed query's (predicted, actual) leg cost back
         into the model; recalibrates when the median drift of that leg
-        exceeds DRIFT_BOUND in either direction."""
+        exceeds DRIFT_BOUND in either direction. ``actual_s`` is the
+        least the leg can have cost alone and ``wall_s`` the most (its
+        wall among other legs; the same where it ran alone): the scale
+        is folded up by the median of the lower ends once THAT is over
+        the bound, down by the median of the upper ends once THAT is
+        under it."""
         if predicted_s <= 0 or actual_s <= 0:
             return
+        if wall_s is None or wall_s < actual_s:
+            wall_s = actual_s
+        lo, hi = actual_s / predicted_s, wall_s / predicted_s
         with self._mu:
             d = self._drift.get(leg)
             if d is None:
                 return
-            d.append(actual_s / predicted_s)
+            d.append((lo, hi))
             if len(d) < DRIFT_MIN_SAMPLES:
                 return
-            med = sorted(d)[len(d) // 2]
-            if 1.0 / DRIFT_BOUND <= med <= DRIFT_BOUND:
+            if (len(d) > DRIFT_MIN_SAMPLES and lo <= DRIFT_BOUND
+                    and hi >= 1.0 / DRIFT_BOUND):
+                # The window was inside the bound a sample ago (it is
+                # cleared whenever it is not) and a sample inside it
+                # cannot take a median out: a served read pays an
+                # append here, not two sorts under the lock.
                 return
+            med = sorted(lo for lo, _ in d)[len(d) // 2]
+            if med <= DRIFT_BOUND:
+                med = sorted(hi for _, hi in d)[len(d) // 2]
+                if med >= 1.0 / DRIFT_BOUND:
+                    return
             attr = self._SCALE_ATTR[leg]
             scale = getattr(self.cal, attr) * med
             scale = min(max(scale, 1.0 / _SCALE_CLAMP), _SCALE_CLAMP)
@@ -182,7 +241,7 @@ class CostModel:
         with self._mu:
             out = {}
             for leg, d in self._drift.items():
-                vals = sorted(d)
+                vals = sorted(lo for lo, _ in d)
                 out[leg] = {
                     "n": len(vals),
                     "median": round(vals[len(vals) // 2], 3) if vals
@@ -313,12 +372,15 @@ _cache: dict[str, Calibration] = {}
 _cache_mu = threading.Lock()
 
 
-def get_model(mesh, margin: float = 0.5) -> CostModel:
+def get_model(mesh, host_visit_probe, margin: float = 0.5) -> CostModel:
     """Calibrate once per backend platform per process; the margin is
     per-caller (a cached calibration must not freeze the first caller's
     margin for everyone). Measurement happens OUTSIDE the lock —
     concurrent queries must not stall behind it; a losing racer just
-    discards its duplicate measurement.
+    discards its duplicate measurement. ``host_visit_probe`` is the
+    caller's probe of its own host fan-out, ``() -> host_visit_s`` (the
+    executor's: Executor.calibrate), asked only where this process has
+    no calibration yet.
 
     The calibration lives in this process only. A file would carry one
     process's drift corrections into the next — and a parent commit's
@@ -331,6 +393,7 @@ def get_model(mesh, margin: float = 0.5) -> CostModel:
         cal = Calibration(
             sync_s=sync_s,
             host_bps=_measure_host_bps(),
+            host_visit_s=host_visit_probe(),
             upload_bps=_measure_upload_bps(mesh, sync_s),
             pack_bps=_measure_pack_bps(),
             device_bps=_measure_device_bps(mesh, sync_s))

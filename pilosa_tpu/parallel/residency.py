@@ -46,12 +46,14 @@ from __future__ import annotations
 import itertools
 import os
 import threading
+import time
 from collections import OrderedDict
 from typing import Callable, Optional
 
 import jax
 import numpy as np
 
+from ..obs import accounting
 from ..ops import packed
 from ..sched import context as sched_context
 
@@ -64,6 +66,17 @@ DEFAULT_MAX_ROWS = 256
 DEFAULT_HBM_BUDGET_MB = 1024
 
 _uid_counter = itertools.count(1)
+
+
+class _Fill:
+    """One build in flight: what its waiters are handed."""
+
+    __slots__ = ("done", "arr", "error")
+
+    def __init__(self):
+        self.done = threading.Event()
+        self.arr = None
+        self.error: Optional[BaseException] = None
 
 
 class DeviceBlockCache:
@@ -81,10 +94,17 @@ class DeviceBlockCache:
         self.budget_bytes = budget_bytes
         self._mu = threading.Lock()
         self._lru: OrderedDict[tuple, jax.Array] = OrderedDict()
+        self._filling: dict[tuple, _Fill] = {}
         self.used_bytes = 0
         self.hits = 0
         self.misses = 0
         self.evictions = 0
+        # What the misses cost: builds run, requests that waited for
+        # another request's build of their key, and the builders' wall
+        # seconds (summed; builds of different keys overlap).
+        self.fills = 0
+        self.fill_waits = 0
+        self.fill_seconds = 0.0
 
     @staticmethod
     def _nbytes(arr) -> int:
@@ -92,6 +112,18 @@ class DeviceBlockCache:
 
     def get_or_build(self, key: tuple,
                      build: Callable[[], jax.Array]) -> jax.Array:
+        """The resident array under ``key``, built on a miss by the
+        first requester, on its own thread and outside the lock
+        (packing + device_put take long and must not serialize
+        unrelated queries). Fills are single-flight by key: a request
+        that arrives while the key's build is in flight waits for it
+        and takes its array (a miss, and a ``fillWait``) instead of
+        packing the same rows again. The caller's key embeds the
+        owning view's token, read before the fragments are resolved,
+        so a waiter never receives a slab older than its own token: a
+        write between two requests gives two keys and two builds. A
+        build that raises wakes its waiters with the error and leaves
+        nothing behind: the next request builds again."""
         with self._mu:
             arr = self._lru.get(key)
             if arr is not None:
@@ -99,24 +131,49 @@ class DeviceBlockCache:
                 self.hits += 1
                 return arr
             self.misses += 1
-        # Build outside the lock: packing + device_put can take long and
-        # must not serialize unrelated queries. Concurrent builders of
-        # the same key race benignly (last insert wins).
-        arr = build()
+            fill = self._filling.get(key)
+            if fill is not None:
+                self.fill_waits += 1
+            else:
+                mine = self._filling[key] = _Fill()
+        if fill is not None:
+            with sched_context.stage("fill_wait"):
+                fill.done.wait()
+            if fill.error is not None:
+                raise fill.error
+            return fill.arr
+        t0 = time.perf_counter()
+        try:
+            mine.arr = build()
+        except BaseException as e:
+            mine.error = e
+            raise
+        finally:
+            with self._mu:
+                del self._filling[key]
+                self.fills += 1
+                self.fill_seconds += time.perf_counter() - t0
+                if mine.error is None:
+                    self._insert(key, mine.arr)
+            mine.done.set()
+        accounting.note_cold_leaf()
+        return mine.arr
+
+    def _insert(self, key: tuple, arr) -> None:
+        """Keep a built array (lock held), unless it is bigger than the
+        whole working set: that one is a one-shot."""
         nbytes = self._nbytes(arr)
         if nbytes > self.budget_bytes:
-            return arr  # one-shot: bigger than the whole working set
-        with self._mu:
-            if key not in self._lru:
-                self._lru[key] = arr
-                self.used_bytes += nbytes
-            self._lru.move_to_end(key)
-            # len > 1 keeps the just-built entry (now most-recent) alive.
-            while self.used_bytes > self.budget_bytes and len(self._lru) > 1:
-                _, old = self._lru.popitem(last=False)
-                self.used_bytes -= self._nbytes(old)
-                self.evictions += 1
-        return arr
+            return
+        if key not in self._lru:
+            self._lru[key] = arr
+            self.used_bytes += nbytes
+        self._lru.move_to_end(key)
+        # len > 1 keeps the just-built entry (now most-recent) alive.
+        while self.used_bytes > self.budget_bytes and len(self._lru) > 1:
+            _, old = self._lru.popitem(last=False)
+            self.used_bytes -= self._nbytes(old)
+            self.evictions += 1
 
     def contains(self, key: tuple) -> bool:
         """Residency probe WITHOUT touching LRU order — the routing
@@ -126,10 +183,15 @@ class DeviceBlockCache:
 
     def lookup(self, keys: list) -> list:
         """The resident array of every key (None where absent) in ONE
-        lock hold, WITHOUT touching LRU order or the hit counters: the
-        routing cost model asks what a leg would have to upload before
-        the leg is taken, and a vetoed leg must leave no trace. A leg
-        that is taken reports what it used with ``touch``."""
+        lock hold, WITHOUT touching LRU order or the hit counters: a
+        leg looks its operands up before it is placed, and a leg the
+        router keeps on the host must leave no trace here. What it
+        finds absent is NOT what places it: a veto may depend on what
+        a read of resident slabs costs, on a slab too large to be kept
+        and on a streaming leg's re-pack, never on residency, or a
+        host answer (which fills nothing) would veto the next read of
+        the same rows again. A leg that is taken reports what it used
+        with ``touch`` and fills what was absent (``get_or_build``)."""
         with self._mu:
             get = self._lru.get
             return [get(k) for k in keys]
@@ -167,7 +229,9 @@ class DeviceBlockCache:
                     "budgetBytes": self.budget_bytes,
                     "perDeviceBytes": per_device,
                     "hits": self.hits, "misses": self.misses,
-                    "evictions": self.evictions}
+                    "evictions": self.evictions,
+                    "fills": self.fills, "fillWaits": self.fill_waits,
+                    "fillSeconds": round(self.fill_seconds, 6)}
 
 
 _device_cache: Optional[DeviceBlockCache] = None
@@ -190,6 +254,15 @@ def _bucketed_slices(mesh, n_slices: int) -> int:
     from . import programs
     return programs.slice_bucket(n_slices,
                                  mesh.shape[mesh_mod.AXIS_SLICES])
+
+
+def slab_is_kept(mesh, n_slices: int) -> bool:
+    """Whether one row's slab at ``n_slices`` fits the residency budget
+    at its uploaded (bucket-padded) shape: ``get_or_build`` keeps it
+    then, and its fill is paid once; a larger one is packed and shipped
+    again by every query that asks for it."""
+    return (_bucketed_slices(mesh, n_slices) * packed.WORDS_PER_SLICE * 4
+            <= device_cache().budget_bytes)
 
 
 def leaf_slab(mesh, key: tuple, frags, row_id: int) -> jax.Array:

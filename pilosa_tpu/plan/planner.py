@@ -612,7 +612,8 @@ class Planner:
                 # Roaring walk cost: ~2 bytes/bit in array containers,
                 # capped at the dense slab.
                 host_bytes += min(leaf_est * 2, slab)
-        host = cal.host_cost(host_bytes)
+        # every leaf row of every slice is one visit of the host walk
+        host = cal.host_cost(host_bytes, leaves * n_slices)
         device = cal.device_cost(device_bytes)
         node.est_cost_s = min(host, device)
         if host < self.margin * device:

@@ -64,8 +64,8 @@ TENANT_HEADER = "X-Pilosa-Tenant"
 # The stage names are a contract: counter keys in /debug/vars
 # ``queryStages``, span names in kept traces, ``pilosa.<name>`` in the
 # profiler. On the connection thread, in order: http_read, parse, setup,
-# admission, execute (self), plan, route, pack, upload, dispatch
-# (+ compile), fetch, merge, legs_wait, commit, finish, encode,
+# admission, execute (self), plan, route, pack, upload, fill_wait,
+# dispatch (+ compile), fetch, merge, legs_wait, commit, finish, encode,
 # http_write; ``leg`` is the base stage of a map-reduce worker thread.
 # docs/OBSERVABILITY.md "Trace contract" has the table.
 

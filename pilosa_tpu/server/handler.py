@@ -607,6 +607,7 @@ class Handler:
         if model is not None:
             snap["costModel"] = {"syncS": model.cal.sync_s,
                                  "hostBps": model.cal.host_bps,
+                                 "hostVisitS": model.cal.host_visit_s,
                                  "uploadBps": model.cal.upload_bps,
                                  "packBps": model.cal.pack_bps,
                                  "deviceBps": model.cal.device_bps,

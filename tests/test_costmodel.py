@@ -5,8 +5,10 @@ tuned for, wrong on every other machine. The cost model predicts per
 query from constants measured on the attached hardware; these tests pin
 the decision function on injected calibrations (a slow and a fast
 host<->device sync), that the executor's veto actually routes a query
-onto the host path, and that a query REPEATED over the same cold slabs
-ends up on the device once its host answers have been observed.
+onto the host path, that a query over cold slabs is placed where the
+same query over resident slabs is (its first answer fills them), and
+that the drift loop corrects a wrong probe without reading load, or a
+fill, as drift.
 """
 
 import numpy as np
@@ -22,10 +24,12 @@ def block_bytes(rows: int, slices: int) -> int:
 
 def cal(sync_s: float, host_bps: float = 1.0e9, upload_bps: float = 1.7e9,
         pack_bps: float = 1.3e8, device_bps: float = 4.0e11,
-        **kw) -> Calibration:
+        host_visit_s: float = 0.0, **kw) -> Calibration:
     """An injected calibration: every constant is explicit here because
-    Calibration has no defaults (real ones are measured, never assumed)."""
+    Calibration has no defaults (real ones are measured, never assumed).
+    ``host_visit_s`` 0 is a host path that costs its bytes alone."""
     return Calibration(sync_s=sync_s, host_bps=host_bps,
+                       host_visit_s=host_visit_s,
                        upload_bps=upload_bps, pack_bps=pack_bps,
                        device_bps=device_bps, **kw)
 
@@ -54,21 +58,41 @@ class TestDecision:
         m = CostModel(FAST_SYNC)
         assert m.device_pays(block_bytes(2, 128))
 
-    def test_cold_upload_flips_decision_on_slow_link(self):
-        # TopN phase 2: 1000 candidates × 10 slices (~1.3 GB block).
-        # Resident, the device wins (host ~1.3 s vs sync floor); cold,
-        # the upload over a 100 MB/s link (~13 s) hands it to the host.
+    def test_repacked_block_flips_decision_on_slow_link(self):
+        # TopN phase 2, streaming: 1000 candidates × 10 slices (~1.3 GB
+        # block) re-packed and shipped by every query. Were it kept,
+        # the device would win (host ~1.3 s vs sync floor); shipped
+        # over a 100 MB/s link (~13 s) on every query, the host does.
+        # (The executor passes cold_bytes only for what every query
+        # packs again: tests/test_placement_fill.py.)
         m = CostModel(cal(sync_s=0.130, upload_bps=1.0e8))
         bytes_ = block_bytes(1000, 10)
         assert m.device_pays(bytes_, cold_bytes=0)
-        assert not m.device_pays(bytes_, cold_bytes=bytes_)
+        assert not m.device_pays(bytes_, cold_bytes=bytes_,
+                                 streaming=True)
 
-    def test_cold_upload_cheap_on_fast_link(self):
-        # 20 GB/s transfers and memory-speed packing make the same cold
-        # block a device win again.
+    def test_repacked_block_cheap_on_fast_link(self):
+        # 20 GB/s transfers and memory-speed packing make the same
+        # re-packed block a device win again.
         m = CostModel(cal(sync_s=0.001, upload_bps=2.0e10, pack_bps=2.0e9))
         bytes_ = block_bytes(1000, 10)
-        assert m.device_pays(bytes_, cold_bytes=bytes_)
+        assert m.device_pays(bytes_, cold_bytes=bytes_, streaming=True)
+
+    def test_the_host_walk_is_priced_by_the_slice(self):
+        # The chip's probe (PERF.md, PRs 21, 31): the byte term prices a
+        # 2-leaf Count at 32 slices at 0.45 ms, under half a 1.09 ms
+        # sync — a host win; the served host path walks 64 fragment
+        # rows at 85 µs each, 5.9 ms, and the device serves.
+        chip = dict(sync_s=1.09e-3, host_bps=1.87e10, upload_bps=6.0e9,
+                    pack_bps=2.0e8, device_bps=7.0e11)
+        bytes_ = block_bytes(2, 32)
+        assert not CostModel(cal(**chip)).device_pays(bytes_)
+        m = CostModel(cal(host_visit_s=8.5e-5, **chip))
+        assert m.device_pays(bytes_, host_visits=64)
+        assert m.predict("host", bytes_, host_visits=64) == pytest.approx(
+            bytes_ / 1.87e10 + 64 * 8.5e-5)
+        # a shape with no measured walk is priced by its bytes alone
+        assert not m.device_pays(bytes_)
 
     def test_margin_keeps_marginal_shapes_on_device(self):
         # Host must be a CLEAR win (margin 0.5): a shape where host
@@ -145,21 +169,24 @@ class TestExecutorVeto:
 
 
 class TestRepeatedColdQuery:
-    """What gets a repeated query onto the device (chip run, PR 21):
-    the start-up host probe is a micro popcount and is optimistic at
-    real widths, so the first answers are vetoed; the vetoed queries'
-    host legs are timed, the drift loop re-prices the host after
-    DRIFT_MIN_SAMPLES of them, the cold slabs are uploaded once and
-    the query stays on the device."""
+    """Where the drift loop is still what gets a repeated query onto
+    the device: a shape the PROBES price as a host win. At 16 slices a
+    2-leaf Count is 0.23 ms of host bytes against half a 1 ms sync, so
+    the veto stands on resident cost alone (the fill is no part of it:
+    tests/test_placement_fill.py has the widths where the first answer
+    is the device's); the vetoed queries' host legs are timed, the
+    loop re-prices the host after DRIFT_MIN_SAMPLES of them, the cold
+    slabs are filled once and the query stays on the device."""
 
-    # The constants the probe measured on the v5e (PERF.md, PR 21).
+    # The constants the probe measured on the v5e (PERF.md, PR 21),
+    # with a host path that costs its bytes alone.
     CHIP = dict(sync_s=1.0e-3, host_bps=1.85e10, upload_bps=6.0e9,
                 pack_bps=2.0e8, device_bps=7.0e11)
-    # What a host answer really took there against ~4 ms predicted;
+    # What a host answer really took there against ~0.2 ms predicted;
     # scripted, so that the test does not hang on this machine's clock.
     HOST_ANSWER_S = 0.060
 
-    def test_repeated_query_over_cold_slabs_ends_up_on_the_device(
+    def test_repeated_query_a_wrong_probe_vetoes_ends_up_on_the_device(
             self, tmp_path):
         from pilosa_tpu.executor import Executor
         from pilosa_tpu.parallel import residency
@@ -168,10 +195,10 @@ class TestRepeatedColdQuery:
         host_answer_s = self.HOST_ANSWER_S
 
         class ObservedSlowHost(CostModel):
-            def record(self, leg, predicted_s, actual_s):
+            def record(self, leg, predicted_s, actual_s, wall_s=None):
                 if leg == "host":
-                    actual_s = host_answer_s
-                super().record(leg, predicted_s, actual_s)
+                    actual_s = wall_s = host_answer_s
+                super().record(leg, predicted_s, actual_s, wall_s)
 
         n_slices = 16
         holder = _filled_holder(tmp_path, n_slices)
@@ -190,15 +217,17 @@ class TestRepeatedColdQuery:
                 served_by.append("host" if ex.cost_vetoes > vetoes
                                  else "device")
                 uploads.append(cache.misses - misses)
-            # one-time pack + upload (22 ms predicted) against a host
-            # answer predicted at 0.2 ms: vetoed until the host leg has
-            # been observed DRIFT_MIN_SAMPLES times, and not after
+            # a host answer predicted at 0.2 ms against half a 1 ms
+            # sync: vetoed until the host leg has been observed
+            # DRIFT_MIN_SAMPLES times, and not after
             assert served_by == (["host"] * DRIFT_MIN_SAMPLES
                                  + ["device"] * 3), served_by
-            # the first device answer uploads both slabs, later ones none
+            # the first device answer fills both slabs, later ones none
             assert uploads[DRIFT_MIN_SAMPLES:] == [2, 0, 0], uploads
             assert ex.cost_model.recalibrations >= 1
             assert ex.device_fallbacks == 0
+            # the filling leg was no sample of the device's scale
+            assert ex.cost_model.drift_snapshot()["device"]["n"] == 2
         finally:
             ex.close()
             holder.close()
@@ -244,7 +273,7 @@ class TestFeedbackLoop:
     @pytest.mark.parametrize("leg,attr", sorted(CostModel._SCALE_ATTR.items()))
     def test_the_drift_loop_folds_after_the_same_samples_to_the_same_scale(
             self, leg, attr):
-        """The router's settling is part of ``setup_s``: a leg is re-priced
+        """A leg that ran alone (its wall is what it cost) is re-priced
         at its DRIFT_MIN_SAMPLES-th observation, not before, by the median
         of the window ((n // 2)-th of the sorted ratios), the window starts
         over, and a median inside the bound folds nothing."""
@@ -274,6 +303,33 @@ class TestFeedbackLoop:
         for _ in range(10 * cm.DRIFT_MIN_SAMPLES):
             m.record(leg, 1.0, 1e-9)
         assert getattr(c, attr) == 1.0 / cm._SCALE_CLAMP
+
+    @pytest.mark.parametrize("leg,attr", sorted(CostModel._SCALE_ATTR.items()))
+    def test_load_is_not_drift(self, leg, attr):
+        """A leg among N running legs took at least its wall over N and
+        at most its wall alone. A scale grows only where even the lower
+        ends are over the bound, and shrinks only where even the upper
+        ends are under it: eight clients are not an eight times slower
+        device."""
+        from pilosa_tpu.parallel import costmodel as cm
+
+        def after(n, own_s, wall_s):
+            c = cal(sync_s=0.001)
+            m = CostModel(c)
+            for _ in range(n):
+                m.record(leg, 1.0, own_s, wall_s)
+            return getattr(c, attr), m.recalibrations
+
+        n = cm.DRIFT_MIN_SAMPLES
+        # wall 7x the prediction among 8 legs: alone, 0.9x to 7x
+        assert after(10 * n, 7.0 / 8, 7.0) == (1.0, 0)
+        # even its share of the wall is 3x: that is drift, folded by
+        # the lower ends' median
+        assert after(n, 3.0, 24.0) == (3.0, 1)
+        # even the whole wall is a quarter: folded by the upper ends'
+        assert after(n, 0.03, 0.25) == (0.25, 1)
+        # ends that straddle 1 move nothing, however many
+        assert after(200, 0.1, 10.0) == (1.0, 0)
 
     def test_scales_clamped(self):
         from pilosa_tpu.parallel import costmodel as cm
@@ -329,14 +385,14 @@ class TestExecutorFeedbackWiring:
                 margin = 0.5
 
                 def device_pays(self, total_bytes, cold_bytes=0,
-                            streaming=False):
+                                streaming=False, **kw):
                     return False
 
-                def predict(self, leg, total_bytes, cold_bytes=0):
+                def predict(self, leg, total_bytes, cold_bytes=0, **kw):
                     return 0.001
 
-                def record(self, leg, pred, actual):
-                    recorded.append((leg, pred, actual))
+                def record(self, leg, pred, actual, wall=None):
+                    recorded.append((leg, pred, actual, wall))
 
             ex.cost_model = VetoModel()
             ex._cost_model_enabled = True
@@ -346,6 +402,8 @@ class TestExecutorFeedbackWiring:
             assert got == [3]
             legs = [r[0] for r in recorded]
             assert "host" in legs, recorded
+            # alone, what the leg cost is its wall
+            assert all(0 < r[2] <= r[3] for r in recorded), recorded
         finally:
             h.close()
 
@@ -398,7 +456,7 @@ class TestStreamingLeg:
 
 class TestProbes:
     def test_get_model_measures_every_constant_on_this_backend(self):
-        """The start-up probe fills all five constants from
+        """The start-up probe fills all six constants from
         measurements on the mesh it is given (CPU here), positive and
         finite; a second call reuses the process's calibration."""
         import math
@@ -406,9 +464,17 @@ class TestProbes:
         from pilosa_tpu.parallel import costmodel as cm
         from pilosa_tpu.parallel import mesh as mesh_mod
         mesh = mesh_mod.make_mesh(1)
-        m = cm.get_model(mesh)
-        for name in ("sync_s", "host_bps", "upload_bps", "pack_bps",
-                     "device_bps"):
+        from pilosa_tpu.executor import _measure_host_visit_s
+        cm._cache.clear()
+        m = cm.get_model(mesh, _measure_host_visit_s)
+        for name in ("sync_s", "host_bps", "host_visit_s", "upload_bps",
+                     "pack_bps", "device_bps"):
             v = getattr(m.cal, name)
             assert v > 0 and math.isfinite(v), (name, v)
-        assert cm.get_model(mesh, margin=0.9).cal is m.cal
+        # the walk of one fragment row is microseconds to milliseconds
+        assert 1e-6 < m.cal.host_visit_s < 1e-2
+        assert set(m.cal.to_dict()) >= {"host_visit_s", "host_scale"}
+
+        def no_probe():
+            raise AssertionError("calibrated twice in one process")
+        assert cm.get_model(mesh, no_probe, margin=0.9).cal is m.cal
