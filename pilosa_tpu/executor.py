@@ -60,21 +60,6 @@ from .utils import timequantum as tq
 DEFAULT_FRAME = "general"
 
 
-def _attach_plan_nodes(call: Call, node) -> None:
-    """Pair the planner's PlanNode tree onto the cloned Call tree via a
-    ``_plan_node`` attribute — the per-slice hooks run on mapper-pool
-    threads where no request context is visible, so the hint has to
-    travel with the call itself. The planner clones calls 1:1 with the
-    nodes it emits (drops happen to both sides together), so a zip is
-    exact; a length mismatch would mean a planner bug and we stop
-    attaching rather than mis-pair hints."""
-    call._plan_node = node
-    if len(call.children) != len(node.children):
-        return
-    for ch_call, ch_node in zip(call.children, node.children):
-        _attach_plan_nodes(ch_call, ch_node)
-
-
 class _RoutedSlices(list):
     """The whole-index slice list of one route record
     (``Executor._route``). It carries its record, so every layer below
@@ -704,11 +689,14 @@ class Executor:
     def _maybe_plan(self, index: str, query: Query, slices: list[int],
                     opt: ExecOptions):
         """Plan a read query: returns (query', PlanRecord) — the
-        planned clone when planning applies, the original (query,
-        None) otherwise. The plan tree rides each cloned Call as
-        ``_plan_node`` (the per-slice hooks read it without a context
-        lookup) and the record attaches to ``ctx.plan`` for the
-        observability plane."""
+        planned calls when planning applies, the original (query,
+        None) otherwise. The planner builds every planned Call with
+        its plan node as ``_plan_node`` (the per-slice hooks run on
+        mapper-pool threads where no request context is visible, so
+        the hint travels with the call itself) and the record attaches
+        to ``ctx.plan`` for the observability plane. A profiled
+        request (?profile=1) is planned in full, past the shape
+        entries, and records every node."""
         if (self.planner is None or not self.planner_enabled
                 or not plan_record.enabled()):
             return query, None
@@ -718,21 +706,24 @@ class Executor:
             all_local = self._owns_all_slices(index, slices)
         except Exception:  # noqa: BLE001 - locality is advisory here
             all_local = False
-        route = getattr(slices, "route", None)
+        ctx = opt.ctx
+        profile = ctx is not None and bool(getattr(ctx, "profile", False))
         try:
-            planned, rec = self.planner.plan_query_cached(
-                index, query.calls, slices, all_local=all_local,
-                node=self.host,
-                slices_key=route["skey"] if route is not None else None)
+            if profile:
+                planned, rec = self.planner.plan_query(
+                    index, query.calls, slices, all_local=all_local,
+                    node=self.host)
+            else:
+                route = getattr(slices, "route", None)
+                planned, rec = self.planner.plan_query_cached(
+                    index, query.calls, slices, all_local=all_local,
+                    node=self.host,
+                    slices_key=(route["skey"] if route is not None
+                                else None))
         except Exception:  # noqa: BLE001 - planning never fails a query
             return query, None
-        for call, node in zip(planned, rec.roots):
-            # Memo hits return calls already carrying their plan node.
-            if getattr(call, "_plan_node", None) is not node:
-                _attach_plan_nodes(call, node)
-        ctx = opt.ctx
         if ctx is not None:
-            rec.analyze = bool(getattr(ctx, "profile", False))
+            rec.analyze = profile
             ctx.plan = rec
         return Query(planned), rec
 

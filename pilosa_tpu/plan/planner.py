@@ -39,10 +39,19 @@ never faults cold tier fragments in (cache-only, inexact) and never
 reaches across the cluster (non-local slices extrapolate from the
 local fraction, inexact).
 
-Finished plans are memoized (``plan_query_cached``): a repeated query
-reuses its plan after an epoch-validation sweep over the exact facts
-the plan's proofs rest on, which is what keeps planner-on p50 within
-the ≤2% overhead budget on hot repeated queries.
+A statement's SHAPE — the calls with their integer argument values
+lifted out (``pql.shape``: ``rowID=3``, list members and condition
+operands are parameters; names, strings, floats, ``true/false/null``
+and operators are the shape) — is planned once
+(``plan_query_cached``): the entry holds what no row id changes (the
+fingerprint, a ``_Skel`` a call with its frame, view and purity, the
+facts those rest on) and a request only binds its row ids into it
+(``_bind``): one estimate a leaf from a row memo keyed by the view's
+mutation token, the emptiness proofs, the order, placement, the CSE
+ladder. ``plan_query`` is the same skeleton built from scratch and
+the same bind, so the two cannot plan a request differently; what
+differs is only what is kept. ``/debug/vars.planShapes`` counts
+``hits`` / ``misses`` / ``full`` (docs/OBSERVABILITY.md).
 """
 
 from __future__ import annotations
@@ -54,7 +63,8 @@ from typing import Optional
 from ..cluster import generations
 from ..obs import metrics as obs_metrics
 from ..ops.packed import WORDS_PER_SLICE
-from ..pql.ast import Call, Condition
+from ..pql import shape
+from ..pql.ast import Call
 from ..utils.hotlock import HotLock
 from .record import PlanNode, PlanRecord, fingerprint_calls
 
@@ -67,14 +77,14 @@ ESTIMATE_SAMPLES = 8
 # understand Count/TopN wrappers (their bitmap child is planned).
 _BITMAP_OPS = ("Intersect", "Union", "Difference")
 
-# Per-operand estimation entries kept (keyed by fragment mutation
-# epoch, so a write invalidates in place).
+# Leaf estimates kept in the row memo (keyed by the view's mutation
+# token: a write gives a new key, the old entries age out).
 _ESTIMATE_CACHE_ENTRIES = 4096
 # Canonical subtrees remembered for cross-query CSE detection.
 _SEEN_ENTRIES = 1024
-# Finished plans memoized per (index, canonical calls, slices) — the
-# repeated-query fast path the ≤2% overhead budget requires. Validity
-# is fact-checked per hit (plan_query_cached), never assumed.
+# Shape entries (and the executor's route records) kept per (index,
+# literal-free calls, slices) — what a read must not redo per request.
+# Validity is fact-checked per hit (plan_query_cached), never assumed.
 _PLAN_MEMO_ENTRIES = 256
 
 
@@ -143,11 +153,68 @@ class SubresultCache:
             return {"entries": len(self._entries), "bits": self._bits}
 
 
+class _Skel:
+    """One call of a planned shape: what planning it needs that no
+    parameter changes. ``kind`` is ``_WRAP`` (a pass-through call: its
+    bitmap children are planned), ``_OP`` (Intersect / Union /
+    Difference), ``_LEAF`` (a Bitmap whose row can be estimated:
+    ``dep`` indexes the entry's (frame name, frame, view) facts,
+    ``row_slot`` the parameter that names the row, ``canon`` its
+    canonical string cut around the row id, or None where more than
+    the row varies) or ``_OPEN`` (a Bitmap left unestimated: no index,
+    no frame, a filter, an inverse leaf)."""
+
+    __slots__ = ("name", "spec", "kids", "kind", "covered", "dep",
+                 "row_slot", "row_key", "frame_name", "frames", "canon")
+
+    def __init__(self, name: str, spec, kind: int):
+        self.name = name
+        self.spec = spec
+        self.kids: list = []
+        self.kind = kind
+        self.covered = False
+        self.dep = -1
+        self.row_slot = -1
+        self.row_key = ""
+        self.frame_name = ""
+        self.frames: frozenset = frozenset()
+        self.canon = None
+
+
+_WRAP, _OP, _LEAF, _OPEN = range(4)
+
+
+class _Binding:
+    """One request's half of a plan: its parameter values, the view
+    tokens read before any estimate, its slices, and the decisions
+    made while binding."""
+
+    __slots__ = ("vals", "deps", "gens", "slices", "skey", "all_local",
+                 "decisions")
+
+    def __init__(self, vals, deps, gens, slices, skey, all_local):
+        self.vals = vals
+        self.deps = deps
+        self.gens = gens
+        self.slices = slices
+        self.skey = skey
+        self.all_local = all_local
+        self.decisions: dict[str, int] = {}
+
+
+class _RowMark:
+    """Stands in for the row id while a leaf's canonical string is cut
+    around it."""
+
+    def __str__(self):
+        return "\0"
+
+
 class Planner:
     """One per executor. Thread-safe: planning itself runs on the
-    query thread; the memo, the seen LRU and inserts into the estimate
-    cache take the planner lock (a ``HotLock``: every request thread
-    enters it), reads of warm estimates do not."""
+    query thread; the memo, the seen LRU and inserts into the row memo
+    take the planner lock (a ``HotLock``: every request thread enters
+    it), reads of warm estimates do not."""
 
     def __init__(self, holder, margin: float = 0.5,
                  subresult_entries: int = 512,
@@ -162,106 +229,121 @@ class Planner:
         self.calibration = None
         self._mu = HotLock()
         self._seen: OrderedDict[str, int] = OrderedDict()
-        self._estimates: OrderedDict[tuple, tuple] = OrderedDict()
-        # Finished-plan memo: key -> {planned, roots, fingerprint,
-        # decisions, deps, cse_nodes} (plan_query_cached). The
-        # executor's route records live here too, one an index under
-        # ("route", index) (memo_get / memo_put / memo_drop): what a
-        # read resolves once per generation — the plan of a call shape,
+        # The row memo: (leaf canon, view uid, view generation, row,
+        # slices key, all_local) -> the finished leaf PlanNode. The
+        # view token stands for every fragment's epoch and for the
+        # absent ones, so a write to the view gives a new key and the
+        # old entries age out (oldest insertion first); nothing is ever
+        # invalidated in place.
+        self._rows: dict[tuple, PlanNode] = {}
+        # Shape entries: (index, shape key, slices key, all_local) ->
+        # {idx, skels, deps, fingerprint, hits} (plan_query_cached).
+        # The executor's route records live here too, one an index
+        # under ("route", index) (memo_get / memo_put / memo_drop):
+        # what a read resolves once — the plan of a statement shape,
         # the route of a slice set — is one bounded LRU under one
-        # validity rule, a compare of view tokens.
+        # validity rule, identity and view tokens compared.
         self._plans: OrderedDict[tuple, dict] = OrderedDict()
+        # /debug/vars.planShapes (with pql.parser's own `full`): reads
+        # planned from a shape entry, entries built, and whole planning
+        # passes (plan_query: ?profile=1, ?plan=1, a shape with an
+        # unhashable value). Plain bumps, like decision_totals.
+        self.shapes = {"hits": 0, "misses": 0, "full": 0}
         # Decision roll-up for the blackbox / debug snapshot.
         self.decision_totals: dict[str, int] = {}
+        self._decision_counters: dict[str, object] = {}
 
     # -- public entry points -------------------------------------------------
 
     def plan_query(self, index: str, calls: list[Call], slices,
-                   all_local: bool = True,
-                   record: Optional[PlanRecord] = None,
-                   deps: Optional[list] = None
+                   all_local: bool = True, node: str = ""
                    ) -> tuple[list[Call], PlanRecord]:
-        """Plan a batch of read calls. Returns (rewritten clones, the
-        populated record). Caller is responsible for gating (write
-        queries and disabled planning never reach here). ``deps``, when
-        given, collects the (frame, view, view-token) facts the
-        plan's estimates rest on — the memo validity set."""
+        """Plan a batch of read calls in full: the shape's skeleton is
+        built from scratch and bound to the calls' own values, nothing
+        is looked up or kept. Returns (rewritten clones, the populated
+        record). Caller is responsible for gating (write queries and
+        disabled planning never reach here)."""
         t0 = time.perf_counter()
-        if record is None:
-            record = PlanRecord(fingerprint_calls(calls))
-        idx = self.holder.index(index)
-        slices = tuple(map(int, slices))
-        planned: list[Call] = []
+        all_local = bool(all_local)
+        self.shapes["full"] += 1
+        vals: list = []
         for call in calls:
-            c = call.clone()
-            node = self._plan_call(idx, index, c, slices, all_local,
-                                   record, covered=True, deps=deps)
-            record.roots.append(node)
-            planned.append(c)
-        record.note("planned")
-        self._bump("planned")
+            shape.ints_of(call, vals)
+        ent = self._shape_entry(index, calls)
+        out = self._bind_query(ent, vals, self._shape_gens(index, ent),
+                               slices, None, all_local, node)
         obs_metrics.PLANNER_PLAN_SECONDS.observe(
             time.perf_counter() - t0)
-        return planned, record
+        return out
 
     def plan_query_cached(self, index: str, calls: list[Call], slices,
                           all_local: bool = True, node: str = "",
                           slices_key: Optional[tuple] = None
                           ) -> tuple[list[Call], PlanRecord]:
-        """``plan_query`` behind a bounded memo: a repeated query (the
-        hot shape the PR-9 caches serve) reuses its finished plan
-        instead of re-walking estimation and fingerprinting, so
-        planning amortizes to a key build plus a validity sweep.
+        """``plan_query`` with the statement's shape planned once.
+
+        The entry is keyed by (index, the calls with their integer
+        argument values lifted out — ``pql.shape.lift`` —, the slice
+        set's name, ``all_local``), NOT by the row ids: it holds what
+        no parameter changes — the fingerprint, one ``_Skel`` a call
+        (frame, view, purity, which slot names the row, the canonical
+        string cut around it) and the facts those rest on. A request
+        then does only what depends on its rows (``_bind``): one
+        estimate a leaf from the row memo, the short-circuit proofs,
+        the smallest-first order, placement from k numbers and the CSE
+        ladder — the plan ``plan_query`` makes for the same request,
+        because ``plan_query`` is the same skeleton and the same bind.
         ``slices_key`` is the caller's O(1) name for the slice set
         (the executor's route record has one); absent, the slices
         themselves key the entry.
 
-        Safety: every entry carries the exact facts its proofs rest on
-        — frame/view identity and each view's mutation token (any
+        Safety: an entry is used only while the index, each frame and
+        each standard view it resolved are still those OBJECTS (a drop
+        and recreate, a view appearing, voids it), and every estimate
+        is keyed by the view's mutation token read BEFORE binding (any
         write to, and any fragment appearing in or leaving, the view
         moves it: a fragment appearing breaks an emptiness proof as
-        surely as a write), including PROVABLY ABSENT views. Any
-        mismatch discards the entry and replans, so a memoized
-        short-circuit can never outlive the emptiness it proved. Plan
-        NODES are shared across hits; the per-query PlanRecord
-        (actuals, stitched legs) is always fresh."""
+        surely as a write), so a proof can never outlive the emptiness
+        it proved. Leaf plan NODES are shared between requests that
+        name the same row; the per-query PlanRecord (actuals, stitched
+        legs) and every interior node are always fresh. A hit samples
+        its observability 1 time in 16; a first sighting always. A
+        warm shape holds the planner's lock once, for the CSE ladder
+        of a Count's bitmap child (and once more when it samples)."""
+        all_local = bool(all_local)
         if slices_key is None:
             slices_key = tuple(map(int, slices))
+        vals: list = []
         try:
-            key = (index, tuple(_memo_call_key(c) for c in calls),
-                   slices_key, bool(all_local))
-            ent = self.memo_get(key)
+            key = (index, tuple([shape.lift(c, vals) for c in calls]),
+                   slices_key, all_local)
+            # Read without the lock (one dict look-up); a hit renews
+            # its place in the LRU when it samples, 1 time in 16.
+            ent = self._plans.get(key)
         except TypeError:
-            # Unhashable literal somewhere in the tree — plan uncached.
-            key = ent = None
-        if ent is not None and self._deps_valid(index, ent["deps"]):
-            ent["hits"] = hits = ent["hits"] + 1
-            rec = PlanRecord(ent["fingerprint"], node=node)
-            # Roots/calls are aliased, not copied: plan shape is
-            # immutable after planning (only per-node actuals race,
-            # and those are observability-only).
-            rec.roots = ent["roots"]
-            rec.decisions.update(ent["decisions"])
-            rec.sample = hits % 16 == 0
-            # A hit is another sighting of every cacheable subtree —
-            # keep the cross-query CSE ladder climbing to store state.
-            for n in ent["cse_nodes"]:
-                if not n.cache_store:
-                    self._mark_cse(n)
-            return ent["planned"], rec
-        rec = PlanRecord(fingerprint_calls(calls), node=node)
-        deps: list[tuple] = []
-        planned, rec = self.plan_query(index, calls, slices,
-                                       all_local=all_local,
-                                       record=rec, deps=deps)
-        cse_nodes = [n for root in rec.roots
-                     for n in _walk_nodes(root) if n.cache_lookup]
-        if key is not None:
-            ent = {"planned": planned, "roots": list(rec.roots),
-                   "fingerprint": rec.fingerprint,
-                   "decisions": rec.decision_summary(),
-                   "deps": deps, "cse_nodes": cse_nodes, "hits": 0}
+            # Unhashable literal somewhere in the tree: no shape.
+            return self.plan_query(index, calls, slices, all_local,
+                                   node=node)
+        gens = None if ent is None else self._shape_gens(index, ent)
+        built = gens is None
+        if built:
+            t0 = time.perf_counter()
+            ent = self._shape_entry(index, calls)
+            gens = self._shape_gens(index, ent)
             self.memo_put(key, ent)
+            self.shapes["misses"] += 1
+            hits = 0
+        else:
+            self.shapes["hits"] += 1
+            ent["hits"] = hits = ent["hits"] + 1
+            if hits % 16 == 0:
+                self.memo_get(key)
+        planned, rec = self._bind_query(ent, vals, gens, slices,
+                                        slices_key, all_local, node)
+        rec.sample = hits % 16 == 0
+        if built:
+            obs_metrics.PLANNER_PLAN_SECONDS.observe(
+                time.perf_counter() - t0)
         return planned, rec
 
     # -- the memo's three verbs (plans here, routes from the executor) -------
@@ -286,31 +368,6 @@ class Planner:
             if self._plans.get(key) is ent:
                 del self._plans[key]
 
-    def _deps_valid(self, index: str, deps) -> bool:
-        """True when every fact a memoized plan depends on still
-        holds. Identity checks (``is``) catch drop-and-recreate, not
-        just mutation; one token compare a view stands for every
-        fragment of it."""
-        idx = self.holder.index(index)
-        if idx is None:
-            return False
-        try:
-            for d in deps:
-                kind = d[0]
-                if kind == "view":
-                    _, frame, view, gen = d
-                    if frame.views.get("standard") is not view:
-                        return False
-                    if view is not None and view.generation != gen:
-                        return False
-                else:  # "frame"
-                    _, name, frame = d
-                    if idx.frames.get(name) is not frame:
-                        return False
-        except Exception:  # noqa: BLE001 - any doubt means replan
-            return False
-        return True
-
     def explain(self, index: str, calls: list[Call], slices,
                 all_local: bool = True) -> dict:
         """EXPLAIN-only (?plan=1): plan without executing."""
@@ -324,6 +381,7 @@ class Planner:
             totals = dict(self.decision_totals)
             seen = len(self._seen)
         out = {"decisions": totals, "seenSubtrees": seen,
+               "shapes": dict(self.shapes),
                "subresultCache": self.subresults.stats()}
         if self.calibration is not None:
             out["calibration"] = self.calibration.to_dict()
@@ -332,110 +390,217 @@ class Planner:
     # -- decision bookkeeping ------------------------------------------------
 
     def _bump(self, outcome: str) -> None:
-        obs_metrics.PLANNER_DECISIONS.labels(outcome).inc()
-        # A plain bump, no lock (see _sum_slices): a roll-up for the
-        # debug surfaces, where a rare lost count is accepted.
+        counter = self._decision_counters.get(outcome)
+        if counter is None:
+            counter = self._decision_counters[outcome] = \
+                obs_metrics.PLANNER_DECISIONS.labels(outcome)
+        counter.inc()
+        # A plain bump, no lock: a roll-up for the debug surfaces,
+        # where a rare lost count is accepted.
         self.decision_totals[outcome] = \
             self.decision_totals.get(outcome, 0) + 1
 
-    def _decide(self, record: PlanRecord, node: PlanNode,
+    def _decide(self, b: _Binding, node: PlanNode,
                 outcome: str) -> None:
         node.decisions.append(outcome)
-        record.note(outcome)
+        b.decisions[outcome] = b.decisions.get(outcome, 0) + 1
         self._bump(outcome)
 
-    # -- recursive planning --------------------------------------------------
+    # -- the shape's half: built once ----------------------------------------
 
-    def _plan_call(self, idx, index: str, call: Call, slices,
-                   all_local: bool, record: PlanRecord,
-                   covered: bool = False,
-                   deps: Optional[list] = None) -> PlanNode:
-        """Plan one call subtree in place (mutates the clone).
-        ``covered`` marks subtrees the executor's whole-result caches
-        already key (the root of a Union/Intersect/Difference call) —
-        those skip subresult-cache marking to avoid double storage."""
-        name = call.name
+    def _shape_entry(self, index: str, calls: list[Call]) -> dict:
+        """Everything planning ``calls`` needs that does not depend on
+        their integer values."""
+        idx = self.holder.index(index)
+        deps: list[tuple] = []
+        skels = []
+        n = 0
+        for call in calls:
+            spec, n = shape.spec_of(call, n)
+            skels.append(self._skeleton(idx, spec, deps, covered=True))
+        self._bump("planned")
+        return {"idx": idx, "skels": skels, "deps": deps,
+                "fingerprint": fingerprint_calls(calls), "hits": 0}
+
+    def _shape_gens(self, index: str, ent: dict) -> Optional[list]:
+        """The view tokens a bind of ``ent`` keys its estimates by, one
+        a dependency, or None when a fact the skeleton rests on no
+        longer holds. Identity checks (``is``) catch drop-and-recreate
+        and a view appearing; the token, read here BEFORE any estimate
+        walks a fragment, stands for every fragment of the view."""
+        try:
+            idx = self.holder.index(index)
+            if idx is not ent["idx"]:
+                return None
+            gens = []
+            for name, frame, view in ent["deps"]:
+                if idx.frames.get(name) is not frame:
+                    return None
+                now = None if frame is None \
+                    else frame.views.get("standard")
+                if now is not view:
+                    return None
+                gens.append(0 if view is None else view.generation)
+        except Exception:  # noqa: BLE001 - any doubt means replan
+            return None
+        return gens
+
+    def _skeleton(self, idx, spec, deps: list,
+                  covered: bool = False) -> _Skel:
+        """``spec``'s subtree as ``_Skel``s. ``covered`` marks
+        subtrees the executor's whole-result caches already key (the
+        root of a Union/Intersect/Difference call) — those skip
+        subresult-cache marking to avoid double storage."""
+        name = spec[0]
         if name == "Bitmap":
-            return self._plan_leaf(idx, call, slices, all_local, deps)
-        if name in _BITMAP_OPS:
-            return self._plan_bitmap_op(idx, index, call, slices,
-                                        all_local, record, covered,
-                                        deps)
-        # Wrappers (Count/TopN/...) — plan bitmap children; the call
-        # itself is a pass-through node.
-        node = PlanNode(name)
-        for child in call.children:
-            node.children.append(self._plan_call(
-                idx, index, child, slices, all_local, record,
-                deps=deps))
+            return self._leaf_skeleton(idx, spec, deps)
+        sk = _Skel(name, spec, _OP if name in _BITMAP_OPS else _WRAP)
+        sk.covered = covered and sk.kind == _OP
+        # Wrappers (Count/TopN/...) plan their bitmap children; the
+        # call itself is a pass-through node.
+        sk.kids = [self._skeleton(idx, kid, deps) for kid in spec[3]]
+        return sk
+
+    def _leaf_skeleton(self, idx, spec, deps: list) -> _Skel:
+        sk = _Skel("Bitmap", spec, _OPEN)
+        args = spec[1]
+        frame_name = args.get("frame")
+        if not isinstance(frame_name, str) or not frame_name:
+            frame_name = "general"  # executor.DEFAULT_FRAME
+        if idx is None or args.get("filter") is not None:
+            return sk
+        frame = idx.frames.get(frame_name)
+        dep = len(deps)
+        for i, d in enumerate(deps):
+            if d[0] == frame_name:
+                dep = i
+                break
+        else:
+            # A frame or a view APPEARING breaks what was resolved
+            # here ("no view" = exact 0), so both absences are facts.
+            deps.append((frame_name, frame,
+                         None if frame is None
+                         else frame.views.get("standard")))
+        if frame is None:
+            # No frame: estimation stays open (the executor raises its
+            # own FrameNotFound; planning must not pre-empt errors).
+            return sk
+        slot = None
+        for key, fill in spec[2]:
+            if key == frame.row_label and type(fill) is int:
+                slot = fill
+        if slot is None:
+            # Inverse leaves (columnID) read the inverse view over a
+            # different slice domain; leave them unestimated.
+            return sk
+        sk.kind = _LEAF
+        sk.dep = dep
+        sk.row_slot = slot
+        sk.row_key = frame.row_label
+        sk.frame_name = frame_name
+        sk.frames = frozenset((f"{frame_name}/standard",))
+        if len(spec[2]) == 1 and not spec[3]:
+            marked = dict(args)
+            marked[frame.row_label] = _RowMark()
+            text = str(Call("Bitmap", marked))
+            if text.count("\0") == 1:
+                sk.canon = tuple(text.split("\0"))
+        return sk
+
+    # -- the request's half: bound every time --------------------------------
+
+    def _bind_query(self, ent: dict, vals: list, gens: Optional[list],
+                    slices, slices_key, all_local: bool, node: str
+                    ) -> tuple[list[Call], PlanRecord]:
+        if gens is None:
+            # A fact moved between the build and this read of it: bind
+            # what was resolved, keep no estimate.
+            gens = [None] * len(ent["deps"])
+        b = _Binding(vals, ent["deps"], gens, slices, slices_key,
+                     all_local)
+        planned: list[Call] = []
+        rec = PlanRecord(ent["fingerprint"], node=node)
+        for sk in ent["skels"]:
+            call, pnode = self._bind(sk, b)
+            planned.append(call)
+            rec.roots.append(pnode)
+        b.decisions["planned"] = 1
+        rec.decisions = b.decisions
+        return planned, rec
+
+    def _bind(self, sk: _Skel, b: _Binding) -> tuple[Call, PlanNode]:
+        """The planned call and plan node of ``sk`` for one request's
+        values. Every call is a fresh object carrying its node as
+        ``_plan_node``."""
+        kind = sk.kind
+        if kind == _LEAF:
+            return self._bind_leaf(sk, b)
+        if kind == _OP:
+            return self._bind_bitmap_op(sk, b)
+        if kind == _OPEN:
+            call = shape.bind_call(sk.spec, b.vals)
+            node = call._plan_node = PlanNode("Bitmap")
+            return call, node
+        node = PlanNode(sk.name)
+        children = []
+        for kid in sk.kids:
+            child, child_node = self._bind(kid, b)
+            children.append(child)
+            node.children.append(child_node)
+        call = Call(sk.name, shape.bind_args(sk.spec, b.vals), children)
+        call._plan_node = node
         if node.children:
             first = node.children[0]
             node.est_rows = first.est_rows
             node.exact = first.exact
-            if name == "Count" and first.short_circuit:
+            if sk.name == "Count" and first.short_circuit:
                 # Count of a proven-empty subtree answers 0 without
                 # fan-out.
                 node.short_circuit = True
-                self._decide(record, node, "short_circuit")
-        return node
+                self._decide(b, node, "short_circuit")
+        return call, node
 
-    def _plan_bitmap_op(self, idx, index: str, call: Call, slices,
-                        all_local: bool, record: PlanRecord,
-                        covered: bool,
-                        deps: Optional[list] = None) -> PlanNode:
-        node = PlanNode(call.name)
-        child_nodes = [self._plan_call(idx, index, c, slices,
-                                       all_local, record, deps=deps)
-                       for c in call.children]
+    def _bind_bitmap_op(self, sk: _Skel,
+                        b: _Binding) -> tuple[Call, PlanNode]:
+        name = sk.name
+        node = PlanNode(name)
+        children = []
+        child_nodes = []
+        for kid in sk.kids:
+            child, child_node = (self._bind_leaf(kid, b)
+                                 if kid.kind == _LEAF
+                                 else self._bind(kid, b))
+            children.append(child)
+            child_nodes.append(child_node)
+        call = Call(name, shape.bind_args(sk.spec, b.vals), children)
+        call._plan_node = node
 
         # Short-circuit rewrites (exact proofs only).
-        if call.name == "Intersect":
-            if any(c.exact and c.est_rows == 0 for c in child_nodes):
+        empty = [c.exact and c.est_rows == 0 for c in child_nodes]
+        if True in empty or (name == "Union" and not empty):
+            if name == "Intersect" or (name == "Difference" and empty[0]) \
+                    or False not in empty:
                 node.short_circuit = True
                 node.est_rows, node.exact = 0, True
                 node.children = child_nodes
-                self._decide(record, node, "short_circuit")
-                return node
-        elif call.name == "Union":
-            keep = [i for i, c in enumerate(child_nodes)
-                    if not (c.exact and c.est_rows == 0)]
-            if not keep:
-                node.short_circuit = True
-                node.est_rows, node.exact = 0, True
-                node.children = child_nodes
-                self._decide(record, node, "short_circuit")
-                return node
-            if len(keep) < len(child_nodes):
-                call.children = [call.children[i] for i in keep]
-                child_nodes = [child_nodes[i] for i in keep]
-                self._decide(record, node, "short_circuit")
-        elif call.name == "Difference":
-            if (child_nodes and child_nodes[0].exact
-                    and child_nodes[0].est_rows == 0):
-                node.short_circuit = True
-                node.est_rows, node.exact = 0, True
-                node.children = child_nodes
-                self._decide(record, node, "short_circuit")
-                return node
-            keep = [0] + [i for i in range(1, len(child_nodes))
-                          if not (child_nodes[i].exact
-                                  and child_nodes[i].est_rows == 0)]
-            if child_nodes and len(keep) < len(child_nodes):
-                call.children = [call.children[i] for i in keep]
-                child_nodes = [child_nodes[i] for i in keep]
-                self._decide(record, node, "short_circuit")
+                self._decide(b, node, "short_circuit")
+                return call, node
+            # A Union's, or a Difference's subtrahends': dropped.
+            keep = [i for i, e in enumerate(empty) if not e]
+            call.children = children = [children[i] for i in keep]
+            child_nodes = [child_nodes[i] for i in keep]
+            self._decide(b, node, "short_circuit")
 
         # Reorder commutative operands smallest-first.
-        if call.name in ("Intersect", "Union") and len(child_nodes) > 1:
-            order = sorted(
-                range(len(child_nodes)),
-                key=lambda i: (child_nodes[i].est_rows
-                               if child_nodes[i].est_rows is not None
-                               else float("inf")))
-            if order != list(range(len(child_nodes))):
-                call.children = [call.children[i] for i in order]
+        n = len(child_nodes)
+        if name != "Difference" and n > 1:
+            ests = [float("inf") if c.est_rows is None else c.est_rows
+                    for c in child_nodes]
+            order = sorted(range(n), key=ests.__getitem__)
+            if order != list(range(n)):
+                call.children = [children[i] for i in order]
                 child_nodes = [child_nodes[i] for i in order]
-                self._decide(record, node, "reordered")
+                self._decide(b, node, "reordered")
 
         node.children = child_nodes
 
@@ -444,13 +609,13 @@ class Planner:
         known = [e for e in ests if e is not None]
         all_exact = bool(child_nodes) and all(c.exact
                                               for c in child_nodes)
-        if call.name == "Intersect" and known:
+        if name == "Intersect" and known:
             node.est_rows = min(known)
             node.exact = all_exact and node.est_rows == 0
-        elif call.name == "Union" and len(known) == len(ests):
+        elif name == "Union" and len(known) == len(ests):
             node.est_rows = sum(known)
             node.exact = all_exact and node.est_rows == 0
-        elif call.name == "Difference" and ests and ests[0] is not None:
+        elif name == "Difference" and ests and ests[0] is not None:
             node.est_rows = ests[0]
             node.exact = child_nodes[0].exact and node.est_rows == 0
 
@@ -464,50 +629,54 @@ class Planner:
             frames.update(c.frames)
         if pure:
             node.frames = frozenset(frames)
-            node.key = str(call)
-            if not covered:
+            if not sk.covered:
+                # The canonical subtree string keys the CSE ladder and
+                # the subresult cache, and nothing else reads it: a
+                # covered root never needs one. Every child of a pure
+                # node carries its own.
+                node.key = str(call) if call.args else "%s(%s)" % (
+                    name, ", ".join([c.key for c in child_nodes]))
                 self._mark_cse(node)
-            self._placement(node, slices)
-        return node
+            self._placement(node, b.slices)
+        return call, node
 
-    def _plan_leaf(self, idx, call: Call, slices,
-                   all_local: bool,
-                   deps: Optional[list] = None) -> PlanNode:
-        node = PlanNode("Bitmap")
-        frame_name = call.args.get("frame")
-        if not isinstance(frame_name, str) or not frame_name:
-            frame_name = "general"  # executor.DEFAULT_FRAME
-        if idx is None or call.args.get("filter") is not None:
-            return node
-        frame = idx.frames.get(frame_name)
-        if frame is None:
-            # No frame: estimation stays open (the executor raises its
-            # own FrameNotFound; planning must not pre-empt errors).
-            return node
-        try:
-            row_id, row_ok = call.uint_arg(frame.row_label)
-        except ValueError:
-            row_ok = False
-            row_id = 0
-        if not row_ok:
-            # Inverse leaves (columnID) read the inverse view over a
-            # different slice domain; leave them unestimated.
-            return node
-        node.detail = f"{frame_name}/{row_id}"
-        view = frame.views.get("standard")
-        node.frames = frozenset((f"{frame_name}/standard",))
-        node.key = str(call)
-        if deps is not None:
-            deps.append(("frame", frame_name, frame))
-            # A view APPEARING breaks a proof ("no view" = exact 0);
-            # its token, read BEFORE the estimate walks its fragments,
-            # stands for every fragment's epoch and for the absent ones.
-            deps.append(("view", frame, view,
-                         view.generation if view is not None else 0))
-        est, exact = self._estimate_row(view, row_id, slices,
-                                        all_local)
-        node.est_rows, node.exact = est, exact
-        return node
+    def _bind_leaf(self, sk: _Skel,
+                   b: _Binding) -> tuple[Call, PlanNode]:
+        raw = b.vals[sk.row_slot]
+        canon = sk.canon
+        if canon is None:
+            call = shape.bind_call(sk.spec, b.vals)
+        else:  # the row is the leaf's one parameter
+            args = dict(sk.spec[1])
+            args[sk.row_key] = raw
+            call = Call("Bitmap", args)
+        view = b.deps[sk.dep][2]
+        gen = b.gens[sk.dep]
+        key = node = None
+        if (canon is not None and view is not None and gen is not None
+                and b.skey is not None):
+            # Read without the lock (one dict look-up is atomic under
+            # the interpreter's own lock): estimates once took the
+            # planner's lock per leaf and slice, and eight connection
+            # threads doing so formed a convoy on it (PERF.md, PR 27).
+            key = (canon, view.uid, gen, raw, b.skey, b.all_local)
+            node = self._rows.get(key)
+        if node is None:
+            row_id = raw & 0xFFFFFFFFFFFFFFFF  # Call.uint_arg's wrap
+            node = PlanNode("Bitmap", f"{sk.frame_name}/{row_id}")
+            node.frames = sk.frames
+            node.key = (str(call) if canon is None
+                        else f"{canon[0]}{raw}{canon[1]}")
+            node.est_rows, node.exact = self._estimate_row(
+                view, row_id, b.slices, b.all_local)
+            if key is not None:
+                with self._mu:
+                    rows = self._rows
+                    rows[key] = node
+                    while len(rows) > _ESTIMATE_CACHE_ENTRIES:
+                        del rows[next(iter(rows))]
+        call._plan_node = node
+        return call, node
 
     def _estimate_row(self, view, row_id: int, slices,
                       all_local: bool) -> tuple[int, bool]:
@@ -529,28 +698,12 @@ class Planner:
         total = 0
         exact = all_local
         for s in slices:
-            frag = view.fragments.get(s)
+            frag = view.fragments.get(int(s))
             if frag is None:
                 # Locally absent fragment = 0 bits — exact only when
                 # this node owns every slice of the query. (A fragment
                 # appearing voids the proof: it moves the view's token,
-                # which the caller recorded as the memo dependency.)
-                continue
-            key = (id(view), row_id, s)
-            epoch = getattr(frag, "_epoch", 0)
-            # Read without the lock (one dict look-up is atomic under
-            # the interpreter's own lock) and without refreshing the
-            # entry's place: k leaves x 8 sampled slices a plan took
-            # the planner's lock 16-32 times a request, and eight
-            # connection threads doing so formed a convoy on it — the
-            # waiter that is handed the lock holds it while it waits for
-            # the interpreter, so everyone behind it queues: `plan` read
-            # 0.2 ms or 8-20 ms for tens of seconds at a time (PERF.md,
-            # PR 27). Eviction is by age of insertion; the cache holds
-            # 4,096 estimates and a hot set is a few hundred.
-            hit = self._estimates.get(key)
-            if hit is not None and hit[0] == epoch:
-                total += hit[1]
+                # which keys the row memo.)
                 continue
             n = 0
             try:
@@ -568,10 +721,6 @@ class Planner:
                 exact = False
                 continue
             total += n
-            with self._mu:
-                self._estimates[key] = (epoch, n)
-                while len(self._estimates) > _ESTIMATE_CACHE_ENTRIES:
-                    self._estimates.popitem(last=False)
         return (total, exact)
 
     # -- CSE + placement -----------------------------------------------------
@@ -600,12 +749,14 @@ class Planner:
         cal = self.calibration
         if cal is None or not slices:
             return
-        leaves = _count_leaves(node)
+        ests: list = []
+        _leaf_estimates(node, ests)
+        leaves = len(ests)
         n_slices = len(slices)
         slab = n_slices * WORDS_PER_SLICE * 4
         device_bytes = leaves * slab
         host_bytes = 0
-        for leaf_est in _leaf_estimates(node):
+        for leaf_est in ests:
             if leaf_est is None:
                 host_bytes += slab
             else:
@@ -636,38 +787,10 @@ class Planner:
         return (index, node.key, int(slice), tuple(out))
 
 
-def _memo_call_key(call: Call) -> tuple:
-    """Structural memo key for one call — a nested tuple, much cheaper
-    to build than the canonical string. Raises TypeError on an
-    unhashable literal (caller plans uncached)."""
-    items = []
-    for k in sorted(call.args):
-        v = call.args[k]
-        if isinstance(v, Condition):
-            v = (v.op, v.value if not isinstance(v.value, list)
-                 else tuple(v.value))
-        elif isinstance(v, list):
-            v = tuple(v)
-        items.append((k, v))
-    return (call.name, tuple(items),
-            tuple(_memo_call_key(c) for c in call.children))
-
-
-def _walk_nodes(node: PlanNode):
-    yield node
-    for c in node.children:
-        yield from _walk_nodes(c)
-
-
-def _count_leaves(node: PlanNode) -> int:
+def _leaf_estimates(node: PlanNode, out: list) -> None:
+    """Append the estimate of every leaf under ``node`` to ``out``."""
     if not node.children:
-        return 1
-    return sum(_count_leaves(c) for c in node.children)
-
-
-def _leaf_estimates(node: PlanNode):
-    if not node.children:
-        yield node.est_rows
+        out.append(node.est_rows)
         return
     for c in node.children:
-        yield from _leaf_estimates(c)
+        _leaf_estimates(c, out)

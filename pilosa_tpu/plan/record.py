@@ -162,11 +162,11 @@ class PlanRecord:
         self.decisions: dict[str, int] = {}
         self.children: list[dict] = []
         self.analyze = False
-        # Observability sampling gate: freshly-planned queries and a
-        # 1-in-16 slice of plan-memo hits carry full per-node actuals
-        # and feed the plan store / misestimation stream; the rest skip
-        # that bookkeeping (the ≤2% overhead budget). ?profile=1
-        # (analyze) always records.
+        # Observability sampling gate: a shape's first sighting, every
+        # whole planning pass and a 1-in-16 slice of shape hits carry
+        # full per-node actuals and feed the plan store /
+        # misestimation stream; the rest skip that bookkeeping (the
+        # ≤2% overhead budget). ?profile=1 (analyze) always records.
         self.sample = True
         self._mu = threading.Lock()
 

@@ -12,13 +12,30 @@ Reference: pql/scanner.go (token rules) and pql/parser.go (grammar):
 Token rules match the reference scanner exactly: idents start with a letter
 and continue with [A-Za-z0-9_\\-.]; numbers allow one leading '-' and one
 '.'; strings are single- or double-quoted with \\n, \\\\, \\", \\' escapes.
+
+``parse`` runs that grammar once per statement SHAPE (``pql.shape``: the
+text with its integer argument values lifted out), not once per request:
+one compiled-regex pass over the text yields the shape key and the
+integer literals, a bounded memo maps the key to the parsed template,
+and a request gets a fresh call tree with its own values bound
+(``_parse_shaped``). Parameters are integers in argument-value position
+only (``rowID=3``, list members, condition operands); identifiers,
+strings, floats, timestamps, ``true/false/null`` and operators stay in
+the key verbatim. Whatever the pass cannot prove it read exactly as
+``Scanner`` would — a backslash anywhere, an integer past 18 digits, a
+text no template verifies for, every error — goes through
+``Parser(text).parse()`` unchanged, so each message and position is the
+full grammar's. The memo is a pure function of the text: bounded, never
+invalidated.
 """
 
 from __future__ import annotations
 
 import re
+import threading
 
 from ..errors import PilosaError
+from . import shape
 from .ast import Call, Condition, Query
 
 EOF = "EOF"
@@ -436,8 +453,77 @@ def _parse_fast(text: str):
     return query
 
 
+# The shape pass. Both patterns start on one character set (the search
+# skips to the next quote or value delimiter) and read the same two
+# things. A quoted string with no backslash in it is taken whole, so
+# digits inside it are never seen. An integer of at most 18 digits
+# (always inside int64) that follows a value delimiter — the `=` `[` `,`
+# `<` `>` that end EQ, LBRACK, COMMA and every COND token — and blanks,
+# and is not the head of a float, is a parameter: `_LIFT_KEY_RE` keeps
+# group 1 (the string, or the delimiter and blanks) and writes `?` for
+# the digits (and after a string: a key is only compared),
+# `_LIFT_INTS_RE` captures the digits. Digits inside identifiers (`f1`,
+# `a-5`) follow an identifier character, never a delimiter. Every other
+# character — blanks, names, floats, longer integers, stray quotes,
+# illegal characters — stays in the key as written, so two texts with
+# one key and one count of literals differ only in their integers'
+# digits, and `Scanner` reads them alike (a literal `?` is an ILLEGAL
+# token: a text with one never parses, so never becomes a template;
+# `_parse_shaped` holds a template to the count AND the values of the
+# integers its own full parse found). Backslashes are not modelled:
+# such a text takes the full parser.
+_LIFT_KEY_RE = re.compile(
+    r"""(["'=\[,<>](?:(?<=")[^"\n]*"|(?<=')[^'\n]*'"""
+    r"""|(?<=[=\[,<>])\s*(?=-?[0-9]{1,18}(?![0-9.]))))"""
+    r"""(?:(?<!["'])-?[0-9]{1,18})?""")
+_LIFT_INTS_RE = re.compile(
+    r"""["'=\[,<>](?:(?<=")[^"\n]*"|(?<=')[^'\n]*'"""
+    r"""|(?<=[=\[,<>])\s*(-?[0-9]{1,18})(?![0-9.]))""")
+
+_SHAPE_TEXT_MAX = 4096   # longer bodies are write batches: _parse_fast's
+_SHAPE_ENTRIES = 512
+# shape key -> (call specs, parameter count); insertion-ordered, the
+# oldest entry leaves first. Read without the lock (one dict look-up).
+_shapes: dict[str, tuple] = {}
+_shapes_mu = threading.Lock()
+# Texts the shape pass could not parameterise (the `full` of
+# /debug/vars.planShapes, with the planner's own): a plain bump.
+shape_stats = {"full": 0}
+
+
+def _parse_shaped(text: str) -> Query:
+    """``Parser(text).parse()`` through the shape memo (module
+    docstring): a known shape costs the regex pass and a bind."""
+    if len(text) > _SHAPE_TEXT_MAX or "\\" in text:
+        shape_stats["full"] += 1
+        return Parser(text).parse()
+    key = _LIFT_KEY_RE.sub(r"\1?", text)
+    vals = [int(x) for x in _LIFT_INTS_RE.findall(text) if x]
+    ent = _shapes.get(key)
+    if ent is not None and ent[1] == len(vals):
+        return Query([shape.bind_call(spec, vals) for spec in ent[0]])
+    query = Parser(text).parse()
+    found: list = []
+    specs = []
+    n = 0
+    for call in query.calls:
+        shape.ints_of(call, found)
+        spec, n = shape.spec_of(call, n)
+        specs.append(spec)
+    if found != vals:
+        # The pass and the grammar disagree on this text's integers (one
+        # of 19 digits, say): it is parsed in full every time.
+        shape_stats["full"] += 1
+        return query
+    with _shapes_mu:
+        if len(_shapes) >= _SHAPE_ENTRIES:
+            _shapes.pop(next(iter(_shapes)), None)
+        _shapes[key] = (tuple(specs), n)
+    return query
+
+
 def parse(text: str) -> Query:
     fast = _parse_fast(text)
     if fast is not None:
         return fast
-    return Parser(text).parse()
+    return _parse_shaped(text)

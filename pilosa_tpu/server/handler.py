@@ -603,6 +603,15 @@ class Handler:
             # local leg on the calling thread, or the pool
             # (docs/OBSERVABILITY.md).
             snap["legs"] = dict(legs)
+        planner = getattr(self.executor, "planner", None)
+        if planner is not None:
+            # Planned reads by how their statement shape was found:
+            # bound from its entry, built on a first sighting, or
+            # taken through the whole planner / the whole parser
+            # (docs/OBSERVABILITY.md).
+            shapes = dict(planner.shapes)
+            shapes["full"] += pql.shape_stats["full"]
+            snap["planShapes"] = shapes
         model = getattr(self.executor, "cost_model", None)
         if model is not None:
             snap["costModel"] = {"syncS": model.cal.sync_s,
@@ -1893,8 +1902,9 @@ class Handler:
                     # Kept traces carry the plan fingerprint and the
                     # decision summary — a slow trace names the plan
                     # that produced it (/debug/plans has the tree).
-                    # Sampled out on most plan-memo hits (the ≤2%
-                    # overhead budget); fresh plans always carry it.
+                    # Sampled out on most shape hits (the ≤2% overhead
+                    # budget); a shape's first sighting and every
+                    # whole planning pass always carry it.
                     tags = {"fingerprint": ctx.plan.fingerprint}
                     tags.update(ctx.plan.decision_summary())
                     trace.add_span("query_plan", ctx.started_wall,
@@ -1940,9 +1950,10 @@ class Handler:
                     and (ctx.plan.sample or ctx.plan.analyze)):
                 # Per-fingerprint aggregation behind /debug/plans —
                 # coordinator-only so a fleet of remote legs does not
-                # multiply one query into N rows. Fresh plans and a
-                # 1-in-16 slice of memo hits record (an unbiased
-                # duration reservoir); the rest skip the bookkeeping.
+                # multiply one query into N rows. A shape's first
+                # sighting and a 1-in-16 slice of its hits record (an
+                # unbiased duration reservoir); the rest skip the
+                # bookkeeping.
                 est = actual = None
                 for root in ctx.plan.roots:
                     if (root.est_rows is not None

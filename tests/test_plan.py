@@ -271,26 +271,36 @@ class TestPlannerDecisions:
         assert ctx.plan is None
 
 
-# -- plan memo: reuse, validity sweep, sampling --------------------------------
+# -- shape entries: reuse across row ids, validity, sampling -------------------
 
 
 class TestPlanMemo:
-    def test_hit_reuses_finished_plan(self, planned_holder):
+    def test_hit_reuses_the_shape_entry_whatever_the_row(
+            self, planned_holder):
         ex = Executor(planned_holder, host="local", use_mesh=False)
-        q = "Count(Bitmap(rowID=1, frame=f))"
-        want = ex.execute("p", q)[0]
+        unplanned = Executor(planned_holder, host="local",
+                             use_mesh=False)
+        unplanned.planner_enabled = False
+
+        def q(row):
+            return f"Count(Bitmap(rowID={row}, frame=f))"
 
         def plans():  # the memo also holds the index's route record
             return [e for k, e in ex.planner._plans.items()
                     if k[0] != "route"]
+        assert ex.execute("p", q(1)) == unplanned.execute("p", q(1))
         assert len(plans()) == 1
         ent = plans()[0]
         assert ent["hits"] == 0
-        for _ in range(3):
+        assert ex.planner.shapes == {"hits": 0, "misses": 1, "full": 0}
+        for row in (2, 3, N_ROWS + 1):   # other rows, the same shape
             ex._bitmap_results.clear()
-            assert ex.execute("p", q)[0] == want
-        assert len(plans()) == 1
+            assert ex.execute("p", q(row)) == \
+                unplanned.execute("p", q(row))
+        assert plans() == [ent]
         assert ent["hits"] == 3
+        assert ex.planner.shapes == {"hits": 3, "misses": 1, "full": 0}
+        assert ex.planner.decision_totals["planned"] == 1
 
     def test_write_invalidates_memoized_plan(self, planned_holder):
         ex = Executor(planned_holder, host="local", use_mesh=False)
@@ -306,15 +316,15 @@ class TestPlanMemo:
     def test_view_appearing_voids_short_circuit_proof(self,
                                                       planned_holder):
         # An empty frame's missing standard view is an exact-0 proof;
-        # the first write creates the view and MUST void the memoized
-        # short-circuit, or the cached plan would keep answering 0.
+        # the first write creates the view and MUST void the shape
+        # entry that resolved "no view", or it would keep answering 0.
         planned_holder.index("p").create_frame("g")
         ex = Executor(planned_holder, host="local", use_mesh=False)
         bits = list(ex.execute("p", "Bitmap(rowID=0, frame=f)")[0].bits())
         col = bits[0]
         q = (f"Count(Intersect(Bitmap(rowID=0, frame=f),"
              f" Bitmap(rowID=0, frame=g)))")
-        for _ in range(2):  # second run serves from the memo
+        for _ in range(2):  # second run binds the kept shape
             ex._bitmap_results.clear()
             assert ex.execute("p", q)[0] == 0
         ex.execute("p", f"SetBit(frame=g, rowID=0, columnID={col})")
@@ -326,20 +336,26 @@ class TestPlanMemo:
         ex = Executor(planned_holder, host="local", use_mesh=False)
         for i in range(_PLAN_MEMO_ENTRIES + 20):
             ex.execute("p", f"Count(Bitmap(rowID={i}, frame=f))")
-        assert len(ex.planner._plans) <= _PLAN_MEMO_ENTRIES
+        # every row id of one shape is ONE entry (+ the route record)
+        assert len(ex.planner._plans) == 2
+        slices = list(range(N_SLICES))
+        for i in range(_PLAN_MEMO_ENTRIES + 20):   # as many shapes
+            calls = pql.parse(
+                f"Count(Bitmap(rowID=1, frame=f{i}))").calls
+            ex.planner.plan_query_cached("p", calls, slices)
+        assert len(ex.planner._plans) == _PLAN_MEMO_ENTRIES
 
-    def test_fresh_plans_sample_and_hits_sample_1_in_16(self,
-                                                        planned_holder):
+    def test_first_sighting_samples_and_hits_sample_1_in_16(
+            self, planned_holder):
         from pilosa_tpu.executor import ExecOptions
         ex = Executor(planned_holder, host="local", use_mesh=False)
-        query = pql.parse("Count(Bitmap(rowID=2, frame=f))")
         slices = list(range(N_SLICES))
-        _, rec = ex._maybe_plan("p", query, slices, ExecOptions())
-        assert rec.sample  # fresh plan: full fidelity
-        samples = []
-        for _ in range(16):
-            _, rec = ex._maybe_plan("p", query, slices, ExecOptions())
-            samples.append(rec.sample)
+
+        def plan(row):
+            query = pql.parse(f"Count(Bitmap(rowID={row}, frame=f))")
+            return ex._maybe_plan("p", query, slices, ExecOptions())[1]
+        assert plan(2).sample  # the shape's first sighting: in full
+        samples = [plan(row % N_ROWS).sample for row in range(16)]
         assert samples.count(True) == 1 and samples[-1]
 
 
@@ -366,16 +382,17 @@ class _CountingLock:
 
 class TestPlannerLock:
     @pytest.mark.parametrize("k", [2, 3, 4])
-    def test_a_warm_plan_takes_the_lock_a_few_times_not_per_slice(
+    def test_a_warm_shape_takes_the_lock_twice_whatever_the_rows(
             self, tmp_path, k):
         """Planning a k-leaf Count over many slices samples 8 slices a
         leaf; each sample used to take the planner's lock (16-32 times
         a request), and eight connection threads convoyed on it: the
         served ``plan`` stage read 0.2 ms or 8-20 ms for tens of
-        seconds at a time (PERF.md, PR 27). Warm estimates are read
-        without the lock: what is left is the memo's get and put, the
-        route record's get and the CSE ladder — whatever the leaf and
-        slice counts."""
+        seconds at a time (PERF.md, PR 27). A warm shape takes it for
+        the route record's get and for the CSE ladder — whatever the
+        leaf and slice counts and whichever rows are named (its entry
+        is read without the lock; one hit in 16 renews its LRU place
+        under it)."""
         from pilosa_tpu.plan import planner as planner_mod
         holder = Holder(str(tmp_path / "d"))
         holder.open()
@@ -396,10 +413,12 @@ class TestPlannerLock:
                 assert ex.execute("w", q(range(first, first + k)))[0] \
                     == 0
             lock = ex.planner._mu = _CountingLock()
-            rows = list(range(k))[::-1]         # a call shape not planned yet
+            hits = ex.planner.shapes["hits"]
+            rows = list(range(k))[::-1]         # rows not asked together yet
             assert ex.execute("w", q(rows))[0] == 0
-            assert 0 < lock.taken <= 6, lock.taken
-            assert ex.planner.decision_totals["planned"] >= 6 - k + 2
+            assert 0 < lock.taken <= 2, lock.taken
+            assert ex.planner.shapes["hits"] == hits + 1
+            assert ex.planner.decision_totals["planned"] == 1
         finally:
             holder.close()
 
@@ -578,18 +597,22 @@ class TestServingSurface:
 
     def test_debug_plans_aggregates(self, served):
         h, _ex, _reg = served
-        for row in (0, 1, 2):   # same shape, different literal
+        # Same shape, different literals: the first sighting records
+        # in full, then one request in 16 (the reservoir).
+        for row in range(33):
             _call(h, "POST", "/index/p/query",
-                  f"Count(Bitmap(rowID={row}, frame=f))".encode())
+                  f"Count(Bitmap(rowID={row % N_ROWS}, frame=f))".encode())
         st, _hd, body = _call(h, "GET", "/debug/plans")
         assert st == 200
         doc = json.loads(body)
         assert doc["enabled"] is True
         assert doc["fingerprints"] >= 1
         top = doc["plans"][0]
-        assert top["count"] >= 3     # three literals, ONE fingerprint
+        assert top["count"] == 3     # 33 requests, ONE fingerprint
         assert top["lastPlan"]["calls"][0]["op"] == "Count"
-        assert doc["planner"]["decisions"].get("planned", 0) >= 3
+        assert doc["planner"]["decisions"].get("planned", 0) == 1
+        assert doc["planner"]["shapes"] == {"hits": 32, "misses": 1,
+                                            "full": 0}
 
     def test_slow_log_cross_links_fingerprint(self, served):
         h, _ex, reg = served
