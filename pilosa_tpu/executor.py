@@ -4498,6 +4498,7 @@ class Executor:
         cost = ctx.cost if ctx is not None else None
         programs_before = cost.device_programs if cost is not None else 0
         cold_before = cost.cold_leaves if cost is not None else 0
+        waits_before = cost.fill_waits if cost is not None else 0
         # The first fan-out of a whole-index read takes its grouping
         # from the route record; a failover re-map walks.
         route = (getattr(slices, "route", None) if not opt.remote
@@ -4583,9 +4584,11 @@ class Executor:
         finally:
             if cost is not None and cost.device_programs > programs_before:
                 # How wide a mesh this fan-out's device programs ran
-                # on, and how many of their operand slabs it filled.
+                # on, how many of their operand slabs it filled and
+                # how many it waited for another query to fill.
                 span.tag(mesh_devices=cost.mesh_devices,
-                         cold_leaves=cost.cold_leaves - cold_before)
+                         cold_leaves=cost.cold_leaves - cold_before,
+                         fill_waits=cost.fill_waits - waits_before)
             span.__exit__(None, None, None)
             # On an error path, drain what we started: the pool is
             # shared with other queries, and the old per-query pool's

@@ -14,7 +14,8 @@ work at container granularity, not just wall-clock:
 - **bits written** (fragment mutate/import paths);
 - **device programs dispatched + device bytes**, the width of the
   widest mesh one of them ran on (parallel/mesh entry points), the
-  operand slabs the query filled (parallel/residency), and
+  operand slabs the query filled and those it waited for while another
+  query filled them (parallel/residency), and
   **XLA compile seconds** attributed to the query whose first call
   paid the trace+compile;
 - **RPC bytes in/out per peer** (cluster/client fan-out legs);
@@ -76,8 +77,9 @@ class QueryCost:
 
     __slots__ = ("node", "container_ops", "words_scanned",
                  "bits_written", "device_programs", "device_bytes",
-                 "mesh_devices", "cold_leaves", "compile_s", "wal_wait_s",
-                 "result_cache_hits", "rpc", "children", "_mu")
+                 "mesh_devices", "cold_leaves", "fill_waits", "compile_s",
+                 "wal_wait_s", "result_cache_hits", "rpc", "children",
+                 "_mu")
 
     def __init__(self, node: str = ""):
         self.node = node
@@ -92,6 +94,10 @@ class QueryCost:
         # Device operand slabs (leaf slabs, candidate blocks) this
         # query found not resident and built itself: packed, uploaded.
         self.cold_leaves = 0
+        # Operand slabs it found not resident while another query was
+        # building them, and waited for (stage ``fill_wait``): with
+        # ``cold_leaves`` 0 and this 0, every operand was resident.
+        self.fill_waits = 0
         self.compile_s = 0.0
         # Seconds this query's threads spent blocked in WAL group
         # commit (waiting for a leader's flush to cover their records)
@@ -127,6 +133,9 @@ class QueryCost:
 
     def note_cold_leaf(self) -> None:
         self.cold_leaves += 1
+
+    def note_fill_wait(self) -> None:
+        self.fill_waits += 1
 
     def note_compile(self, seconds: float) -> None:
         self.compile_s += seconds
@@ -182,6 +191,8 @@ class QueryCost:
             out["meshDevices"] = self.mesh_devices
         if self.cold_leaves:
             out["coldLeaves"] = self.cold_leaves
+        if self.fill_waits:
+            out["fillWaits"] = self.fill_waits
         if self.wal_wait_s:
             out["walWaitMs"] = round(self.wal_wait_s * 1e3, 3)
         if self.result_cache_hits:
@@ -215,6 +226,8 @@ class QueryCost:
             out["meshDevices"] = self.mesh_devices
         if self.cold_leaves:
             out["coldLeaves"] = self.cold_leaves
+        if self.fill_waits:
+            out["fillWaits"] = self.fill_waits
         if self.wal_wait_s:
             out["walWaitMs"] = round(self.wal_wait_s * 1e3, 3)
         if self.result_cache_hits:
@@ -314,6 +327,12 @@ def note_cold_leaf() -> None:
     cost = current_cost()
     if cost is not None:
         cost.note_cold_leaf()
+
+
+def note_fill_wait() -> None:
+    cost = current_cost()
+    if cost is not None:
+        cost.note_fill_wait()
 
 
 def note_compile(seconds: float) -> None:

@@ -481,6 +481,19 @@ def densify_mode() -> str | None:
     return "compiled" if jax.devices()[0].platform == "tpu" else None
 
 
+# The bucket widths ``ops.packed.sparse_gate`` lets through (a power of
+# two, at most 32): one densify program each, for every slab shape.
+DENSIFY_WIDTHS = (1, 2, 4, 8, 16, 32)
+
+# Told ``(mesh, lead_shape, subs, interpret)`` by every sparse upload,
+# on the filling thread. Which of a slab shape's widths a fill meets
+# depends on the row's data, not on the query's shape, so traffic
+# cannot be counted on to compile them all early: the warm-up lane
+# (sched.warmup) installs itself here and compiles a new shape's other
+# widths off the serving threads. None: nobody listens.
+on_densify = None
+
+
 @functools.lru_cache(maxsize=64)
 def _densify_sharded_fn(mesh: Mesh, lead_shape: tuple, subs: int,
                         g_slots: int, interpret: bool):
@@ -514,11 +527,25 @@ def densify_sharded(mesh: Mesh, lanes: np.ndarray, vals: np.ndarray,
     packing dense host-side and shipping 4 bytes per word, set or
     not."""
     _dispatch_gate()
+    heard = on_densify
+    if heard is not None:
+        heard((mesh, lanes.shape[:-2], lanes.shape[-2], interpret))
     dl = shard_slices(mesh, lanes)
     dv = shard_slices(mesh, vals)
     fn = _densify_sharded_fn(mesh, lanes.shape[:-2], lanes.shape[-2],
                              lanes.shape[-1], interpret)
     return fn(dl, dv)
+
+
+def warm_densify(mesh: Mesh, lead_shape: tuple, subs: int,
+                 interpret: bool = False) -> None:
+    """Run the densify program of every width once, on zeros, for one
+    slab shape ``lead_shape + (subs * 128,)``: after it a first sparse
+    fill of that shape compiles nothing, whatever its row's width."""
+    for g_slots in DENSIFY_WIDTHS:
+        zeros = np.zeros(lead_shape + (subs, g_slots), dtype=np.uint32)
+        densify_sharded(mesh, zeros, zeros,
+                        interpret=interpret).block_until_ready()
 
 
 def pad_to_multiple(arr: np.ndarray, n: int) -> np.ndarray:

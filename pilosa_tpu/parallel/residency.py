@@ -100,11 +100,16 @@ class DeviceBlockCache:
         self.misses = 0
         self.evictions = 0
         # What the misses cost: builds run, requests that waited for
-        # another request's build of their key, and the builders' wall
-        # seconds (summed; builds of different keys overlap).
+        # another request's build of their key, the builders' wall
+        # seconds (summed; builds of different keys overlap), the bytes
+        # of the slabs built (at their uploaded, bucket-padded shape)
+        # and the builds whose transfer was the host-dense pack +
+        # device_put (the others densified on the device).
         self.fills = 0
         self.fill_waits = 0
         self.fill_seconds = 0.0
+        self.fill_bytes = 0
+        self.fills_dense = 0
 
     @staticmethod
     def _nbytes(arr) -> int:
@@ -137,6 +142,7 @@ class DeviceBlockCache:
             else:
                 mine = self._filling[key] = _Fill()
         if fill is not None:
+            accounting.note_fill_wait()
             with sched_context.stage("fill_wait"):
                 fill.done.wait()
             if fill.error is not None:
@@ -154,6 +160,7 @@ class DeviceBlockCache:
                 self.fills += 1
                 self.fill_seconds += time.perf_counter() - t0
                 if mine.error is None:
+                    self.fill_bytes += self._nbytes(mine.arr)
                     self._insert(key, mine.arr)
             mine.done.set()
         accounting.note_cold_leaf()
@@ -174,6 +181,11 @@ class DeviceBlockCache:
             _, old = self._lru.popitem(last=False)
             self.used_bytes -= self._nbytes(old)
             self.evictions += 1
+
+    def note_dense_fill(self) -> None:
+        """A builder's word that its fill ships a host-dense block."""
+        with self._mu:
+            self.fills_dense += 1
 
     def contains(self, key: tuple) -> bool:
         """Residency probe WITHOUT touching LRU order — the routing
@@ -231,7 +243,9 @@ class DeviceBlockCache:
                     "hits": self.hits, "misses": self.misses,
                     "evictions": self.evictions,
                     "fills": self.fills, "fillWaits": self.fill_waits,
-                    "fillSeconds": round(self.fill_seconds, 6)}
+                    "fillSeconds": round(self.fill_seconds, 6),
+                    "fillBytes": self.fill_bytes,
+                    "fillsDense": self.fills_dense}
 
 
 _device_cache: Optional[DeviceBlockCache] = None
@@ -265,6 +279,47 @@ def slab_is_kept(mesh, n_slices: int) -> bool:
             <= device_cache().budget_bytes)
 
 
+def _fill(mesh, lead_shape: tuple, extract: Callable[[], list],
+          **tags) -> jax.Array:
+    """One residency fill in its two stages. ``pack``: ``extract()``'s
+    sparse (word idx, value) pairs, one entry a slice-row of
+    ``lead_shape`` (None = absent = zero words), then the gate picks
+    the transfer — bucketed sparse lanes and values (far fewer bytes to
+    pack and ship at sparse shapes) or a host dense scatter. ``upload``:
+    the transfer, and the on-device densify of a sparse one. Always at
+    the bucket-padded, program-stable shape ``lead_shape + (words,)``.
+    In a kept trace both stages' spans carry ``tags`` (which rows),
+    ``slices`` (the padded slice count), ``bytes`` (what the host hands
+    the device) and ``path``: ``sparse`` or ``dense``."""
+    from . import mesh as mesh_mod
+    words = packed.WORDS_PER_SLICE
+    mode = mesh_mod.densify_mode()
+    tags["slices"] = lead_shape[0]
+    with sched_context.stage("pack", **tags) as pack:
+        pairs = extract()
+        sparse = None
+        if mode is not None:
+            use_sparse, plan = packed.sparse_gate(pairs, words)
+            if use_sparse:
+                lanes, vals = packed.bucket_prepared(
+                    pairs, words // 128, plan=plan)
+                shp = lead_shape + lanes.shape[1:]
+                sparse = lanes.reshape(shp), vals.reshape(shp)
+        if sparse is None:
+            block = packed.densify_host(pairs, words).reshape(
+                lead_shape + (words,))
+            device_cache().note_dense_fill()
+            tags.update(bytes=block.nbytes, path="dense")
+        else:
+            tags.update(bytes=sum(a.nbytes for a in sparse), path="sparse")
+        pack.tag(**tags)
+    with sched_context.stage("upload", **tags):
+        if sparse is not None:
+            return mesh_mod.densify_sharded(
+                mesh, *sparse, interpret=(mode == "interpret"))
+        return mesh_mod.shard_slices(mesh, block)
+
+
 def leaf_slab(mesh, key: tuple, frags, row_id: int) -> jax.Array:
     """Device-resident ``[bucket(n_slices), words]`` slab of one PQL
     leaf row across ``frags`` (one fragment per slice, None = absent =
@@ -274,38 +329,20 @@ def leaf_slab(mesh, key: tuple, frags, row_id: int) -> jax.Array:
 
     The caller owns the key contract (executor embeds the backing
     view's (uid, generation), read BEFORE the fragments are resolved,
-    so writes/reopens age entries out of the LRU); this builder owns
-    the transfer: sparse-gate → bucketed sparse upload + on-device
-    densify when it wins, dense host pack otherwise — always at the
-    bucket-padded, program-stable shape."""
-    from . import mesh as mesh_mod
+    so writes/reopens age entries out of the LRU); ``_fill`` owns the
+    transfer."""
 
     def build(frags=frags):
-        # A residency miss: ``pack`` is roaring → words on the host,
-        # ``upload`` the transfer (and the on-device densify).
-        from ..ops import packed
         if callable(frags):
             frags = frags()
         n = _bucketed_slices(mesh, len(frags))
-        mode = mesh_mod.densify_mode()
-        with sched_context.stage("pack"):
+
+        def extract():
             pairs = [frag.sparse_row_pairs(row_id)
                      if frag is not None else None for frag in frags]
-            pairs += [None] * (n - len(pairs))
-            sparse = None
-            if mode is not None:
-                use_sparse, plan = packed.sparse_gate(
-                    pairs, packed.WORDS_PER_SLICE)
-                if use_sparse:
-                    sparse = packed.bucket_prepared(
-                        pairs, packed.WORDS_PER_SLICE // 128, plan=plan)
-            if sparse is None:
-                block = packed.densify_host(pairs, packed.WORDS_PER_SLICE)
-        with sched_context.stage("upload"):
-            if sparse is not None:
-                return mesh_mod.densify_sharded(
-                    mesh, *sparse, interpret=(mode == "interpret"))
-            return mesh_mod.shard_slices(mesh, block)
+            return pairs + [None] * (n - len(pairs))
+
+        return _fill(mesh, (n,), extract, row=row_id)
 
     return device_cache().get_or_build(key, build)
 
@@ -316,43 +353,22 @@ def candidate_block(mesh, key: tuple, frags,
     candidate block (same key/staleness contract and lazy ``frags`` as
     ``leaf_slab``), bucket-padded and slice-sharded — repeat TopN
     queries skip the per-query pack + upload entirely."""
-    from . import mesh as mesh_mod
 
     def build(frags=frags):
-        from ..ops import packed
         if callable(frags):
             frags = frags()
         n = _bucketed_slices(mesh, len(frags))
-        # Extract once as sparse (word idx, value) pairs; the gate
-        # then picks the transfer representation — bucketed sparse +
-        # device densify (far fewer bytes to pack and ship at sparse
-        # shapes) or host dense scatter.
-        mode = mesh_mod.densify_mode()
-        with sched_context.stage("pack"):
+
+        def extract():
             pairs: list = []
             for si in range(n):
                 frag = frags[si] if si < len(frags) else None
                 for rid in row_ids:
                     pairs.append(None if frag is None
                                  else frag.sparse_row_pairs(rid))
-            sparse = None
-            if mode is not None:
-                use_sparse, plan = packed.sparse_gate(
-                    pairs, packed.WORDS_PER_SLICE)
-                if use_sparse:
-                    lanes, vals = packed.bucket_prepared(
-                        pairs, packed.WORDS_PER_SLICE // 128, plan=plan)
-                    shp = (n, len(row_ids)) + lanes.shape[1:]
-                    sparse = lanes.reshape(shp), vals.reshape(shp)
-            if sparse is None:
-                rows = packed.densify_host(
-                    pairs, packed.WORDS_PER_SLICE).reshape(
-                        n, len(row_ids), packed.WORDS_PER_SLICE)
-        with sched_context.stage("upload"):
-            if sparse is not None:
-                return mesh_mod.densify_sharded(
-                    mesh, *sparse, interpret=(mode == "interpret"))
-            return mesh_mod.shard_slices(mesh, rows)
+            return pairs
+
+        return _fill(mesh, (n, len(row_ids)), extract, rows=len(row_ids))
 
     return device_cache().get_or_build(key, build)
 

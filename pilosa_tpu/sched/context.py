@@ -247,6 +247,13 @@ class _StageCM:
         self._clock.push(self._name, self._tags)
         return self
 
+    def tag(self, **tags) -> None:
+        """Tags learned while the stage is open, for a stage entered
+        with some (the clock holds that dict; one entered with none has
+        no span tags to add to)."""
+        if self._tags is not None:
+            self._tags.update(tags)
+
     def __exit__(self, exc_type, exc, tb):
         self._clock.pop()
         return False

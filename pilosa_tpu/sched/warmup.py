@@ -22,6 +22,17 @@ candidate-row count, a new expression structure) can still compile
 later — the warmup removes the dominant cold cost, not every possible
 trace.
 
+The residency fill's on-device densify is warmed with them: one
+program a bucket width (parallel.mesh.DENSIFY_WIDTHS) for the holder's
+slab shape. Which width a fill needs depends on the row's data, so
+unlike a query program it is not met by the first query of its shape
+but at random, hours in (one cold read in seven windows paid 263 ms for
+one; PERF.md, PR 32). A slab shape no pass warmed — an index loaded or
+grown after start, a TopN candidate block — is heard of from the first
+fill that densifies one (``parallel.mesh.on_densify``) and its other
+widths are compiled here, on this thread, which therefore lives as
+long as the server.
+
 State is exposed at ``/status`` (``pending → running → done``;
 ``disabled`` when the mesh is off or unavailable, ``failed`` carries
 the error) including per-program coverage: which catalogue programs
@@ -32,6 +43,7 @@ on; tests disable it the way they disable the cost model).
 from __future__ import annotations
 
 import os
+import queue
 import threading
 import time
 from typing import Optional
@@ -55,7 +67,13 @@ class Warmup:
         self.compiled: list[str] = []
         self.bucket: Optional[int] = None
         self.elapsed_s: Optional[float] = None
+        # Slab shapes (their leading axes) whose densify programs are
+        # compiled at every width, and the shapes heard of and waiting.
+        self.densified: list[list] = []
+        self._densify_heard: set = set()
+        self._densify_todo: queue.SimpleQueue = queue.SimpleQueue()
         self._stop = threading.Event()
+        self._done = threading.Event()
         self._thread: Optional[threading.Thread] = None
 
     def start(self) -> None:
@@ -66,10 +84,12 @@ class Warmup:
 
     def stop(self) -> None:
         self._stop.set()
+        self._densify_todo.put(None)
 
     def wait(self, timeout: Optional[float] = None) -> None:
+        """Until the start-up pass has ended, whatever its state."""
         if self._thread is not None:
-            self._thread.join(timeout)
+            self._done.wait(timeout)
 
     def to_json(self) -> dict:
         from ..parallel import programs
@@ -77,6 +97,7 @@ class Warmup:
         return {"state": self.state, "compiled": list(self.compiled),
                 "error": self.error or None,
                 "bucket": self.bucket,
+                "densified": list(self.densified),
                 "coverage": {
                     "warmed": len(self.compiled),
                     "programs": len(catalogue),
@@ -103,13 +124,56 @@ class Warmup:
     # -- worker --------------------------------------------------------------
 
     def _run(self) -> None:
+        try:
+            followed = self._pass()
+        finally:
+            self._done.set()
+        if followed:
+            self._follow()
+
+    def _hear_densify(self, shape: tuple) -> None:
+        """``parallel.mesh.on_densify``: a fill's thread names the slab
+        shape it densifies; a new one is queued for this lane."""
+        if shape not in self._densify_heard:
+            self._densify_heard.add(shape)
+            self._densify_todo.put(shape)
+
+    def _warm_densify(self, shape: tuple) -> None:
+        from ..parallel import mesh as mesh_mod
+        self._densify_heard.add(shape)
+        with sched_context.background_tick("warmup"):
+            mesh_mod.warm_densify(*shape)
+        self.densified.append(list(shape[1]))
+
+    def _follow(self) -> None:
+        """After the pass: compile every width of each slab shape a
+        fill densifies that no pass has warmed, until ``stop``."""
+        from ..parallel import mesh as mesh_mod
+        try:
+            while True:
+                shape = self._densify_todo.get()
+                if shape is None or self._stop.is_set():
+                    return
+                try:
+                    self._warm_densify(shape)
+                except Exception as e:  # noqa: BLE001 - never kill serving
+                    self.logger.printf(
+                        "warmup: densify programs of %s failed: %s: %s",
+                        shape[1], type(e).__name__, e)
+        finally:
+            if mesh_mod.on_densify == self._hear_densify:
+                mesh_mod.on_densify = None
+
+    def _pass(self) -> bool:
+        """The start-up pass; True where a mesh exists and the lane
+        goes on to follow the fills."""
         t0 = time.monotonic()
         self.state = "running"
         try:
             mesh = self.executor._mesh_or_none()
             if mesh is None:
                 self.state = "disabled"
-                return
+                return False
             # Routing constants are measured here, on the device the
             # programs below compile for, so the planner's placement
             # and the executor's veto price the first query from
@@ -121,8 +185,8 @@ class Warmup:
             from ..parallel import mesh as mesh_mod
             from ..parallel import programs
             n_dev = mesh.shape[mesh_mod.AXIS_SLICES]
-            self.bucket = programs.slice_bucket(
-                self._holder_max_slices(), n_dev)
+            served = self._holder_max_slices()
+            self.bucket = programs.slice_bucket(served, n_dev)
             S = self.bucket
 
             def slab():
@@ -170,13 +234,23 @@ class Warmup:
                 with sched_context.background_tick("warmup"):
                     step()
                 self.compiled.append(name)
+            mode = mesh_mod.densify_mode()
+            if mode is not None:
+                mesh_mod.on_densify = self._hear_densify
+                # An empty holder has no slab shape yet: its first
+                # fill will name it.
+                if served and not self._stop.is_set():
+                    self._warm_densify((mesh, (S,), WORDS_PER_SLICE // 128,
+                                        mode == "interpret"))
             self.state = "done"
             self.elapsed_s = time.monotonic() - t0
             self.logger.printf(
                 "warmup: compiled %s at bucket %d in %.2fs",
                 ",".join(self.compiled), S, self.elapsed_s)
+            return mode is not None
         except Exception as e:  # noqa: BLE001 - warmup must never kill serving
             self.state = "failed"
             self.error = f"{type(e).__name__}: {e}"
             self.elapsed_s = time.monotonic() - t0
             self.logger.printf("warmup failed: %s", self.error)
+            return False
