@@ -136,145 +136,114 @@ def unpack_to_bitmap(words: np.ndarray, base_word: int = 0) -> Bitmap:
     return b
 
 
-def sparse_words(b: Bitmap, n_words: int, base_word: int = 0
-                 ) -> tuple[np.ndarray, np.ndarray]:
-    """Sparse word form of a roaring bitmap: (sorted unique i32 word
-    indices, u32 word values) — the upload payload of the device
-    densify kernel (ops.pallas_kernels.densify_pallas). Bounded by SET
-    words (= on-disk density), not row width: bitmap containers list
-    their nonzero u32 words directly, array containers group positions
-    by word with one reduceat. Positions relative to ``base_word*32``;
-    words outside [0, n_words) are dropped."""
-    idx_parts: list[np.ndarray] = []
-    val_parts: list[np.ndarray] = []
-    for key, c in zip(b.keys, b.containers):
-        if c.n == 0:
-            continue
-        word0 = key * _WORDS_PER_CONTAINER - base_word
-        if word0 >= n_words or word0 + _WORDS_PER_CONTAINER <= 0:
-            continue
-        if not c.is_array():
-            view = (c.bitmap if c.bitmap is not None
-                    else runs_to_words(c.runs)).view("<u4")
-            nz = np.flatnonzero(view)
-            widx = word0 + nz.astype(np.int64)
-            keep = (widx >= 0) & (widx < n_words)
-            idx_parts.append(widx[keep].astype(np.int32))
-            val_parts.append(view[nz[keep]])
-        else:
-            a = c.array
-            widx = word0 + (a >> np.uint32(5)).astype(np.int64)
-            keep = (widx >= 0) & (widx < n_words)
-            widx, a = widx[keep], a[keep]
-            if not len(widx):
-                continue
-            bits = np.uint32(1) << (a & np.uint32(31))
-            # positions are sorted, so equal word indices are adjacent:
-            # one reduceat ORs each word's bits together.
-            starts = np.concatenate(
-                ([0], np.flatnonzero(np.diff(widx)) + 1))
-            idx_parts.append(widx[starts].astype(np.int32))
-            val_parts.append(np.bitwise_or.reduceat(bits, starts))
-    if not idx_parts:
-        return (np.empty(0, np.int32), np.empty(0, np.uint32))
-    return np.concatenate(idx_parts), np.concatenate(val_parts)
-
-
-def sparse_row_words(storage: Bitmap, row_id: int
-                     ) -> tuple[np.ndarray, np.ndarray]:
-    """sparse_words for one fragment row (pos = row*SLICE_WIDTH + col)."""
-    row_bm = storage.offset_range(0, row_id * SLICE_WIDTH,
-                                  (row_id + 1) * SLICE_WIDTH)
-    return sparse_words(row_bm, WORDS_PER_SLICE)
-
-
 # 128 words per bucket group - must match pallas_kernels._DENSIFY_LANES.
 _DENSIFY_LANES = 128
+# The widest bucket that ships sparse: at 32 slots a group the bucketed
+# payload (8 B a slot) is half the dense block's (4 B a word), the margin
+# the transfer must win by, and the kernel's VMEM envelope ends there;
+# sparse loses outright by 128 (every slot of every group is shipped and
+# OR-ed). Must match parallel.mesh.DENSIFY_WIDTHS.
+_MAX_SLOTS = 32
 
 
-def bucket_rows(storage: Bitmap, row_ids,
-                n_words: int = WORDS_PER_SLICE
-                ) -> tuple[np.ndarray, np.ndarray]:
-    """Bucketed sparse form of a row block for the device densify
-    kernel (ops.pallas_kernels.densify_pallas): ``([T, n_words/128, G]
-    u32 lanes, same-shape u32 values)``, where slot g of 128-word group
-    s of row t is one set word (its lane 0-127 and value); ``val == 0``
-    slots are padding. G is the max set-word count in any row's group,
-    rounded up to a power of two (shape-bucketing keeps the kernel's
-    compile cache small). Transfer size is ``T * n_words/16 * G`` bytes
-    vs ``4 * T * n_words`` dense — the win whenever G stays small,
-    which is exactly the sparse/clustered case the cost model routes
-    here."""
-    subs = n_words // _DENSIFY_LANES
-    rows = [sparse_row_words(storage, r) for r in row_ids]
-    return bucket_prepared(rows, subs)
+def _array_words(arrays: list, slots: list, index_t) -> tuple:
+    """Set words of array containers, each at container slot
+    ``slots[i]`` of the slab: (ascending slab word indices, u32 values).
+    Values are sorted in a container and containers come in slab order,
+    so equal word indices are adjacent: one reduceat ORs each word's
+    bits together."""
+    low = np.concatenate(arrays)
+    at = np.repeat(np.asarray(slots, dtype=index_t) * _WORDS_PER_CONTAINER,
+                   [len(a) for a in arrays])
+    at += low >> np.uint32(5)
+    new_word = np.ones(len(at), dtype=bool)
+    np.not_equal(at[1:], at[:-1], out=new_word[1:])
+    first = np.flatnonzero(new_word)
+    low &= np.uint32(31)
+    return at[first], np.bitwise_or.reduceat(np.uint32(1) << low, first)
 
 
-def _bucket_plan(rows: list, subs: int) -> tuple[int, list]:
-    """One bincount pass over pre-extracted pairs: (g_pad, metas) —
-    shared by sparse_gate (the decision) and bucket_prepared (the
-    fill), so the cold path pays the grouping exactly once."""
-    g_max = 1
-    metas = []
-    for pair in rows:
-        if pair is None or not len(pair[0]):
-            metas.append(None)
+def pack_slab(rows: list, sparse: bool = True
+              ) -> tuple[tuple | None, np.ndarray | None, int]:
+    """One pass over every container of a slab: the host side of a
+    residency fill (parallel.residency._fill).
+
+    ``rows`` has one entry a slice-row: None (absent = zero words) or
+    the row's containers as a Bitmap keyed 0..15 (what
+    ``Fragment.row_containers`` and ``unpack_to_bitmap`` give). Returns
+    ``(sparse, block, containers)``, one of the first two None:
+
+    - ``sparse``: ``([T, 256, G] u32 lanes, same-shape u32 values)``,
+      the upload payload of the device densify kernel
+      (ops.pallas_kernels.densify_pallas). Slot g of 128-word group s of
+      slice-row t is one set word (its lane 0-127 and its value) in
+      ascending word order; ``val == 0`` slots are padding. G is the
+      largest count of set words in any group of any slice-row, rounded
+      up to a power of two (shape-bucketing keeps the kernel's compile
+      cache small). Taken when ``sparse`` allows it and G is at most
+      ``_MAX_SLOTS``.
+    - ``block``: dense ``[T, 32768]`` u32 otherwise.
+
+    The numpy calls do not grow with the slab: array containers are
+    concatenated and grouped by word once, bitmap and run containers
+    are stacked once as the u32 words they are (a dense block takes
+    them by one assignment, never through pairs), one bincount finds
+    the ranks, one scatter fills the buckets."""
+    per = _WORDS_PER_CONTAINER
+    arrays, array_slots, dense, dense_slots = [], [], [], []
+    for t, row in enumerate(rows):
+        if row is None:
             continue
-        idx, val = pair
-        groups = (idx >> 7).astype(np.int64)
-        counts = np.bincount(groups, minlength=subs)
-        g_max = max(g_max, int(counts.max()))
-        starts = np.zeros(subs + 1, dtype=np.int64)
-        np.cumsum(counts, out=starts[1:])
-        rank = np.arange(len(idx), dtype=np.int64) - starts[groups]
-        metas.append((groups, rank, idx, val))
-    return 1 << (g_max - 1).bit_length(), metas
-
-
-def bucket_prepared(rows: list, subs: int, plan=None
-                    ) -> tuple[np.ndarray, np.ndarray]:
-    """bucket_rows over pre-extracted ``(idx, val)`` pairs (None for
-    absent rows) — the shared form for multi-fragment blocks, where
-    extraction happens once and feeds either the sparse upload or the
-    host dense scatter (ops.packed.densify_host). ``plan`` is the
-    (g_pad, metas) a prior sparse_gate computed."""
-    g_pad, metas = plan if plan is not None else _bucket_plan(rows, subs)
-    lanes = np.zeros((len(rows), subs, g_pad), dtype=np.uint32)
-    vals = np.zeros((len(rows), subs, g_pad), dtype=np.uint32)
-    for t, meta in enumerate(metas):
-        if meta is None:
-            continue
-        groups, rank, idx, val = meta
-        lanes[t, groups, rank] = (idx & 127).astype(np.uint32)
-        vals[t, groups, rank] = val
-    return lanes, vals
-
-
-def densify_host(rows: list, n_words: int) -> np.ndarray:
-    """Pre-extracted ``(idx, val)`` pairs → dense ``[T, n_words]`` u32
-    host-side (the dense-upload leg when the sparse gate says no —
-    reuses the extraction instead of re-walking containers)."""
-    out = np.zeros((len(rows), n_words), dtype=np.uint32)
-    for t, pair in enumerate(rows):
-        if pair is None or not len(pair[0]):
-            continue
-        out[t, pair[0]] = pair[1]
-    return out
-
-
-def sparse_gate(rows: list, n_words: int,
-                margin: float = 2.0) -> tuple[bool, tuple]:
-    """Should a block of pre-extracted rows ship sparse? Returns
-    (use_sparse, plan) — pass ``plan`` to bucket_prepared to reuse the
-    grouping pass. Sparse pays when the bucketed payload —
-    ``T * n_words/16 * G`` bytes — is under ``dense/margin`` and G is
-    within the kernel's VMEM envelope; sparse wins at small G and
-    loses outright by G=128 (every slot of every group is shipped and
-    OR-ed), so the gate is deliberately conservative."""
-    subs = n_words // _DENSIFY_LANES
-    plan = _bucket_plan(rows, subs)
-    g_pad = plan[0]
-    sparse_bytes = len(rows) * subs * g_pad * 8
-    dense_bytes = len(rows) * n_words * 4
-    return (g_pad <= 32
-            and sparse_bytes * margin <= dense_bytes), plan
+        slot0 = t * (WORDS_PER_SLICE // per)
+        for key, c in zip(row.keys, row.containers):
+            if not c.n:
+                continue
+            if c.is_array():
+                arrays.append(c.array)
+                array_slots.append(slot0 + key)
+            else:
+                dense.append((c.bitmap if c.bitmap is not None
+                              else runs_to_words(c.runs)).view("<u4"))
+                dense_slots.append(slot0 + key)
+    taken = len(arrays) + len(dense)
+    # A slab word index fits 32 bits up to 2^17 slice-rows: half the
+    # bytes through every step of a 256-slice fill's ten million values.
+    index_t = np.uint32 if len(rows) <= 1 << 17 else np.int64
+    if arrays:
+        word, val = _array_words(arrays, array_slots, index_t)
+    else:
+        word, val = np.empty(0, index_t), np.empty(0, np.uint32)
+    if dense:
+        stack = np.concatenate(dense).reshape(-1, per)
+    if sparse:      # the gate: the fullest group's set words
+        fullest = max(
+            1, int(np.bincount(word >> 7).max()) if len(word) else 0,
+            int(np.count_nonzero(stack.reshape(-1, _DENSIFY_LANES),
+                                 axis=1).max()) if dense else 0)
+        g_pad = 1 << (fullest - 1).bit_length()
+        sparse = g_pad <= _MAX_SLOTS
+    if not sparse:
+        block = np.zeros((len(rows), WORDS_PER_SLICE), dtype=np.uint32)
+        block.reshape(-1)[word] = val
+        if dense:
+            block.reshape(-1, per)[dense_slots] = stack
+        return None, block, taken
+    if dense:
+        # A slab holding bitmap or run containers that still passes the
+        # gate (a short run does): their set words join the arrays'.
+        d, w = np.nonzero(stack)
+        word = np.concatenate(
+            (word, (np.asarray(dense_slots)[d] * per + w).astype(index_t)))
+        val = np.concatenate((val, stack[d, w]))
+        order = np.argsort(word, kind="stable")
+        word, val = word[order], val[order]
+    group = word >> 7
+    counts = np.bincount(group, minlength=len(rows) * WORDS_PER_SLICE
+                         // _DENSIFY_LANES)
+    rank = np.arange(len(word)) - (np.cumsum(counts) - counts)[group]
+    lanes = np.zeros((len(counts), g_pad), dtype=np.uint32)
+    vals = np.zeros((len(counts), g_pad), dtype=np.uint32)
+    lanes[group, rank] = word & 127
+    vals[group, rank] = val
+    shape = (len(rows), -1, g_pad)
+    return (lanes.reshape(shape), vals.reshape(shape)), None, taken

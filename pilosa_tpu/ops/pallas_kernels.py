@@ -5,7 +5,7 @@ Counts, TopN and every other query program are XLA fusions built by
 roofline (PERF.md), so no hand kernel is kept for them. Densify is
 different: it turns a sparse upload into a dense slab on the device,
 which in XLA is a scatter - the TPU's weak spot. It is selected from
-the input (``ops.packed.sparse_gate``) and from the platform
+the input (the gate of ``ops.packed.pack_slab``) and from the platform
 (``parallel.mesh.densify_mode``), and off a TPU it only runs with
 ``interpret=True``.
 """
@@ -29,7 +29,7 @@ from jax.experimental import pallas as pl
 # scatter lowering made the sparse path a loss, and Mosaic forbids
 # scalar/dynamic-lane VMEM access, so the layout is arranged host-side
 # to make the kernel a pure vector computation
-# (ops.packed.bucket_rows). This is the device
+# (ops.packed.pack_slab). This is the device
 # analogue of the reference materializing a row in O(containers), not
 # O(row width) (roaring.go:253-285).
 
@@ -59,7 +59,7 @@ def densify_pallas(lane: jax.Array, val: jax.Array, n_words: int,
     ``lane``/``val`` are ``[T, n_words/128, G]``: slot g of group s of
     row t holds a word value and its lane (0-127) within the group;
     ``val == 0`` slots are padding (OR no-ops, any lane). Returns
-    ``[T, n_words]``. Produced by ops.packed.bucket_rows."""
+    ``[T, n_words]``. Produced by ops.packed.pack_slab."""
     t_rows, subs, g_slots = lane.shape
     if subs * _DENSIFY_LANES != n_words:
         raise ValueError("lane/val buckets do not match n_words")

@@ -481,8 +481,9 @@ def densify_mode() -> str | None:
     return "compiled" if jax.devices()[0].platform == "tpu" else None
 
 
-# The bucket widths ``ops.packed.sparse_gate`` lets through (a power of
-# two, at most 32): one densify program each, for every slab shape.
+# The bucket widths the gate of ``ops.packed.pack_slab`` lets through (a
+# power of two, at most 32): one densify program each, for every slab
+# shape.
 DENSIFY_WIDTHS = (1, 2, 4, 8, 16, 32)
 
 # Told ``(mesh, lead_shape, subs, interpret)`` by every sparse upload,
@@ -521,7 +522,7 @@ def _densify_sharded_fn(mesh: Mesh, lead_shape: tuple, subs: int,
 @_fair_dispatch
 def densify_sharded(mesh: Mesh, lanes: np.ndarray, vals: np.ndarray,
                     interpret: bool = False) -> jax.Array:
-    """Upload bucketed sparse rows (ops.packed.bucket_prepared) and
+    """Upload bucketed sparse rows (ops.packed.pack_slab) and
     densify per shard: ``[S, (R,) subs, G]`` → slice-sharded
     ``[S, (R,) subs*128]`` dense words. The cold-path replacement for
     packing dense host-side and shipping 4 bytes per word, set or

@@ -34,8 +34,8 @@ catalogue compiles for — growing an index within a bucket re-uses both
 the compiled programs AND the upload path's shapes.
 
 Host container kinds are invisible past this layer: the extraction
-feeding both the sparse and dense upload legs (ops.packed
-sparse_row_words / pack_bitmap) decodes array, bitmap, AND run
+feeding both the sparse and dense upload legs (ops.packed pack_slab;
+pack_bitmap for a streamed block) decodes array, bitmap, AND run
 containers to the same word form, so run-compressed fragments (the
 memory win that lets more of the matrix fit in HBM) ride the existing
 bucket-padded path with no residency-side special case.
@@ -279,40 +279,34 @@ def slab_is_kept(mesh, n_slices: int) -> bool:
             <= device_cache().budget_bytes)
 
 
-def _fill(mesh, lead_shape: tuple, extract: Callable[[], list],
+def _fill(mesh, lead_shape: tuple, collect: Callable[[], list],
           **tags) -> jax.Array:
-    """One residency fill in its two stages. ``pack``: ``extract()``'s
-    sparse (word idx, value) pairs, one entry a slice-row of
-    ``lead_shape`` (None = absent = zero words), then the gate picks
-    the transfer — bucketed sparse lanes and values (far fewer bytes to
-    pack and ship at sparse shapes) or a host dense scatter. ``upload``:
-    the transfer, and the on-device densify of a sparse one. Always at
-    the bucket-padded, program-stable shape ``lead_shape + (words,)``.
-    In a kept trace both stages' spans carry ``tags`` (which rows),
-    ``slices`` (the padded slice count), ``bytes`` (what the host hands
-    the device) and ``path``: ``sparse`` or ``dense``."""
+    """One residency fill in its two stages. ``pack``: ``collect()``'s
+    containers, one entry a slice-row of ``lead_shape`` (None = absent
+    = zero words), packed in one pass over the slab
+    (ops.packed.pack_slab), whose gate picks the transfer — bucketed
+    sparse lanes and values (far fewer bytes to pack and ship at sparse
+    shapes) or a host dense block. ``upload``: the transfer, and the
+    on-device densify of a sparse one. Always at the bucket-padded,
+    program-stable shape ``lead_shape + (words,)``. In a kept trace
+    both stages' spans carry ``tags`` (which rows), ``slices`` (the
+    padded slice count), ``bytes`` (what the host hands the device) and
+    ``path``: ``sparse`` or ``dense``; ``pack``'s also ``containers``,
+    how many the pass took."""
     from . import mesh as mesh_mod
-    words = packed.WORDS_PER_SLICE
     mode = mesh_mod.densify_mode()
     tags["slices"] = lead_shape[0]
     with sched_context.stage("pack", **tags) as pack:
-        pairs = extract()
-        sparse = None
-        if mode is not None:
-            use_sparse, plan = packed.sparse_gate(pairs, words)
-            if use_sparse:
-                lanes, vals = packed.bucket_prepared(
-                    pairs, words // 128, plan=plan)
-                shp = lead_shape + lanes.shape[1:]
-                sparse = lanes.reshape(shp), vals.reshape(shp)
+        sparse, block, taken = packed.pack_slab(
+            collect(), sparse=mode is not None)
         if sparse is None:
-            block = packed.densify_host(pairs, words).reshape(
-                lead_shape + (words,))
+            block = block.reshape(lead_shape + block.shape[1:])
             device_cache().note_dense_fill()
             tags.update(bytes=block.nbytes, path="dense")
         else:
+            sparse = [a.reshape(lead_shape + a.shape[1:]) for a in sparse]
             tags.update(bytes=sum(a.nbytes for a in sparse), path="sparse")
-        pack.tag(**tags)
+        pack.tag(containers=taken, **tags)
     with sched_context.stage("upload", **tags):
         if sparse is not None:
             return mesh_mod.densify_sharded(
@@ -337,12 +331,12 @@ def leaf_slab(mesh, key: tuple, frags, row_id: int) -> jax.Array:
             frags = frags()
         n = _bucketed_slices(mesh, len(frags))
 
-        def extract():
-            pairs = [frag.sparse_row_pairs(row_id)
-                     if frag is not None else None for frag in frags]
-            return pairs + [None] * (n - len(pairs))
+        def collect():
+            rows = [frag.row_containers(row_id)
+                    if frag is not None else None for frag in frags]
+            return rows + [None] * (n - len(rows))
 
-        return _fill(mesh, (n,), extract, row=row_id)
+        return _fill(mesh, (n,), collect, row=row_id)
 
     return device_cache().get_or_build(key, build)
 
@@ -359,16 +353,16 @@ def candidate_block(mesh, key: tuple, frags,
             frags = frags()
         n = _bucketed_slices(mesh, len(frags))
 
-        def extract():
-            pairs: list = []
+        def collect():
+            rows: list = []
             for si in range(n):
                 frag = frags[si] if si < len(frags) else None
                 for rid in row_ids:
-                    pairs.append(None if frag is None
-                                 else frag.sparse_row_pairs(rid))
-            return pairs
+                    rows.append(None if frag is None
+                                else frag.row_containers(rid))
+            return rows
 
-        return _fill(mesh, (n, len(row_ids)), extract, rows=len(row_ids))
+        return _fill(mesh, (n, len(row_ids)), collect, rows=len(row_ids))
 
     return device_cache().get_or_build(key, build)
 

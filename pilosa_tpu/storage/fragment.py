@@ -1469,17 +1469,23 @@ class Fragment:
     _EMPTY_COUNTS = (np.empty(0, dtype=np.int64),
                      np.empty(0, dtype=np.int64))
 
-    def sparse_row_pairs(self, row_id: int):
-        """(word idx, word value) pairs for one row, under the
-        fragment lock — the extraction feeding sparse device uploads
-        (ops.packed); lockless storage walks race with concurrent
-        mutations (review finding, round 4)."""
-        from ..ops import packed
+    def row_containers(self, row_id: int) -> roaring.Bitmap:
+        """One row's containers, keyed 0..15: what a residency fill
+        packs (ops.packed.pack_slab). Only references are taken under
+        the fragment lock, the copy-on-write shared views ``row()``
+        hands out too: every mutation replaces an array or run buffer
+        and copies a bitmap's words before writing them once the
+        container is marked ``mapped`` (roaring ``_guard_inplace`` /
+        ``_unmap``, native/fastmutate.c bails on the flag), and a
+        swapped-out map lives as long as a view pins it — so the
+        packing itself runs outside the lock on what the row held at
+        this moment."""
         with self._mu:
             self._verify_on_read()
             if self.tier is not None:
                 self._tier_gate(row_id=row_id)
-            return packed.sparse_row_words(self.storage, row_id)
+            return self.storage.offset_range(0, row_id * SLICE_WIDTH,
+                                             (row_id + 1) * SLICE_WIDTH)
 
     def _cached_total_bits(self) -> int:
         """storage.count() walks every container in Python (~115 ms

@@ -1093,14 +1093,16 @@ class TestSparseUploadPath:
         loses outright by G=128)."""
         import numpy as np
         from pilosa_tpu.ops import packed
-        dense_row = (np.arange(0, 32768, dtype=np.int32),
-                     np.full(32768, 7, dtype=np.uint32))
-        sparse_row = (np.array([5, 300], dtype=np.int32),
-                      np.array([1, 2], dtype=np.uint32))
-        use, plan = packed.sparse_gate([dense_row, sparse_row], 32768)
-        assert not use and plan[0] > 32
-        use2, plan2 = packed.sparse_gate([sparse_row, None], 32768)
-        assert use2 and plan2[0] == 1
+        dense_row = packed.unpack_to_bitmap(
+            np.full(32768, 7, dtype=np.uint32))
+        words = np.zeros(32768, dtype=np.uint32)
+        words[[5, 300]] = 1, 2
+        sparse_row = packed.unpack_to_bitmap(words)
+        sparse, block, _ = packed.pack_slab([dense_row, sparse_row])
+        assert sparse is None
+        assert (block[0] == 7).all() and (block[1] == words).all()
+        sparse2, block2, _ = packed.pack_slab([sparse_row, None])
+        assert block2 is None and sparse2[0].shape[-1] == 1
 
 
 class TestVectorizedHostTopN:

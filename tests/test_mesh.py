@@ -107,12 +107,12 @@ class TestSlabOrigins:
         n = 8
         dense = np.zeros((n, packed.WORDS_PER_SLICE), dtype=np.uint32)
         dense[:, :4] = 7
-        pairs = [(np.array([1, 2, 3], dtype=np.int64),
-                  np.array([5, 6, 7], dtype=np.uint32))] * n
+        thin = np.zeros(packed.WORDS_PER_SLICE, dtype=np.uint32)
+        thin[1:4] = 5, 6, 7
+        sparse, _, _ = packed.pack_slab([packed.unpack_to_bitmap(thin)] * n)
         a = mesh_mod.shard_slices(mesh, dense)
         b = mesh_mod.densify_sharded(
-            mesh, *packed.bucket_prepared(
-                pairs, packed.WORDS_PER_SLICE // 128), interpret=True)
+            mesh, *sparse, interpret=True)
         assert a.sharding == b.sharding
         expr = ("and", ("leaf", 0), ("leaf", 1))
         want_ab = _popcount(np.asarray(a) & np.asarray(b))

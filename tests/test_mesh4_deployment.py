@@ -192,12 +192,12 @@ class TestSlabOrigins:
         n = 8
         dense = np.zeros((n, packed.WORDS_PER_SLICE), dtype=np.uint32)
         dense[:, :4] = 7
-        pairs = [(np.array([1, 2, 3], dtype=np.int64),
-                  np.array([5, 6, 7], dtype=np.uint32))] * n
+        thin = np.zeros(packed.WORDS_PER_SLICE, dtype=np.uint32)
+        thin[1:4] = 5, 6, 7
+        sparse, _, _ = packed.pack_slab([packed.unpack_to_bitmap(thin)] * n)
         put = mesh_mod.shard_slices(mesh, dense)
         made = mesh_mod.densify_sharded(
-            mesh, *packed.bucket_prepared(
-                pairs, packed.WORDS_PER_SLICE // 128), interpret=True)
+            mesh, *sparse, interpret=True)
         assert put.sharding == made.sharding
         assert len(made.addressable_shards) == n_dev
         host = {id(put): np.asarray(put), id(made): np.asarray(made)}

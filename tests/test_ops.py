@@ -133,37 +133,48 @@ class TestSparseWords:
             [rows * SLICE_WIDTH + cols, dense])))
         return st
 
-    def test_bucket_rows_matches_dense_pack(self):
-        import numpy as np
+    @staticmethod
+    def _rows(st, ids):
+        from pilosa_tpu import SLICE_WIDTH
+        return [st.offset_range(0, r * SLICE_WIDTH, (r + 1) * SLICE_WIDTH)
+                for r in ids]
+
+    def test_pack_slab_matches_dense_pack(self):
+        """Rows 1-5 hold ~20 set words a 128-word group and row 0
+        bitmap containers: over the gate, so the block comes back dense
+        and equals the streaming leg's pack of the same rows."""
         from pilosa_tpu.ops import packed
         st = self._storage()
         ids = [0, 1, 2, 3, 4, 5]
-        dense = packed.pack_rows(st, ids)
-        lanes, vals = packed.bucket_rows(st, ids)
-        assert lanes.shape == vals.shape
-        assert lanes.shape[1] == packed.WORDS_PER_SLICE // 128
-        got = np.zeros_like(dense)
-        for t in range(len(ids)):
-            for s_grp in range(lanes.shape[1]):
-                nz = vals[t, s_grp] != 0
-                got[t, s_grp * 128 + lanes[t, s_grp][nz]] = \
-                    vals[t, s_grp][nz]
-        assert (got == dense).all()
+        sparse, block, taken = packed.pack_slab(self._rows(st, ids))
+        assert sparse is None and taken > len(ids)
+        assert (block == packed.pack_rows(st, ids)).all()
 
-    def test_bucket_then_densify_kernel(self):
+    def test_pack_slab_then_densify_kernel(self):
+        """Every 16th column of rows 1 and 5 (about two set words a
+        group) ships sparse, and the kernel rebuilds the dense pack."""
         import numpy as np
+        from pilosa_tpu import SLICE_WIDTH
         from pilosa_tpu.ops import packed
         from pilosa_tpu.ops.pallas_kernels import densify_pallas
-        st = self._storage()
-        ids = [0, 1, 5]
+        from pilosa_tpu.storage.roaring import Bitmap
+        pos = self._storage().values()
+        st = Bitmap()
+        st.add_many(pos[(pos % SLICE_WIDTH) % 16 == 0])
+        ids = [1, 5]
         dense = packed.pack_rows(st, ids)
-        lanes, vals = packed.bucket_rows(st, ids)
+        (lanes, vals), block, _ = packed.pack_slab(self._rows(st, ids))
+        assert block is None and lanes.shape == vals.shape
+        assert lanes.shape[:2] == (2, packed.WORDS_PER_SLICE // 128)
+        assert 1 < lanes.shape[2] <= 32 and dense.any()
         got = np.asarray(densify_pallas(
             lanes, vals, packed.WORDS_PER_SLICE, True))
         assert (got == dense).all()
 
-    def test_sparse_words_empty(self):
+    def test_pack_slab_empty(self):
         from pilosa_tpu.ops import packed
         from pilosa_tpu.storage.roaring import Bitmap
-        idx, val = packed.sparse_words(Bitmap(), 32768)
-        assert len(idx) == 0 and len(val) == 0
+        (lanes, vals), block, taken = packed.pack_slab([Bitmap()])
+        assert block is None and taken == 0
+        assert lanes.shape == (1, packed.WORDS_PER_SLICE // 128, 1)
+        assert not lanes.any() and not vals.any()

@@ -468,9 +468,10 @@ class TestFragmentEndToEnd:
         frag2.storage.check()
 
     def test_run_backed_rows_decode_to_same_device_words(self, holder):
-        """pack_row / sparse_row_words over run containers equal the
+        """pack_row / pack_slab over run containers equal the
         legacy-kind decode — the residency upload sees identical
         bit-plane slabs."""
+        from pilosa_tpu import SLICE_WIDTH
         from pilosa_tpu.ops import packed
         self._run_heavy_frame(holder)
         frag = holder.fragment("r", "f", "standard", 0)
@@ -485,9 +486,18 @@ class TestFragmentEndToEnd:
             out_legacy = np.zeros(packed.WORDS_PER_SLICE, np.uint32)
             packed.pack_storage_row(legacy, row, out_legacy)
             assert np.array_equal(out_run, out_legacy)
-            ir, vr = packed.sparse_row_words(frag.storage, row)
-            il, vl = packed.sparse_row_words(legacy, row)
-            assert np.array_equal(ir, il) and np.array_equal(vr, vl)
+            for sparse in (True, False):
+                (sr, br, nr), (sl, bl, nl) = (
+                    packed.pack_slab([st.offset_range(
+                        0, row * SLICE_WIDTH, (row + 1) * SLICE_WIDTH)],
+                        sparse=sparse) for st in (frag.storage, legacy))
+                assert nr == nl > 0
+                if br is not None:
+                    assert np.array_equal(br, bl)
+                    assert np.array_equal(br[0], out_run)
+                else:
+                    assert np.array_equal(sr[0], sl[0])
+                    assert np.array_equal(sr[1], sl[1])
 
     def test_resident_bytes_shrink_vs_legacy(self, holder):
         self._run_heavy_frame(holder)

@@ -185,6 +185,9 @@ def test_one_header_tells_resident_filling_and_waiting_reads_apart(served):
                 assert args["bytes"] == (nbytes or args["bytes"]) > 0
             assert fills[row]["pack"]["bytes"] == \
                 fills[row]["upload"]["bytes"]
+            # how many containers the one pass took: the pack's alone
+            assert 4 <= fills[row]["pack"]["containers"] <= 4 * 16
+            assert "containers" not in fills[row]["upload"]
         assert fills[40]["pack"]["bytes"] < 4 * SLAB_ROW // 2
         span = next(e for e in events if e["name"] == "map_reduce")
         assert span["args"]["cold_leaves"] == 2
@@ -495,24 +498,26 @@ def test_the_cell_reports_the_unlisted_metrics_and_its_own_five():
 
 # -- the densify programs are warm before the first fill ----------------------
 
-def _pairs(n: int, width: int) -> list:
+def _rows(n: int, width: int) -> list:
     """``n`` slice-rows whose fullest 128-word group holds ``width``
     set words: the gate buckets them at exactly that width."""
-    idx = np.arange(width, dtype=np.int64)
-    return [(idx, np.full(width, 1, dtype=np.uint32))] * n
+    words = np.zeros(packed.WORDS_PER_SLICE, dtype=np.uint32)
+    words[:width] = 1
+    return [packed.unpack_to_bitmap(words)] * n
+
+
+def _sparse(n: int, width: int) -> tuple:
+    return packed.pack_slab(_rows(n, width))[0]
 
 
 def test_the_gate_passes_no_width_the_warm_up_does_not_compile():
     for width in mesh_mod.DENSIFY_WIDTHS:
-        use, plan = packed.sparse_gate(_pairs(4, width),
-                                       packed.WORDS_PER_SLICE)
-        assert use and plan[0] == width
+        assert _sparse(4, width)[0].shape == (
+            4, packed.WORDS_PER_SLICE // 128, width)
     for width in (3, 5, 17):        # padded up to the next power of two
-        _, plan = packed.sparse_gate(_pairs(4, width),
-                                     packed.WORDS_PER_SLICE)
-        assert plan[0] in mesh_mod.DENSIFY_WIDTHS
-    assert not packed.sparse_gate(_pairs(4, 33),
-                                  packed.WORDS_PER_SLICE)[0]
+        assert _sparse(4, width)[0].shape[-1] in mesh_mod.DENSIFY_WIDTHS
+    sparse, block, _ = packed.pack_slab(_rows(4, 33))
+    assert sparse is None and int(block.sum()) == 4 * 33
 
 
 def test_after_warm_up_a_first_sparse_fill_of_each_width_compiles_nothing(
@@ -537,19 +542,15 @@ def test_after_warm_up_a_first_sparse_fill_of_each_width_compiles_nothing(
         status = warm.to_json()
         assert status["state"] == "done", status
         assert status["bucket"] == 2 and status["densified"] == [[2]]
-        subs = packed.WORDS_PER_SLICE // 128
         compiled = mesh_mod.compile_stats()["firstCalls"]
         for width in mesh_mod.DENSIFY_WIDTHS:
             out = mesh_mod.densify_sharded(
-                ex._mesh, *packed.bucket_prepared(_pairs(2, width), subs),
-                interpret=True)
+                ex._mesh, *_sparse(2, width), interpret=True)
             assert int(np.asarray(out).sum()) == 2 * width
         assert mesh_mod.compile_stats()["firstCalls"] == compiled
         # the index grows to 4 slices: the first fill compiles its own
         # width, the lane the other five
-        mesh_mod.densify_sharded(
-            ex._mesh, *packed.bucket_prepared(_pairs(4, 4), subs),
-            interpret=True)
+        mesh_mod.densify_sharded(ex._mesh, *_sparse(4, 4), interpret=True)
         deadline = time.monotonic() + JOIN_S
         while [4] not in warm.to_json()["densified"]:
             assert warm._thread.is_alive() and time.monotonic() < deadline
@@ -557,8 +558,7 @@ def test_after_warm_up_a_first_sparse_fill_of_each_width_compiles_nothing(
         compiled = mesh_mod.compile_stats()["firstCalls"]
         for width in mesh_mod.DENSIFY_WIDTHS:
             mesh_mod.densify_sharded(
-                ex._mesh, *packed.bucket_prepared(_pairs(4, width), subs),
-                interpret=True)
+                ex._mesh, *_sparse(4, width), interpret=True)
         assert mesh_mod.compile_stats()["firstCalls"] == compiled
     finally:
         warm.stop()
