@@ -87,9 +87,10 @@ class Blackbox:
         self.ring.close()
 
     def _run(self) -> None:
-        while not self._stop.wait(self.interval_s):
+        while (due := sched_context.timed_wait(
+                self._stop, self.interval_s)) is not None:
             try:
-                with sched_context.background_tick("blackbox"):
+                with sched_context.background_tick("blackbox", due):
                     self.snapshot("periodic")
             except Exception:  # noqa: BLE001 - recording must not kill serving
                 pass

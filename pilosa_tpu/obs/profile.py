@@ -97,9 +97,10 @@ class ContinuousProfiler:
         self._thread = None
 
     def _run(self) -> None:
-        while not self._stop.wait(self.interval):
+        while (due := sched_context.timed_wait(
+                self._stop, self.interval)) is not None:
             try:
-                with sched_context.background_tick("profile"):
+                with sched_context.background_tick("profile", due):
                     self.sample_once()
             except Exception:  # noqa: BLE001 - sampling must not die
                 pass

@@ -132,9 +132,10 @@ class RuntimeCollector:
         self._thread = None
 
     def _run(self) -> None:
-        while not self._stop.wait(self.interval_s):
+        while (due := sched_context.timed_wait(
+                self._stop, self.interval_s)) is not None:
             try:
-                with sched_context.background_tick("runtime"):
+                with sched_context.background_tick("runtime", due):
                     self.collect()
             except Exception:  # noqa: BLE001 - sampling must not kill serving
                 pass

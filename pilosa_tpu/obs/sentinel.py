@@ -197,9 +197,10 @@ class Sentinel:
         self._thread = None
 
     def _run(self) -> None:
-        while not self._stop.wait(self.interval_s):
+        while (due := sched_context.timed_wait(
+                self._stop, self.interval_s)) is not None:
             try:
-                with sched_context.background_tick("sentinel"):
+                with sched_context.background_tick("sentinel", due):
                     self.check()
             except Exception:  # noqa: BLE001 - the sentinel must not die
                 pass

@@ -137,9 +137,10 @@ class Watchdog:
         self._stop.set()
 
     def _run(self) -> None:
-        while not self._stop.wait(self.interval_s):
+        while (due := sched_context.timed_wait(
+                self._stop, self.interval_s)) is not None:
             try:
-                with sched_context.background_tick("watchdog"):
+                with sched_context.background_tick("watchdog", due):
                     self.check()
             except Exception:  # noqa: BLE001 - the watchdog must not die
                 pass

@@ -29,11 +29,13 @@ is gone. The context travels between executor worker threads via
 
 from __future__ import annotations
 
+import gc
 import os
 import random
 import sys
 import threading
 import time
+from collections import deque
 from contextlib import contextmanager
 from typing import Optional
 
@@ -84,13 +86,14 @@ def _session_live() -> bool:
     """Is a profiler session recording? One flag test (no switch of
     our own: the session IS the switch). A process that has not
     imported jax has none — look again next time instead of paying the
-    import here."""
+    import here; nor has one that is half way through importing it
+    (the collector's hook asks from wherever a collection starts)."""
     global _annotation
     if _annotation is None:
-        jax = sys.modules.get("jax")
-        if jax is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is None:
             return False
-        _annotation = jax.profiler.TraceAnnotation
+        _annotation = profiler.TraceAnnotation
     return _annotation.is_enabled()
 
 
@@ -105,6 +108,124 @@ def _annotate(name: str):
     return ann
 
 
+# -- who held the interpreter -------------------------------------------------
+# One interpreter serves every thread, so a request's wait is mostly a
+# wait for whoever runs. Three readings of that, all behind /debug/vars
+# ``interpreter`` and ``backgroundTicks`` (docs/OBSERVABILITY.md):
+# every collector pass is bracketed (``_gc_hook``), every background
+# tick is a named *hold* while it is open and says how late it woke
+# (``background_tick``), and a stretch in which NO request thread
+# crossed a stage boundary although one was inside a stage throughout
+# is a *quiet interval*, named after the hold that covers it.
+
+# No boundary for this long, seen from inside a stage, is a quiet
+# interval: the hot cells' ordinary gaps are 5-14 ms, the stops that
+# PERF.md section 7 rows 22 and 25 describe 86 ms to 3.3 s.
+QUIET_S = 0.025
+HOLD_MIN_S = 0.001      # a hold shorter than this explains no interval
+
+# perf_counter reading of the last stage boundary crossed by ANY request
+# thread. Loaded and stored without a lock at every boundary, with no
+# call between the two (the interpreter switches threads only at one):
+# a lost store is an error of microseconds against QUIET_S, and a
+# second thread that does see the same stop is dropped in ``_quiet``.
+_last_any = 0.0
+_quiet_end = 0.0        # where the last recorded quiet interval ended
+# Closed holds (t0, t1, name, CPU seconds of the holding thread) and
+# open ticks {thread id: (t0, name, thread CPU at t0, its CPU clock)}.
+_HOLDS: deque = deque(maxlen=64)
+_OPEN_HOLDS: dict[int, tuple] = {}
+_gc_open: Optional[tuple] = None    # (t0, generation, annotation)
+# [n, wall seconds, max seconds] a generation, and objects collected.
+# Only the collecting thread writes them (collections do not nest), so
+# the hook takes no lock: it may run inside any ``with _TOTALS_MU``.
+_GC = ([0, 0.0, 0.0], [0, 0.0, 0.0], [0, 0.0, 0.0])
+_GC_NAMES = ("gc.gen0", "gc.gen1", "gc.gen2")
+_gc_collected = 0
+_QUIET = {"n": 0, "wall": 0.0, "byHolder": {}, "byStage": {}}
+_QUIET_RECENT: deque = deque(maxlen=32)
+
+
+def _gc_hook(phase: str, info: dict) -> None:
+    """``gc.callbacks`` entry: a collection holds the interpreter from
+    ``start`` to ``stop``. Counted always; under a profiler session
+    also a ``pilosa.gc.gen<g>`` segment on the collecting thread."""
+    global _gc_open, _gc_collected
+    if phase == "start":
+        gen = info["generation"]
+        _gc_open = (time.perf_counter(), gen, _annotate(_GC_NAMES[gen]))
+        return
+    t1 = time.perf_counter()
+    opened, _gc_open = _gc_open, None
+    if opened is None:      # registered between a start and its stop
+        return
+    t0, gen, ann = opened
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    dt = t1 - t0
+    g = _GC[gen]
+    g[0] += 1
+    g[1] += dt
+    if dt > g[2]:
+        g[2] = dt
+    _gc_collected += info["collected"]
+    if dt >= HOLD_MIN_S:
+        _HOLDS.append((t0, t1, _GC_NAMES[gen], dt))
+
+
+def _holder(t0: float, t1: float) -> tuple[str, float]:
+    """(name, seconds) of the hold that best explains ``[t0, t1]``:
+    closed ones from the ring, open ticks and a running collection
+    counted up to ``t1`` (a tick whose long call has just returned has
+    not reached its ``finally`` when the first waiter runs). A hold
+    whose thread was on a CPU for under half of what it overlaps does
+    not count: a tick asleep on a disk or a compile, or itself waiting
+    for the interpreter, holds nothing (half, not all of it: a holder
+    the machine parked for a while still holds). The one that overlaps
+    most, to the millisecond; of equals the shorter (a tick inside a
+    tick). It has to cover half of the interval, else ``unknown``."""
+    holds = list(_HOLDS)
+    for h0, name, cpu0, clock_id in list(_OPEN_HOLDS.values()):
+        try:
+            cpu = time.clock_gettime(clock_id) - cpu0
+        except OSError:         # the thread is gone, or no such clock
+            cpu = t1 - h0
+        holds.append((h0, t1, name, cpu))
+    running = _gc_open
+    if running is not None:
+        holds.append((running[0], t1, _GC_NAMES[running[1]],
+                      t1 - running[0]))
+    best, best_key, best_s = "unknown", (0.0, 0.0), 0.0
+    for h0, h1, name, cpu in holds:
+        s = min(h1, t1) - max(h0, t0)
+        key = (round(s, 3), h0 - h1)
+        if s > 0.0 and cpu >= s / 2 and key > best_key:
+            best, best_key, best_s = name, key, s
+    if best_s < (t1 - t0) / 2:
+        return "unknown", 0.0
+    return best, best_s
+
+
+def _quiet(stage: str, wall: float, gap: float) -> None:
+    """Record the quiet interval ``[wall - gap, wall]`` that the
+    calling thread saw from inside ``stage``. Off the hot path: a
+    boundary calls this only where ``gap >= QUIET_S``."""
+    global _quiet_end
+    holder, held = _holder(wall - gap, wall)
+    rec = {"at": time.time() - gap, "ms": round(gap * 1e3, 3),
+           "stage": stage, "holder": holder,
+           "holderMs": round(held * 1e3, 3)}
+    with _TOTALS_MU:
+        if wall - gap < _quiet_end:     # the stop another thread recorded
+            return
+        _quiet_end = wall
+        _QUIET["n"] += 1
+        _QUIET["wall"] += gap
+        _add(_QUIET["byHolder"], {holder: (1, gap)})
+        _add(_QUIET["byStage"], {stage: (1, gap)})
+        _QUIET_RECENT.append(rec)
+
+
 class StageClock:
     """Self-time stages of ONE thread: a stack of open stages and, per
     stage name, ``[entries, wall seconds]``.
@@ -116,7 +237,12 @@ class StageClock:
     the last boundary by construction. ``switch`` replaces the top
     stage: the form for the connection thread's top-level sequence
     (http_read → parse → … → http_write), which never leaves the stack
-    empty in between. A boundary reads ``perf_counter`` once.
+    empty in between. A boundary reads ``perf_counter`` once, and
+    compares it with the last boundary of ANY thread (``_last_any``):
+    a boundary that ends a wait of ``QUIET_S`` in which no other request
+    thread crossed one records a quiet interval (``_quiet``). A boundary
+    that fills an empty stack only sets the mark: the thread was in no
+    stage, so a request reaching an idle server is no stop.
 
     The thread's CPU clock is read only where the stack fills and where
     it empties (twice a request on the connection thread, twice a leg):
@@ -133,6 +259,7 @@ class StageClock:
                  "_stack", "_wall", "_cpu0", "_ann")
 
     def __init__(self, name: str = "", start: Optional[float] = None):
+        global _last_any
         self.acct: dict[str, list] = {}
         self.cpu = 0.0
         self.ctx: Optional["QueryContext"] = None
@@ -148,6 +275,7 @@ class StageClock:
         self.spans: list[tuple] = []
         self.tid = threading.get_ident()
         if name:
+            _last_any = self._wall
             self._cpu0 = time.thread_time()
             self._begin(name, self._wall, None)
 
@@ -179,9 +307,14 @@ class StageClock:
     def push(self, name: str, tags: Optional[dict] = None) -> None:
         """The boundary into ``name``: charge the time since the last
         one to the stage on top, suspend it, enter ``name``."""
+        global _last_any
         wall = time.perf_counter()
+        gap = wall - _last_any
+        _last_any = wall
         if self._stack:
             self.acct[self._stack[-1][0]][1] += wall - self._wall
+            if gap >= QUIET_S:
+                _quiet(self._stack[-1][0], wall, gap)
         else:
             self._cpu0 = time.thread_time()
         self._wall = wall
@@ -190,9 +323,14 @@ class StageClock:
     def pop(self) -> None:
         """The boundary out of the stage on top: charge it, resume its
         parent."""
+        global _last_any
         wall = time.perf_counter()
+        gap = wall - _last_any
+        _last_any = wall
         stack = self._stack
         self.acct[stack[-1][0]][1] += wall - self._wall
+        if gap >= QUIET_S:
+            _quiet(stack[-1][0], wall, gap)
         self._wall = wall
         self._leave()
         if not stack:
@@ -203,10 +341,15 @@ class StageClock:
     def switch(self, name: str) -> None:
         """End the stage on top and begin ``name`` in its place, in one
         boundary (a push where nothing is open)."""
+        global _last_any
         if not self._stack:
             return self.push(name)
         wall = time.perf_counter()
+        gap = wall - _last_any
+        _last_any = wall
         self.acct[self._stack[-1][0]][1] += wall - self._wall
+        if gap >= QUIET_S:
+            _quiet(self._stack[-1][0], wall, gap)
         self._wall = wall
         self._leave()
         self._begin(name, wall, None)
@@ -216,9 +359,14 @@ class StageClock:
         segment closes, and a clock that served a query folds into the
         process totals. The HTTP front end calls this once the
         response has been handed to the socket."""
+        global _last_any
         if self._stack:
             wall = time.perf_counter()
+            gap = wall - _last_any
+            _last_any = wall
             self.acct[self._stack[-1][0]][1] += wall - self._wall
+            if gap >= QUIET_S:
+                _quiet(self._stack[-1][0], wall, gap)
             self._wall = wall
             self.cpu += time.thread_time() - self._cpu0
             while self._stack:
@@ -303,11 +451,12 @@ NOP = _Nop()
 
 
 # Process totals behind /debug/vars: ``queryStages`` by lane (non-remote
-# /query requests, folded once each when the response is on the socket)
-# and ``backgroundTicks`` by loop. One lock, taken once a request / tick.
+# /query requests, folded once each when the response is on the socket),
+# ``backgroundTicks`` by loop and ``interpreter``'s quiet intervals. One
+# lock, taken once a request / tick / quiet interval.
 _TOTALS_MU = threading.Lock()
 _QUERY_TOTALS: dict[str, dict] = {}
-_BG_TOTALS: dict[str, list] = {}
+_BG_TOTALS: dict[str, list] = {}    # loop -> [n, wall, cpu, lateN, late]
 
 
 def _add(into: dict, acct: dict) -> None:
@@ -344,8 +493,14 @@ def _acct_json(acct: dict) -> dict:
 
 
 def stage_totals() -> dict:
-    """The /debug/vars blocks ``queryStages`` and ``backgroundTicks``."""
+    """The /debug/vars blocks ``queryStages``, ``backgroundTicks`` and
+    ``interpreter``."""
     with _TOTALS_MU:
+        gc_json: dict = {
+            "gen%d" % gen: {"n": n, "wallUs": round(wall * 1e6),
+                            "maxUs": round(longest * 1e6)}
+            for gen, (n, wall, longest) in enumerate(_GC)}
+        gc_json["collected"] = _gc_collected
         return {
             "queryStages": {
                 lane: {"requests": t["requests"],
@@ -356,34 +511,74 @@ def stage_totals() -> dict:
                 for lane, t in _QUERY_TOTALS.items()},
             "backgroundTicks": {
                 loop: {"n": n, "wallUs": round(wall * 1e6),
-                       "cpuUs": round(cpu * 1e6)}
-                for loop, (n, wall, cpu) in _BG_TOTALS.items()}}
+                       "cpuUs": round(cpu * 1e6),
+                       "lateN": late_n, "lateUs": round(late * 1e6)}
+                for loop, (n, wall, cpu, late_n, late)
+                in _BG_TOTALS.items()},
+            "interpreter": {
+                "gc": gc_json,
+                "quiet": {"n": _QUIET["n"],
+                          "wallUs": round(_QUIET["wall"] * 1e6),
+                          "thresholdMs": round(QUIET_S * 1e3),
+                          "byHolder": _acct_json(_QUIET["byHolder"]),
+                          "byStage": _acct_json(_QUIET["byStage"])},
+                "recent": list(_QUIET_RECENT)}}
+
+
+def timed_wait(stop: threading.Event, interval: float) -> Optional[float]:
+    """``stop.wait(interval)`` for a loop that ticks on a fixed
+    interval: the ``perf_counter`` time at which the wait was due to
+    end, for ``background_tick(loop, due=...)``; None once ``stop`` is
+    set."""
+    due = time.perf_counter() + interval
+    return None if stop.wait(interval) else due
 
 
 @contextmanager
-def background_tick(loop: str):
+def background_tick(loop: str, due: Optional[float] = None):
     """Around ONE tick of a background loop: the same wall/CPU/entries
     counter under ``backgroundTicks[loop]`` and a ``pilosa.bg.<loop>``
     segment on the profiler's clock, so what the loops cost a served
-    request (the GIL they hold) can be read beside its stages."""
+    request (the GIL they hold) can be read beside its stages. While
+    open the tick is a hold ``bg.<loop>`` that a quiet interval can be
+    named after. ``due`` is when the loop's timed wait should have
+    ended (``timed_wait``): how much later the tick begins is what a
+    thread pays to get the interpreter back after any release, counted
+    under ``lateUs`` / ``lateN``."""
     ann = _annotate("bg." + loop)
     wall, cpu = time.perf_counter(), time.thread_time()
+    tid = threading.get_ident()
+    outer = _OPEN_HOLDS.get(tid)    # ``history`` ticks inside ``runtime``
+    _OPEN_HOLDS[tid] = (wall, "bg." + loop, cpu,
+                        time.pthread_getcpuclockid(tid))
     try:
         yield
     finally:
-        dw = time.perf_counter() - wall
+        t1 = time.perf_counter()
+        dw = t1 - wall
         dc = time.thread_time() - cpu
+        if dw >= HOLD_MIN_S:
+            _HOLDS.append((wall, t1, "bg." + loop, dc))
+        if outer is None:
+            _OPEN_HOLDS.pop(tid, None)
+        else:
+            _OPEN_HOLDS[tid] = outer
         if ann is not None:
             ann.__exit__(None, None, None)
+        late = 0.0 if due is None else max(0.0, wall - due)
         with _TOTALS_MU:
             t = _BG_TOTALS.get(loop)
             if t is None:
-                _BG_TOTALS[loop] = [1, dw, dc]
-            else:
-                t[0] += 1
-                t[1] += dw
-                t[2] += dc
+                t = _BG_TOTALS[loop] = [0, 0.0, 0.0, 0, 0.0]
+            t[0] += 1
+            t[1] += dw
+            t[2] += dc
+            if due is not None:
+                t[3] += 1
+                t[4] += late
 
+
+gc.callbacks.append(_gc_hook)
 
 
 # -- query ids ---------------------------------------------------------------

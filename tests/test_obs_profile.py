@@ -444,15 +444,18 @@ class TestSLOAndHealth:
 # -- overhead guard -----------------------------------------------------------
 
 class TestOverheadGuard:
-    def test_accounting_and_profiler_under_5pct_p50(self, handler,
+    def test_accounting_and_profiler_under_5pct_cpu(self, handler,
                                                     holder):
         """Accounting ON + the continuous profiler at its default rate
-        must cost <5% on the bench query leg's p50. The profiler runs
-        for the WHOLE measurement (its sampling load hits both modes;
-        its per-query serving cost is zero by construction) and the
-        accounting switch alternates in small interleaved groups, so
-        shared-CI scheduler noise lands on both modes equally — the
-        p50s then differ only by the increments under test."""
+        must cost <5% of the bench query leg's CPU. The query runs on
+        the calling thread, so its cost is that thread's CPU time
+        (``time.thread_time``): what the machine's other processes do
+        to the wall clock is not in it. The profiler runs for the WHOLE
+        measurement (its sampling load hits both modes; its per-query
+        serving cost is zero by construction) and the accounting switch
+        alternates in small interleaved groups, so whatever does move
+        the CPU clock (cache, frequency) lands on both modes equally —
+        the sums then differ only by the increments under test."""
         # A bench-leg-weight query (the suite's config-2 shape scaled
         # down): materializing Union over many rows — real container
         # algebra per query, so the fixed per-query ledger cost is
@@ -469,34 +472,32 @@ class TestOverheadGuard:
                              for r in range(n_rows))
         q = f"Union({children})".encode()
 
-        def run_group(samples, n=25):
+        def run_group(n=25) -> float:
+            """CPU seconds of this thread over ``n`` queries."""
+            cpu0 = time.thread_time()
             for _ in range(n):
-                t0 = time.perf_counter()
                 status, _, _ = call(handler, "POST", "/index/i/query",
                                     q)
-                samples.append(time.perf_counter() - t0)
                 assert status == 200
+            return time.thread_time() - cpu0
 
         prof = ContinuousProfiler()  # default rate
-        warm: list = []
-        run_group(warm, 50)  # warm caches/pools for both modes
-        on_samples: list = []
-        off_samples: list = []
+        run_group(50)  # warm caches/pools for both modes
+        on_cpu = off_cpu = 0.0
         prof.start()
         try:
             for _ in range(12):
                 accounting.set_enabled(False)
-                run_group(off_samples)
+                off_cpu += run_group()
                 accounting.set_enabled(True)
-                run_group(on_samples)
+                on_cpu += run_group()
         finally:
             accounting.set_enabled(True)
             prof.stop()
         assert prof.samples_taken >= 1  # it really ran alongside
-        on_p50 = sorted(on_samples)[len(on_samples) // 2]
-        off_p50 = sorted(off_samples)[len(off_samples) // 2]
-        ratio = on_p50 / off_p50
+        assert off_cpu > 0.0
+        ratio = on_cpu / off_cpu
         assert ratio < 1.05, (
             f"accounting+profiler overhead {ratio:.3f}x "
-            f"(on p50={on_p50 * 1e3:.3f}ms"
-            f" off p50={off_p50 * 1e3:.3f}ms)")
+            f"(on {on_cpu * 1e3 / 300:.3f} ms a query"
+            f" off {off_cpu * 1e3 / 300:.3f} ms of CPU)")

@@ -490,10 +490,12 @@ def test_metric_file_agrees_with_benchmark_json(name):
 
 
 def test_the_cell_reports_the_unlisted_metrics_and_its_own_five():
+    """And the four of the interpreter (PR 36), which list every cell."""
     listed = {m["name"] for m in run_cell._listed(BENCH["per_layer"], CELL)}
     assert listed == set(NEW_METRICS) | {
         "device_served_pct", "compiles_in_window", "device_idle_pct",
-        "import_mbit_s"}
+        "import_mbit_s", "gc_pause_pct", "wake_late_ms", "stall_pct",
+        "stall_named_pct"}
 
 
 # -- the densify programs are warm before the first fill ----------------------

@@ -96,24 +96,26 @@ class TestTiling:
                              ids=lambda f: f.__name__.strip("_"))
     def test_self_times_sum_to_the_threads_time(self, body):
         """Σ self wall of the thread's stages = last boundary − first,
-        whatever the nesting: entering a stage suspends its parent."""
+        whatever the nesting: entering a stage suspends its parent.
+        Both ends are the clock's own readings (``start`` and its last
+        boundary), so the sum is exact whatever else the machine runs;
+        and the CPU of the tiling, read at those two boundaries, lies
+        inside two readings of the same clock taken around them."""
+        cpu0 = time.thread_time()
         t0 = time.perf_counter()
-        clock = StageClock("http_read")
+        clock = StageClock("http_read", start=t0)
         ctx = QueryContext(pql="q", clock=clock)
         body(ctx)
         clock.switch("http_write")
         clock.close()
-        t1 = time.perf_counter()
+        cpu1 = time.thread_time()
         own, off = ctx.stage_totals()
         total = sum(a[1] for a in own.values())
-        # The two outer readings bracket the clock's first and last
-        # boundary by a few microseconds.
-        assert total <= t1 - t0
-        assert total >= (t1 - t0) - 0.0005
+        assert total == pytest.approx(clock._wall - t0, rel=0, abs=1e-9)
         assert not off
         assert all(n >= 1 and wall >= 0.0 for n, wall in own.values())
         cpu, off_cpu = ctx.stage_cpu()
-        assert 0.0 < cpu <= total + 0.001 and off_cpu == 0.0
+        assert 0.0 <= cpu <= cpu1 - cpu0 and off_cpu == 0.0
 
     def test_self_time_not_inclusive_time(self):
         ctx = QueryContext(pql="q")
@@ -318,10 +320,13 @@ class TestQueryStagesTotals:
         assert stages["dispatch"]["n"] >= stages["fetch"]["n"] >= self.N
         # CPU is read where a thread's stack fills and empties: one
         # number a thread, under the wall its stages tile, recv to
-        # sendall, inside the client's send-to-read.
+        # sendall, inside the client's send-to-read (each stage's
+        # growth is the difference of two totals rounded to a
+        # microsecond: up to one each).
         wall = sum(a["wallUs"] for a in stages.values())
         cpu = after["cpuUs"] - before["cpuUs"]
-        assert 0 < cpu <= wall * 1.05 and wall <= elapsed * 1e6
+        assert 0 < cpu <= wall * 1.05
+        assert wall <= elapsed * 1e6 + len(stages)
         assert after["offThreadCpuUs"] == before["offThreadCpuUs"]
 
     def test_writes_fold_under_their_lane(self, device_server):
@@ -452,7 +457,9 @@ class TestProfilerClock:
     def test_flat_segments_on_the_host_plane(self, device_server,
                                              tmp_path):
         """A profiler session around served queries: ``pilosa.<stage>``
-        events on /host:CPU, one at a time per thread (never nested)."""
+        events on /host:CPU, one at a time per thread (never nested; a
+        collector pass, ``pilosa.gc.gen<g>``, lies inside the stage of
+        whichever thread it began on and is left out of that rule)."""
         import jax
         from jax.profiler import ProfileData
         _, conn = device_server
@@ -479,7 +486,8 @@ class TestProfilerClock:
             for line in plane.lines:
                 evs = sorted((e.start_ns, e.start_ns + e.duration_ns,
                               e.name) for e in line.events
-                             if e.name.startswith("pilosa."))
+                             if e.name.startswith("pilosa.")
+                             and not e.name.startswith("pilosa.gc."))
                 names.update(e[2] for e in evs)
                 for a, b in zip(evs, evs[1:]):
                     assert a[1] <= b[0], (line.name, a, b)
