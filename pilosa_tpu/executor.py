@@ -2620,6 +2620,11 @@ class Executor:
         v = self.holder.view(index, frame, view)
         if v is None:
             return (0, 0)
+        return self._route_view(v, index, frame, view, slices, walked)
+
+    def _route_view(self, v, index: str, frame: str, view: str, slices,
+                    walked: Optional[list]) -> tuple[int, int]:
+        """``_view_token`` of the view ``v`` itself."""
         tok = (v.uid, v.generation)
         route = getattr(slices, "route", None)
         if route is None:
@@ -2636,13 +2641,22 @@ class Executor:
     def _leaf_cache_key(self, mesh, index: str, leaf: tuple, slices,
                         walked: Optional[list] = None) -> tuple:
         """Residency key of one leaf slab, O(1) in the slice count: the
-        slice set by name and the view's token stand for what used to
-        be a (uid, generation) pair a fragment."""
+        slice set by name, the view's uid and the ROW's token
+        (``View.row_token``), read before anything is resolved: a write
+        to another row leaves the key, and the slab, as they are. The
+        view's token is confirmed against the route record all the same
+        (``routeMemo``)."""
         from .parallel import mesh as mesh_mod
         frame, view, row_id = leaf
-        uid, gen = self._view_token(index, frame, view, slices, walked)
+        v = self.holder.view(index, frame, view)
+        if v is None:
+            uid = epoch = row_epoch = 0
+        else:
+            uid, epoch, row_epoch = (v.uid, v.epoch,
+                                     v.row_epochs.get(row_id, 0))
+            self._route_view(v, index, frame, view, slices, walked)
         return ("leaf", id(self.holder), index, frame, view, row_id,
-                self._slices_key(slices), uid, gen,
+                self._slices_key(slices), uid, epoch, row_epoch,
                 mesh.shape[mesh_mod.AXIS_SLICES])
 
     def _leaf_lookup(self, mesh, index: str, leaves: list[tuple],
@@ -2676,10 +2690,13 @@ class Executor:
         looked up once; what it found is reported as hits now that the
         leg is taken, and only the absent slabs resolve fragments.
 
-        The key embeds the backing view's (uid, generation), so
-        writes/reopens stop the entry being referenced and it ages out
-        of the LRU — repeated Count/TopN over a stable index re-use the
-        upload instead of re-packing + re-transferring per query."""
+        The key embeds the view's uid and the leaf row's token, so a
+        write to the row or a reopen of the view drops the slab (and a
+        fill that raced it keeps nothing) — repeated Count/TopN over
+        rows nobody writes re-use the upload instead of re-packing +
+        re-transferring per query. A miss resolves its fragments by the
+        walk, after the key's token was read: every fragment the token
+        vouches for is in it."""
         from .parallel import residency
         with sched_context.stage("route"):  # a miss nests pack, upload
             keys, found = (looked if looked is not None else
@@ -2691,19 +2708,25 @@ class Executor:
             for i, arr in enumerate(arrs):
                 if arr is None:
                     frame, view, row_id = leaves[i]
+                    key = keys[i]
+                    v = self.holder.view(index, frame, view)
+                    # a view made anew since the key: build, keep none
+                    owner = (None if v is None else (
+                        v, row_id,
+                        key[-3:-1] if v.uid == key[-4] else None))
                     arrs[i] = residency.leaf_slab(
-                        mesh, keys[i],
-                        self._key_frags(index, frame, view, slices,
-                                        keys[i]), row_id)
+                        mesh, key,
+                        lambda f=frame, w=view: self._leaf_frags(
+                            index, f, w, slices), row_id, owner)
             return arrs
 
     def _key_frags(self, index: str, frame: str, view: str, slices,
                    key: tuple):
-        """The lazy fragment list of a slab or candidate block under
-        residency ``key`` (only a miss resolves it): the route
-        record's, where it holds one resolved under the key's view
-        token (a list resolved after the token was read shows every
-        fragment the token vouches for), else the walk."""
+        """The lazy fragment list of a candidate block under residency
+        ``key`` (only a miss resolves it): the route record's, where it
+        holds one resolved under the key's view token (a list resolved
+        after the token was read shows every fragment the token vouches
+        for), else the walk."""
         def frags():
             route = getattr(slices, "route", None)
             if route is not None:

@@ -14,7 +14,7 @@ import threading
 from typing import Callable, Optional
 
 from .. import SLICE_WIDTH
-from ..parallel.residency import next_uid
+from ..parallel.residency import device_cache, next_uid
 from ..storage import cache as cache_mod
 from ..storage.fragment import Fragment
 from ..utils import logger as logger_mod
@@ -71,12 +71,17 @@ class View:
         # picture of any fragment of this view may have — wherever a
         # fragment's own device generation moves, and when a fragment
         # is created, opened, closed or snapshotted; a view dropped and
-        # recreated is a new object with a fresh ``uid``. Residency
-        # keys and the executor's route records embed the pair, so a
-        # read validates a whole view with one compare.
+        # recreated is a new object with a fresh ``uid``. The executor's
+        # route records, the plan memo and TopN's candidate blocks embed
+        # the pair, so a read validates a whole view with one compare.
         self.uid = next_uid()
         self.generation = 0
         self._token_mu = threading.Lock()
+        # A leaf slab's token is its ROW's (``row_token``): the
+        # generation of the view's last whole retirement (an import, a
+        # reopen) and of the last write to the row.
+        self.epoch = 0
+        self.row_epochs: dict[int, int] = {}
 
     def bump(self) -> None:
         """Move the token. Writers call this AFTER changing the data
@@ -86,6 +91,31 @@ class View:
         a reader compares for equality and needs it monotonic."""
         with self._token_mu:
             self.generation += 1
+
+    def row_token(self, row_id: int) -> tuple[int, int]:
+        """What a leaf slab of ``row_id`` is keyed on beside the uid
+        (the executor reads the two fields inline)."""
+        return (self.epoch, self.row_epochs.get(row_id, 0))
+
+    def retire_rows(self, row_ids) -> int:
+        """A write changed bits of ``row_ids``: move the token and those
+        rows' tokens, then drop their resident slabs (the others stay).
+        Same order as ``bump``: after the data, before the ack."""
+        with self._token_mu:
+            self.generation += 1
+            gen = self.generation
+            epochs = self.row_epochs
+            for r in row_ids:
+                epochs[r] = gen
+        return device_cache().retire_rows(self.uid, row_ids)
+
+    def retire_all(self) -> int:
+        """Every row may have changed (an import, a reopen): move the
+        token and every row's, then drop the view's resident slabs."""
+        with self._token_mu:
+            self.generation += 1
+            self.epoch = self.generation
+        return device_cache().retire_view(self.uid)
 
     # -- lifecycle
 

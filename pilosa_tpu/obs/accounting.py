@@ -77,7 +77,8 @@ class QueryCost:
 
     __slots__ = ("node", "container_ops", "words_scanned",
                  "bits_written", "device_programs", "device_bytes",
-                 "mesh_devices", "cold_leaves", "fill_waits", "compile_s",
+                 "mesh_devices", "cold_leaves", "fill_waits",
+                 "retired_slabs", "compile_s",
                  "wal_wait_s", "result_cache_hits", "rpc", "children",
                  "_mu")
 
@@ -98,6 +99,9 @@ class QueryCost:
         # building them, and waited for (stage ``fill_wait``): with
         # ``cold_leaves`` 0 and this 0, every operand was resident.
         self.fill_waits = 0
+        # Resident slabs this query's writes took out of the device
+        # cache (a write retires its own row's slabs).
+        self.retired_slabs = 0
         self.compile_s = 0.0
         # Seconds this query's threads spent blocked in WAL group
         # commit (waiting for a leader's flush to cover their records)
@@ -136,6 +140,9 @@ class QueryCost:
 
     def note_fill_wait(self) -> None:
         self.fill_waits += 1
+
+    def note_retired_slabs(self, n: int) -> None:
+        self.retired_slabs += n
 
     def note_compile(self, seconds: float) -> None:
         self.compile_s += seconds
@@ -193,6 +200,8 @@ class QueryCost:
             out["coldLeaves"] = self.cold_leaves
         if self.fill_waits:
             out["fillWaits"] = self.fill_waits
+        if self.retired_slabs:
+            out["retiredSlabs"] = self.retired_slabs
         if self.wal_wait_s:
             out["walWaitMs"] = round(self.wal_wait_s * 1e3, 3)
         if self.result_cache_hits:
@@ -228,6 +237,8 @@ class QueryCost:
             out["coldLeaves"] = self.cold_leaves
         if self.fill_waits:
             out["fillWaits"] = self.fill_waits
+        if self.retired_slabs:
+            out["retiredSlabs"] = self.retired_slabs
         if self.wal_wait_s:
             out["walWaitMs"] = round(self.wal_wait_s * 1e3, 3)
         if self.result_cache_hits:
@@ -333,6 +344,12 @@ def note_fill_wait() -> None:
     cost = current_cost()
     if cost is not None:
         cost.note_fill_wait()
+
+
+def note_retired_slabs(n: int) -> None:
+    cost = current_cost()
+    if cost is not None:
+        cost.note_retired_slabs(n)
 
 
 def note_compile(seconds: float) -> None:

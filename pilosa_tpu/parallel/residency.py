@@ -15,16 +15,19 @@ so device state is an explicit, *budgeted* cache:
 - ``DeviceRowCache`` — per-fragment host-side LRU of packed row words
   (feeds block builds and mesh uploads; invalidated per row by writes).
 
-Staleness is handled by keys, not callbacks: every cached block's key
-embeds the owning VIEW's ``(uid, generation)`` pair (models.view.View)
-— every write-invalidation of any of the view's fragments bumps the
-generation where the fragment's own generation bumps, a fragment
-created, opened, closed or snapshotted bumps it too, and a view that is
-dropped and recreated mints a fresh uid — so stale entries simply stop
-being referenced and age out of the LRU. The key is O(1) in the slice
-count; invalidation is as coarse as it was for every query over the
-whole index (a write to slice s always retired every slab whose slice
-set held s).
+Staleness is handled by keys, and a leaf slab is retired by its row. A
+TopN candidate block's key embeds the owning VIEW's ``(uid,
+generation)`` token (models.view.View), which every write and every
+lifecycle event of the view's fragments moves: such a block stops
+being referenced and ages out of the LRU. A leaf slab's key embeds the
+view's uid and its ROW's token (``View.row_token``: the generation of
+the last whole-view retirement and of the last write to that row), so
+a write to row r retires only row r's slabs — every other row's slab
+stays resident — and an import, a reopen or a corrupt file retires
+the view's. Retired slabs are dropped from the cache at once (the
+budget they held is free before the write is acknowledged), and a
+fill that raced the retirement, its key taken before it, is not kept
+(``get_or_build``'s ``owner``). The key is O(1) in the slice count.
 
 Upload layout: the globally-sharded slab builders (``leaf_slab``,
 ``candidate_block``) pad the slice axis to its canonical bucket
@@ -66,6 +69,10 @@ DEFAULT_MAX_ROWS = 256
 DEFAULT_HBM_BUDGET_MB = 1024
 
 _uid_counter = itertools.count(1)
+
+# Rows whose resident slab a write retired and no fill has built again
+# yet (what ``refills`` counts), at most this many, oldest forgotten.
+_REFILL_WATCH = 4096
 
 
 class _Fill:
@@ -110,13 +117,26 @@ class DeviceBlockCache:
         self.fill_seconds = 0.0
         self.fill_bytes = 0
         self.fills_dense = 0
+        # The slabs one row of one view owns: view uid -> row id ->
+        # their keys, and each such key's (uid, row), so that a write
+        # drops its row's entries and an eviction forgets its key.
+        self._owned: dict[int, dict[int, set]] = {}
+        self._owner_of: dict[tuple, tuple[int, int]] = {}
+        # Slabs dropped and why: a write changed a bit of their row
+        # (and their bytes), or their whole view was retired; fills of
+        # a row whose slab a write had dropped.
+        self.retired_by_write = 0
+        self.retired_by_write_bytes = 0
+        self.retired_all = 0
+        self.refills = 0
+        self._refill_due: OrderedDict[tuple[int, int], None] = OrderedDict()
 
     @staticmethod
     def _nbytes(arr) -> int:
         return int(np.prod(arr.shape)) * arr.dtype.itemsize
 
-    def get_or_build(self, key: tuple,
-                     build: Callable[[], jax.Array]) -> jax.Array:
+    def get_or_build(self, key: tuple, build: Callable[[], jax.Array],
+                     owner: Optional[tuple] = None) -> jax.Array:
         """The resident array under ``key``, built on a miss by the
         first requester, on its own thread and outside the lock
         (packing + device_put take long and must not serialize
@@ -124,11 +144,19 @@ class DeviceBlockCache:
         that arrives while the key's build is in flight waits for it
         and takes its array (a miss, and a ``fillWait``) instead of
         packing the same rows again. The caller's key embeds the
-        owning view's token, read before the fragments are resolved,
-        so a waiter never receives a slab older than its own token: a
-        write between two requests gives two keys and two builds. A
-        build that raises wakes its waiters with the error and leaves
-        nothing behind: the next request builds again."""
+        owning view's (or row's) token, read before the fragments are
+        resolved, so a waiter never receives a slab older than its own
+        token: a write between two requests gives two keys and two
+        builds. A build that raises wakes its waiters with the error
+        and leaves nothing behind: the next request builds again.
+
+        ``owner`` = ``(view, row_id, token)`` marks a slab that one row
+        of one view owns, ``token`` being the ``view.row_token(row_id)``
+        its key embeds: it is kept only if that is still the row's
+        token, checked under this cache's lock — a build whose key was
+        taken before a write to its row hands its array to its waiters
+        and keeps nothing — and ``retire_rows`` / ``retire_view`` drop
+        it."""
         with self._mu:
             arr = self._lru.get(key)
             if arr is not None:
@@ -161,26 +189,98 @@ class DeviceBlockCache:
                 self.fill_seconds += time.perf_counter() - t0
                 if mine.error is None:
                     self.fill_bytes += self._nbytes(mine.arr)
-                    self._insert(key, mine.arr)
+                    if owner is None:
+                        self._insert(key, mine.arr)
+                    else:
+                        view, row_id, token = owner
+                        if view.row_token(row_id) == token:
+                            self._insert(key, mine.arr, (view.uid, row_id))
             mine.done.set()
         accounting.note_cold_leaf()
         return mine.arr
 
-    def _insert(self, key: tuple, arr) -> None:
+    def _insert(self, key: tuple, arr,
+                tag: Optional[tuple[int, int]] = None) -> None:
         """Keep a built array (lock held), unless it is bigger than the
-        whole working set: that one is a one-shot."""
+        whole working set: that one is a one-shot. ``tag`` = the
+        ``(view uid, row)`` that owns it."""
         nbytes = self._nbytes(arr)
         if nbytes > self.budget_bytes:
             return
         if key not in self._lru:
             self._lru[key] = arr
             self.used_bytes += nbytes
+            if tag is not None:
+                self._owner_of[key] = tag
+                self._owned.setdefault(tag[0], {}).setdefault(
+                    tag[1], set()).add(key)
+                if tag in self._refill_due:
+                    del self._refill_due[tag]
+                    self.refills += 1
         self._lru.move_to_end(key)
         # len > 1 keeps the just-built entry (now most-recent) alive.
         while self.used_bytes > self.budget_bytes and len(self._lru) > 1:
-            _, old = self._lru.popitem(last=False)
+            old_key, old = self._lru.popitem(last=False)
             self.used_bytes -= self._nbytes(old)
             self.evictions += 1
+            tag = self._owner_of.pop(old_key, None)
+            if tag is not None:
+                rows = self._owned[tag[0]]
+                keys = rows[tag[1]]
+                keys.discard(old_key)
+                if not keys:
+                    del rows[tag[1]]
+                    if not rows:
+                        del self._owned[tag[0]]
+
+    def _drop(self, keys) -> int:
+        """Take ``keys`` out of the LRU (lock held); their bytes."""
+        nbytes = 0
+        for k in keys:
+            del self._owner_of[k]
+            nbytes += self._nbytes(self._lru.pop(k))
+        self.used_bytes -= nbytes
+        return nbytes
+
+    def retire_rows(self, uid: int, row_ids) -> int:
+        """A write changed a bit of each of ``row_ids`` in view ``uid``,
+        and the view's row tokens have moved: drop those rows' slabs
+        (their keys can no longer be built). Returns how many; the
+        request that wrote is told (``X-Pilosa-Stats.retiredSlabs``)."""
+        n = 0
+        with self._mu:
+            rows = self._owned.get(uid)
+            if rows:
+                due = self._refill_due
+                for r in row_ids:
+                    keys = rows.pop(r, None)
+                    if keys:
+                        n += len(keys)
+                        self.retired_by_write_bytes += self._drop(keys)
+                        due[(uid, r)] = None
+                        due.move_to_end((uid, r))
+                if not rows:
+                    del self._owned[uid]
+                while len(due) > _REFILL_WATCH:
+                    due.popitem(last=False)
+                self.retired_by_write += n
+        if n:
+            accounting.note_retired_slabs(n)
+        return n
+
+    def retire_view(self, uid: int) -> int:
+        """Every row of view ``uid`` is retired (an import, a reopen, a
+        corrupt file replaced): drop all of its slabs."""
+        with self._mu:
+            rows = self._owned.pop(uid, None)
+            if not rows:
+                return 0
+            n = 0
+            for keys in rows.values():
+                n += len(keys)
+                self._drop(keys)
+            self.retired_all += n
+            return n
 
     def note_dense_fill(self) -> None:
         """A builder's word that its fill ships a host-dense block."""
@@ -222,6 +322,9 @@ class DeviceBlockCache:
     def clear(self) -> None:
         with self._mu:
             self._lru.clear()
+            self._owned.clear()
+            self._owner_of.clear()
+            self._refill_due.clear()
             self.used_bytes = 0
 
     def snapshot(self) -> dict:
@@ -245,7 +348,11 @@ class DeviceBlockCache:
                     "fills": self.fills, "fillWaits": self.fill_waits,
                     "fillSeconds": round(self.fill_seconds, 6),
                     "fillBytes": self.fill_bytes,
-                    "fillsDense": self.fills_dense}
+                    "fillsDense": self.fills_dense,
+                    "retired": {"byWrite": self.retired_by_write,
+                                "byWriteBytes": self.retired_by_write_bytes,
+                                "all": self.retired_all},
+                    "refills": self.refills}
 
 
 _device_cache: Optional[DeviceBlockCache] = None
@@ -314,7 +421,8 @@ def _fill(mesh, lead_shape: tuple, collect: Callable[[], list],
         return mesh_mod.shard_slices(mesh, block)
 
 
-def leaf_slab(mesh, key: tuple, frags, row_id: int) -> jax.Array:
+def leaf_slab(mesh, key: tuple, frags, row_id: int,
+              owner: Optional[tuple] = None) -> jax.Array:
     """Device-resident ``[bucket(n_slices), words]`` slab of one PQL
     leaf row across ``frags`` (one fragment per slice, None = absent =
     zero words; a list, or a callable that resolves it — only a miss
@@ -322,9 +430,10 @@ def leaf_slab(mesh, key: tuple, frags, row_id: int) -> jax.Array:
     in the budgeted HBM cache under ``key``.
 
     The caller owns the key contract (executor embeds the backing
-    view's (uid, generation), read BEFORE the fragments are resolved,
-    so writes/reopens age entries out of the LRU); ``_fill`` owns the
-    transfer."""
+    view's uid and the row's token, read BEFORE the fragments are
+    resolved, and passes ``owner`` = ``(view, row_id, token)``: a write
+    to the row or a reopen of the view drops the slab);
+    ``_fill`` owns the transfer."""
 
     def build(frags=frags):
         if callable(frags):
@@ -338,7 +447,7 @@ def leaf_slab(mesh, key: tuple, frags, row_id: int) -> jax.Array:
 
         return _fill(mesh, (n,), collect, row=row_id)
 
-    return device_cache().get_or_build(key, build)
+    return device_cache().get_or_build(key, build, owner)
 
 
 def candidate_block(mesh, key: tuple, frags,
@@ -377,8 +486,9 @@ class DeviceRowCache:
     """Per-fragment residency state: host packed-row LRU + the
     fragment's own (uid, generation) pair (the cluster generation map
     publishes it). ``owner`` is the fragment's View (None for a bare
-    library fragment): every bump here bumps the view's generation too
-    — the token the shared ``DeviceBlockCache``'s keys embed."""
+    library fragment): every invalidation here retires the written rows
+    of the view, or all of it (``View.retire_rows`` / ``retire_all``),
+    which moves the view's token too."""
 
     def __init__(self, max_rows: int = DEFAULT_MAX_ROWS):
         self.max_rows = max_rows
@@ -414,33 +524,49 @@ class DeviceRowCache:
             self._host_rows.popitem(last=False)
         return words
 
-    def bump(self) -> None:
-        """The fragment's resident picture may have changed. The data
-        changed BEFORE this call and the write is acknowledged AFTER
-        it: a reader that finds the token unmoved has seen the data."""
-        self.generation += 1
-        self.bump_owner()
+    # Every invalidation is called AFTER the data changed and BEFORE the
+    # write is acknowledged: a reader that finds a token unmoved has
+    # seen the data.
 
     def bump_owner(self) -> None:
-        """Move the view's token alone: the fragment's lifecycle
-        (opened, storage swapped by a snapshot) without a change of
-        its bits, which the cluster generation map must not see."""
+        """Move the view's token alone: the storage swapped by a
+        snapshot, no bit changed. Routes resolved before it are
+        resolved again; no slab is retired, and the cluster generation
+        map does not see it."""
         owner = self.owner
         if owner is not None:
             owner.bump()
 
+    def reopened(self) -> None:
+        """The fragment was (re)opened: whatever a reader resolved or
+        built against the view while it was closed is resolved and
+        built again. Its own generation stays (its bits are the file's,
+        as before)."""
+        owner = self.owner
+        if owner is not None:
+            owner.retire_all()
+
     def invalidate_row(self, row_id: int) -> None:
         self._host_rows.pop(row_id, None)
-        self.bump()
+        self.generation += 1
+        owner = self.owner
+        if owner is not None:
+            owner.retire_rows((row_id,))
 
     def invalidate_rows(self, row_ids) -> None:
         """Batch invalidation: one generation bump for the whole write
-        batch (the key embeds the generation, so one bump suffices)."""
+        batch, one retirement of its rows."""
         pop = self._host_rows.pop
         for rid in row_ids:
             pop(rid, None)
-        self.bump()
+        self.generation += 1
+        owner = self.owner
+        if owner is not None:
+            owner.retire_rows(row_ids)
 
     def invalidate_all(self) -> None:
         self._host_rows.clear()
-        self.bump()
+        self.generation += 1
+        owner = self.owner
+        if owner is not None:
+            owner.retire_all()

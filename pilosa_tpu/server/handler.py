@@ -1813,7 +1813,12 @@ class Handler:
                 # thread's current query so a wal.append failpoint hit
                 # during THIS query's barrier flags its context for
                 # the tail sampler (the barrier covers its records).
-                with ctx.stage("commit"), sched_context.use(ctx):
+                # The span says how many resident slabs the writes
+                # retired, where they retired any.
+                tags = {}
+                if ctx.cost is not None and ctx.cost.retired_slabs:
+                    tags["retired_slabs"] = ctx.cost.retired_slabs
+                with ctx.stage("commit", **tags), sched_context.use(ctx):
                     storage_wal.barrier_all()
         except HTTPError as e:  # 429 from _admit / 507 write-unready
             err = e

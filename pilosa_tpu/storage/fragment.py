@@ -260,7 +260,7 @@ class Fragment:
                     self.tier_state = "blob"
                     self.storage = None
                     self._open = True
-                    self.device.bump_owner()
+                    self.device.reopened()
                     return
             self._open_storage_quarantining(verify=True)
             if not self.quarantined and os.path.exists(
@@ -278,9 +278,9 @@ class Fragment:
                     site="open")
             self._open_cache()
             self._open = True
-            # (Re)opened: whatever a reader resolved against the view
-            # while this fragment was closed is resolved again.
-            self.device.bump_owner()
+            # (Re)opened: whatever a reader resolved or built against
+            # the view while this fragment was closed is done again.
+            self.device.reopened()
 
     def _open_storage_quarantining(self, verify: bool = False) -> None:
         """_open_storage, but a file whose bytes contradict their
@@ -305,6 +305,7 @@ class Fragment:
                 pass
             self._open_storage()
             self._set_quarantined(f"open: {e}", site="open")
+            self.device.invalidate_all()
 
     def _open_storage(self, verify: bool = False) -> None:
         # Open (creating) the data file, flock it, seed empty files with an
@@ -1124,9 +1125,11 @@ class Fragment:
         the fragment is visibly broken rather than quietly
         unlogged."""
         self._snapshot_n += 1
-        # The view token moves with every storage swap (bits unchanged,
-        # so the fragment's own generation stays): a route or a slab
-        # resolved before the swap is re-resolved after it.
+        # The view token moves with every storage swap: a route
+        # resolved before the swap is resolved again after it. The bits
+        # are unchanged, so the fragment's own generation stays and no
+        # resident slab is retired (a swap that lands corrupt replaces
+        # the bits and retires them: _open_storage_quarantining).
         self.device.bump_owner()
         if self._snapshot_n % _REMAP_EVERY == 0:
             self._close_storage()

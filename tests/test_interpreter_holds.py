@@ -793,8 +793,10 @@ class TestMetricFiles:
         assert spec["reader"] == reader and spec["what"]
         assert os.path.exists(os.path.join(
             ROOT, "cellbench", "readers", reader + ".py"))
+        # the cells it was accepted with, each still in the benchmark
+        # (a later cell is appended to the list by a benchmark change)
         cells = {w["name"] for w in bench["workloads"]}
-        assert set(entry["workloads"]) == cells
+        assert set(entry["workloads"]) <= cells
 
 
 # -- the profiler's clock -------------------------------------------------------

@@ -333,9 +333,10 @@ class TestFillsAreSingleFlight:
 
     def test_a_write_between_two_requests_is_a_new_key_and_a_new_build(
             self, tmp_path, cache):
-        """The key embeds the view's token, read before the fragments
-        are resolved: a request made after a write never waits for, or
-        is handed, a slab built under the token before it."""
+        """The key embeds the row's token, read before the fragments
+        are resolved: a request made after a write to the row never
+        waits for, or is handed, a slab built under the token before
+        it, and the write dropped that slab."""
         c = cache()
         n_slices = 32
         holder, cols_of = _holder(tmp_path, n_slices, 2, bits=8)
@@ -354,8 +355,9 @@ class TestFillsAreSingleFlight:
                     "i", f'SetBit(frame="f", rowID={row},'
                          f' columnID={col})') == [True]
             key1 = ex._leaf_cache_key(mesh, "i", leaf, slices)
-            assert key0 != key1 and c.contains(key0)
+            assert key0 != key1 and not c.contains(key0)
             assert not c.contains(key1)
+            assert c.retired_by_write == 2
             # the acknowledged write is visible to the next read, which
             # builds both slabs again under the new token
             assert ex.execute("i", _count([0, 1]), slices) == [want + 1]

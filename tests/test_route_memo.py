@@ -3,8 +3,8 @@ ownership, slice→node groups, each leaf view's fragment list — resolved
 once and reused until a token says it may have changed.
 
 The per-slice walk (``_slices_by_node``, ``_owns_all_slices``,
-``_leaf_frags``, the old per-fragment ``(uid, generation)`` key) stays
-in the code as the slow path; here it is the oracle. The memo must be
+``_leaf_frags``) stays in the code as the slow path; here it is the
+oracle, and what a leaf slab holds is the row's bits by that walk. The memo must be
 ENGAGED wherever a test says so: every such test reads
 ``Executor.route_memo`` and finds hits."""
 
@@ -93,13 +93,14 @@ def _oracle(holder, q, index="i"):
 
 
 def _walk_key(ex, index, leaf, slices):
-    """The residency key as it was before the view token: one
-    (uid, generation) pair a fragment, by the walk."""
+    """What a leaf slab over ``slices`` holds, by the walk: the row's
+    bits in each fragment of the set. Two lookups may share a slab only
+    where these are equal."""
     frame, view, row = leaf
     frags = [ex.holder.fragment(index, frame, view, s) for s in slices]
     return (index, frame, view, row, tuple(slices),
-            tuple((f.device.uid, f.device.generation) if f is not None
-                  else (0, 0) for f in frags))
+            tuple(tuple(f.row(row, check_cache=False).bits())
+                  if f is not None else () for f in frags))
 
 
 @pytest.fixture
@@ -160,20 +161,28 @@ class TestRecordEqualsWalk:
             assert all(a is b for a, b in zip(frags, walked))
             v = holder.view("i", frame, view)
             assert tok == (v.uid, v.generation)
-            key = ex._leaf_cache_key(ex._mesh_or_none(), "i",
-                                     (frame, view, 0), route["slices"])
-            assert key[-3:-1] == tok
-            assert ex._key_frags("i", frame, view, route["slices"],
-                                 key)() is frags
+            mesh = ex._mesh_or_none()
+            # a leaf slab is keyed on the view's uid and its row's token
+            key = ex._leaf_cache_key(mesh, "i", (frame, view, 0),
+                                     route["slices"])
+            assert key[-4:-1] == (v.uid, *v.row_token(0))
+            # a candidate block on the view's token, and its fragments
+            # are the record's
+            if view == VIEW_STANDARD:
+                rows_key = ex._topn_rows_key(mesh, "i", frame, (0, 1),
+                                             route["slices"])
+                assert rows_key[-3:-1] == tok
+                assert ex._key_frags("i", frame, view, route["slices"],
+                                     rows_key)() is frags
 
     def test_key_equality_classes(self, holder, n_slices):
-        """Two lookups share a slab under the new key exactly when they
-        did under the per-fragment key: however the same slice set
-        arrives it is one class; another set, another row, or a write
-        between them is another class."""
+        """Two lookups share a slab only where the row's bits in the set
+        are the same: however the same slice set arrives it is one
+        class; another set, another row, or a write to the row between
+        them is another class — and a write to another row is not."""
         ex, route = self._warm(holder, n_slices)
         mesh = ex._mesh_or_none()
-        leaf, other = ("f", VIEW_STANDARD, 0), ("f", VIEW_STANDARD, 1)
+        leaf, other = ("f", VIEW_STANDARD, 0), ("f", VIEW_STANDARD, 3)
         plain = list(range(n_slices))
         forms = {"routed": route["slices"], "plain": plain,
                  "tuple": tuple(plain)}
@@ -200,11 +209,17 @@ class TestRecordEqualsWalk:
             for new_b, old_b in seen[i + 1:]:
                 if old_a != old_b:      # the walk says: not the same slab
                     assert new_a != new_b
-                elif new_a != new_b:
-                    # Coarser only where a write fell OUTSIDE the slice
-                    # set (the view token is per view): never for a
-                    # whole-index set.
-                    assert old_a[4] != tuple(plain)
+                elif new_a != new_b and new_a[:7] == new_b[:7]:
+                    # Coarser only for the row that was written: where a
+                    # write to it fell OUTSIDE the slice set (its token
+                    # is per view), or a write undid one; never for an
+                    # unwritten row.
+                    assert old_a[3] == 3
+        # the row nobody wrote kept its key, in every form, throughout
+        per_look = len(seen) // 3
+        assert [k for k, _ in seen[:per_look] if k[5] == 0] \
+            == [k for k, _ in seen[per_look:2 * per_look] if k[5] == 0] \
+            == [k for k, _ in seen[2 * per_look:] if k[5] == 0]
         # one class for the whole index, whatever the form
         now = {ex._leaf_cache_key(mesh, "i", leaf, sl)
                for sl in (route["slices"], plain, tuple(plain))}
