@@ -394,6 +394,185 @@ extern "C" int64_t batch_remove(
     return n_changed;
 }
 
+// ---- residency fill: one slab's containers -> its upload payload -----------
+// The host half of a residency fill (ops/packed.py pack_slab) in ONE
+// crossing, so a fill lets go of the interpreter once. The slab is
+// n_rows slice-rows of 32768 u32 words, 16 container slots of 2048 words
+// each; `table` holds n_cont rows of four int64s, one a non-empty
+// container: (slot = slice-row * 16 + key, kind, buffer address, length)
+// with kind 0 = sorted u32 array (length = values), 1 = u64 bitmap
+// (length = words, 1024), 2 = wire-form u16 run buffer (length = u16s),
+// 3 = sorted u32 array whose values begin at element `address` of
+// `pool` (small arrays come concatenated, so that the caller takes no
+// address one by one). Nothing is read past a buffer's length or
+// written past `out`.
+//
+// The gate (max_slots > 0): G = the most set words in any 128-word group
+// of any slice-row, rounded up to a power of two. G <= max_slots writes
+// the bucketed payload the device densify kernel takes into the front of
+// `out` — lanes [n_rows * 256, G], then values of the same shape: a
+// group's set words in ascending lane order, value 0 = padding — and
+// returns G. Otherwise (or max_slots = 0) `out` becomes the dense block
+// [n_rows, 32768] and the call returns 0. `out` holds n_rows * 32768 u32
+// words, which both layouts fit (2 * 256 * G <= 32768 while G <= 64).
+
+namespace {
+
+const int64_t kSlotWords = 2048;                     // u32 words a slot
+const int64_t kLanes = 128;                          // words a group
+const int64_t kSlotGroups = kSlotWords / kLanes;     // 16 groups a slot
+
+// Set bits [lo, hi) of a slot's words.
+void set_bit_range(uint32_t* w, uint32_t lo, uint32_t hi) {
+    if (lo >= hi) return;
+    uint32_t first = lo >> 5, last = (hi - 1) >> 5;
+    uint32_t head = ~0u << (lo & 31), tail = ~0u >> (31 - ((hi - 1) & 31));
+    if (first == last) { w[first] |= head & tail; return; }
+    w[first] |= head;
+    for (uint32_t i = first + 1; i < last; i++) w[i] = ~0u;
+    w[last] |= tail;
+}
+
+// A bitmap or run container's words into `w` (2048 u32, zeroed).
+void slot_words(int64_t kind, const void* buf, int64_t len, uint32_t* w) {
+    if (kind == 1) {
+        memcpy(w, buf, (len < kWordsPerContainer ? len : kWordsPerContainer)
+                       * 8);
+        return;
+    }
+    const uint16_t* runs = (const uint16_t*)buf;
+    if (len < 1) return;
+    int64_t n_runs = runs[0];
+    if (n_runs > (len - 1) / 2) n_runs = (len - 1) / 2;
+    for (int64_t i = 0; i < n_runs; i++) {
+        uint32_t lo = runs[1 + 2 * i];
+        uint32_t hi = lo + (uint32_t)runs[2 + 2 * i] + 1;
+        set_bit_range(w, lo, hi < 65536 ? hi : 65536);
+    }
+}
+
+// The most set words in any group of a sorted array container.
+int64_t array_fullest(const uint32_t* a, int64_t n) {
+    int64_t fullest = 0, count = 0;
+    uint32_t group = ~0u, word = ~0u;
+    for (int64_t i = 0; i < n; i++) {
+        uint32_t v = a[i];
+        if (v >> 12 != group) { group = v >> 12; count = 0; }
+        if (v >> 5 != word) {
+            word = v >> 5;
+            if (++count > fullest) fullest = count;
+        }
+    }
+    return fullest;
+}
+
+int64_t words_fullest(const uint32_t* w) {
+    int64_t fullest = 0;
+    for (int64_t g = 0; g < kSlotGroups; g++) {
+        int64_t count = 0;
+        for (int64_t i = 0; i < kLanes; i++) count += w[g * kLanes + i] != 0;
+        if (count > fullest) fullest = count;
+    }
+    return fullest;
+}
+
+// A table row's kind (a pooled array is an array) and its buffer.
+int64_t entry_kind(const int64_t* e) { return e[1] == 3 ? 0 : e[1]; }
+
+const void* entry_buf(const int64_t* e, const uint32_t* pool) {
+    return e[1] == 3 ? (const void*)(pool + e[2]) : (const void*)e[2];
+}
+
+}  // namespace
+
+int64_t pack_slab_u32(int64_t n_cont, const int64_t* table, int64_t n_rows,
+                      int64_t max_slots, const uint32_t* pool,
+                      uint32_t* out) {
+    const int64_t n_slots = n_rows * (32768 / kSlotWords);
+    uint32_t scratch[kSlotWords];
+    int64_t g_pad = 0;
+    if (max_slots > 64) max_slots = 64;
+    if (max_slots > 0) {
+        int64_t fullest = 1;
+        for (int64_t c = 0; c < n_cont && fullest <= max_slots; c++) {
+            const int64_t* e = table + 4 * c;
+            if (e[0] < 0 || e[0] >= n_slots) continue;
+            int64_t f, kind = entry_kind(e);
+            const void* buf = entry_buf(e, pool);
+            if (kind == 0) {
+                f = array_fullest((const uint32_t*)buf, e[3]);
+            } else {
+                memset(scratch, 0, sizeof scratch);
+                slot_words(kind, buf, e[3], scratch);
+                f = words_fullest(scratch);
+            }
+            if (f > fullest) fullest = f;
+        }
+        g_pad = 1;
+        while (g_pad < fullest) g_pad <<= 1;
+        if (g_pad > max_slots) g_pad = 0;
+    }
+    if (g_pad == 0) {
+        memset(out, 0, n_rows * 32768 * 4);
+        for (int64_t c = 0; c < n_cont; c++) {
+            const int64_t* e = table + 4 * c;
+            if (e[0] < 0 || e[0] >= n_slots) continue;
+            uint32_t* w = out + e[0] * kSlotWords;
+            int64_t kind = entry_kind(e);
+            if (kind != 0) {
+                slot_words(kind, entry_buf(e, pool), e[3], w);
+                continue;
+            }
+            const uint32_t* a = (const uint32_t*)entry_buf(e, pool);
+            for (int64_t i = 0; i < e[3]; i++) {
+                uint32_t v = a[i];
+                if (v < 65536) w[v >> 5] |= 1u << (v & 31);
+            }
+        }
+        return 0;
+    }
+    const int64_t n_groups = n_slots * kSlotGroups;
+    uint32_t* lanes = out;
+    uint32_t* vals = out + n_groups * g_pad;
+    memset(out, 0, 2 * n_groups * g_pad * 4);
+    for (int64_t c = 0; c < n_cont; c++) {
+        const int64_t* e = table + 4 * c;
+        if (e[0] < 0 || e[0] >= n_slots) continue;
+        int64_t group0 = e[0] * kSlotGroups, kind = entry_kind(e);
+        if (kind == 0) {
+            // Sorted values: each set word is complete when the next
+            // value leaves it, and comes in ascending lane order.
+            const uint32_t* a = (const uint32_t*)entry_buf(e, pool);
+            int64_t k = 0;
+            uint32_t group = ~0u, word = ~0u, bits = 0;
+            for (int64_t i = 0; i <= e[3]; i++) {
+                uint32_t v = i < e[3] ? a[i] : ~0u;
+                if (v >> 5 == word) { bits |= 1u << (v & 31); continue; }
+                if (bits && k < g_pad) {
+                    int64_t at = (group0 + group) * g_pad + k++;
+                    lanes[at] = word & (kLanes - 1);
+                    vals[at] = bits;
+                }
+                if (v >= 65536) break;
+                if (v >> 12 != group) { group = v >> 12; k = 0; }
+                word = v >> 5;
+                bits = 1u << (v & 31);
+            }
+            continue;
+        }
+        memset(scratch, 0, sizeof scratch);
+        slot_words(kind, entry_buf(e, pool), e[3], scratch);
+        for (int64_t g = 0; g < kSlotGroups; g++) {
+            int64_t base = (group0 + g) * g_pad, k = 0;
+            for (int64_t i = 0; i < kLanes && k < g_pad; i++) {
+                uint32_t v = scratch[g * kLanes + i];
+                if (v) { lanes[base + k] = (uint32_t)i; vals[base + k++] = v; }
+            }
+        }
+    }
+    return g_pad;
+}
+
 }  // extern "C"
 
 // ---- native snapshot writer -------------------------------------------------

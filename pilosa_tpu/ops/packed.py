@@ -22,6 +22,7 @@ from __future__ import annotations
 import numpy as np
 
 from .. import SLICE_WIDTH
+from ..storage import native
 from ..storage.roaring import Bitmap, runs_to_words
 
 WORD_BITS = 32
@@ -92,7 +93,6 @@ def pack_rows(storage: Bitmap, row_ids) -> np.ndarray:
 
 def unpack_words(words: np.ndarray) -> np.ndarray:
     """Dense u32 word vector → sorted u64 bit positions (host)."""
-    from ..storage import native
     return native.unpack_words(np.ascontiguousarray(words))
 
 
@@ -184,11 +184,75 @@ def pack_slab(rows: list, sparse: bool = True
       ``_MAX_SLOTS``.
     - ``block``: dense ``[T, 32768]`` u32 otherwise.
 
-    The numpy calls do not grow with the slab: array containers are
-    concatenated and grouped by word once, bitmap and run containers
-    are stacked once as the u32 words they are (a dense block takes
-    them by one assignment, never through pairs), one bincount finds
-    the ranks, one scatter fills the buckets."""
+    Where the native library loaded (``storage.native.available()``) the
+    pass is one call into it, which lets go of the interpreter once: the
+    walk here only lists each container's slot, kind and buffer. The
+    numpy pass answers otherwise, array for array the same."""
+    if native.available():
+        return _pack_slab_native(rows, sparse)
+    return _pack_slab_numpy(rows, sparse)
+
+
+# The element type of a container buffer, by the kind the native pack's
+# table gives it: 0 array, 1 bitmap, 2 run.
+_KIND_DTYPES = (np.dtype(np.uint32), np.dtype(np.uint64),
+                np.dtype(np.uint16))
+# Array containers of at most this many values reach the native pack
+# concatenated (kind 3), in one numpy call that keeps the interpreter
+# (numpy lets go of it only to copy an input of more than 500 values):
+# taking a buffer's address costs more than copying that many.
+_POOLED = 500
+
+
+def _pack_slab_native(rows: list, sparse: bool) -> tuple:
+    per = WORDS_PER_SLICE // _WORDS_PER_CONTAINER
+    table, bufs, pooled, pool_n = [], [], [], 0
+    for t, row in enumerate(rows):
+        if row is None:
+            continue
+        slot0 = t * per
+        for key, c in zip(row.keys, row.containers):
+            if not c.n:
+                continue
+            if c.bitmap is not None:
+                kind, buf = 1, c.bitmap
+            elif c.runs is not None:
+                kind, buf = 2, c.runs
+            else:
+                kind, buf = 0, c.array
+                if len(buf) <= _POOLED:
+                    pooled.append(buf)
+                    table += (slot0 + key, 3, pool_n, len(buf))
+                    pool_n += len(buf)
+                    continue
+            if buf.dtype is not _KIND_DTYPES[kind] or \
+                    not buf.flags.c_contiguous:
+                buf = np.ascontiguousarray(buf, dtype=_KIND_DTYPES[kind])
+            # The list, not the address, keeps a mapped buffer (and with
+            # it the fragment's map) alive until the call has returned.
+            bufs.append(buf)
+            table += (slot0 + key, kind, buf.ctypes.data, buf.size)
+    pool = (np.concatenate(pooled, dtype=np.uint32)
+            if pooled else np.empty(0, np.uint32))
+    out = np.empty((len(rows), WORDS_PER_SLICE), dtype=np.uint32)
+    g_pad = native.pack_slab(np.array(table, dtype=np.int64).reshape(-1, 4),
+                             pool, out, _MAX_SLOTS if sparse else 0)
+    taken = len(bufs) + len(pooled)
+    if not g_pad:
+        return None, out, taken
+    m = len(rows) * (WORDS_PER_SLICE // _DENSIFY_LANES) * g_pad
+    flat, shape = out.reshape(-1), (len(rows), -1, g_pad)
+    return (flat[:m].reshape(shape), flat[m:2 * m].reshape(shape)), None, \
+        taken
+
+
+def _pack_slab_numpy(rows: list, sparse: bool) -> tuple:
+    """``pack_slab`` without the native library. The numpy calls do not
+    grow with the slab: array containers are concatenated and grouped
+    by word once, bitmap and run containers are stacked once as the u32
+    words they are (a dense block takes them by one assignment, never
+    through pairs), one bincount finds the ranks, one scatter fills the
+    buckets."""
     per = _WORDS_PER_CONTAINER
     arrays, array_slots, dense, dense_slots = [], [], [], []
     for t, row in enumerate(rows):

@@ -59,6 +59,7 @@ import numpy as np
 from ..obs import accounting
 from ..ops import packed
 from ..sched import context as sched_context
+from ..storage import native
 
 # Default packed-row budget per fragment (256 rows × 128 KB = 32 MB
 # host-side).
@@ -73,6 +74,14 @@ _uid_counter = itertools.count(1)
 # Rows whose resident slab a write retired and no fill has built again
 # yet (what ``refills`` counts), at most this many, oldest forgotten.
 _REFILL_WATCH = 4096
+
+# Fills that pack and upload at once. Their host work runs on the one
+# interpreter every request shares, in stretches as long as a read's,
+# so each fill in flight lengthens every resident read's turns at it.
+# Two keep one fill's Python on the interpreter while another's native
+# pack, transfer and densify run off it; a fill past them waits (stage
+# ``fill_slot``) off the interpreter instead of queueing beside reads.
+_FILL_SLOTS = threading.BoundedSemaphore(2)
 
 
 class _Fill:
@@ -111,12 +120,14 @@ class DeviceBlockCache:
         # seconds (summed; builds of different keys overlap), the bytes
         # of the slabs built (at their uploaded, bucket-padded shape)
         # and the builds whose transfer was the host-dense pack +
-        # device_put (the others densified on the device).
+        # device_put (the others densified on the device); the builds
+        # whose pack was one call into the native library.
         self.fills = 0
         self.fill_waits = 0
         self.fill_seconds = 0.0
         self.fill_bytes = 0
         self.fills_dense = 0
+        self.packs_native = 0
         # The slabs one row of one view owns: view uid -> row id ->
         # their keys, and each such key's (uid, row), so that a write
         # drops its row's entries and an eviction forgets its key.
@@ -183,6 +194,7 @@ class DeviceBlockCache:
             mine.error = e
             raise
         finally:
+            evicted: list = []
             with self._mu:
                 del self._filling[key]
                 self.fills += 1
@@ -190,20 +202,25 @@ class DeviceBlockCache:
                 if mine.error is None:
                     self.fill_bytes += self._nbytes(mine.arr)
                     if owner is None:
-                        self._insert(key, mine.arr)
+                        self._insert(key, mine.arr, evicted)
                     else:
                         view, row_id, token = owner
                         if view.row_token(row_id) == token:
-                            self._insert(key, mine.arr, (view.uid, row_id))
+                            self._insert(key, mine.arr, evicted,
+                                         (view.uid, row_id))
             mine.done.set()
+            del evicted     # freed out of the lock (see _insert)
         accounting.note_cold_leaf()
         return mine.arr
 
-    def _insert(self, key: tuple, arr,
+    def _insert(self, key: tuple, arr, evicted: list,
                 tag: Optional[tuple[int, int]] = None) -> None:
         """Keep a built array (lock held), unless it is bigger than the
         whole working set: that one is a one-shot. ``tag`` = the
-        ``(view uid, row)`` that owns it."""
+        ``(view uid, row)`` that owns it. The arrays it evicts go to
+        ``evicted``, for the caller to let go of once the lock is
+        released: freeing a device buffer lets go of the interpreter, and
+        every read's ``lookup`` would queue on the lock behind it."""
         nbytes = self._nbytes(arr)
         if nbytes > self.budget_bytes:
             return
@@ -221,6 +238,7 @@ class DeviceBlockCache:
         # len > 1 keeps the just-built entry (now most-recent) alive.
         while self.used_bytes > self.budget_bytes and len(self._lru) > 1:
             old_key, old = self._lru.popitem(last=False)
+            evicted.append(old)
             self.used_bytes -= self._nbytes(old)
             self.evictions += 1
             tag = self._owner_of.pop(old_key, None)
@@ -233,12 +251,15 @@ class DeviceBlockCache:
                     if not rows:
                         del self._owned[tag[0]]
 
-    def _drop(self, keys) -> int:
-        """Take ``keys`` out of the LRU (lock held); their bytes."""
+    def _drop(self, keys, dropped: list) -> int:
+        """Take ``keys`` out of the LRU (lock held) into ``dropped``, to
+        be let go of out of the lock (``_insert``); their bytes."""
         nbytes = 0
         for k in keys:
             del self._owner_of[k]
-            nbytes += self._nbytes(self._lru.pop(k))
+            arr = self._lru.pop(k)
+            dropped.append(arr)
+            nbytes += self._nbytes(arr)
         self.used_bytes -= nbytes
         return nbytes
 
@@ -247,7 +268,7 @@ class DeviceBlockCache:
         and the view's row tokens have moved: drop those rows' slabs
         (their keys can no longer be built). Returns how many; the
         request that wrote is told (``X-Pilosa-Stats.retiredSlabs``)."""
-        n = 0
+        n, dropped = 0, []
         with self._mu:
             rows = self._owned.get(uid)
             if rows:
@@ -256,7 +277,8 @@ class DeviceBlockCache:
                     keys = rows.pop(r, None)
                     if keys:
                         n += len(keys)
-                        self.retired_by_write_bytes += self._drop(keys)
+                        self.retired_by_write_bytes += self._drop(
+                            keys, dropped)
                         due[(uid, r)] = None
                         due.move_to_end((uid, r))
                 if not rows:
@@ -264,6 +286,7 @@ class DeviceBlockCache:
                 while len(due) > _REFILL_WATCH:
                     due.popitem(last=False)
                 self.retired_by_write += n
+        del dropped
         if n:
             accounting.note_retired_slabs(n)
         return n
@@ -271,6 +294,7 @@ class DeviceBlockCache:
     def retire_view(self, uid: int) -> int:
         """Every row of view ``uid`` is retired (an import, a reopen, a
         corrupt file replaced): drop all of its slabs."""
+        dropped: list = []
         with self._mu:
             rows = self._owned.pop(uid, None)
             if not rows:
@@ -278,14 +302,17 @@ class DeviceBlockCache:
             n = 0
             for keys in rows.values():
                 n += len(keys)
-                self._drop(keys)
+                self._drop(keys, dropped)
             self.retired_all += n
-            return n
+        del dropped
+        return n
 
-    def note_dense_fill(self) -> None:
-        """A builder's word that its fill ships a host-dense block."""
+    def note_pack(self, dense: bool, on_native: bool) -> None:
+        """The filling thread's word on its pack: whether it ships a
+        host-dense block, and whether the native library packed it."""
         with self._mu:
-            self.fills_dense += 1
+            self.fills_dense += dense
+            self.packs_native += on_native
 
     def contains(self, key: tuple) -> bool:
         """Residency probe WITHOUT touching LRU order — the routing
@@ -349,6 +376,7 @@ class DeviceBlockCache:
                     "fillSeconds": round(self.fill_seconds, 6),
                     "fillBytes": self.fill_bytes,
                     "fillsDense": self.fills_dense,
+                    "packsNative": self.packs_native,
                     "retired": {"byWrite": self.retired_by_write,
                                 "byWriteBytes": self.retired_by_write_bytes,
                                 "all": self.retired_all},
@@ -399,26 +427,35 @@ def _fill(mesh, lead_shape: tuple, collect: Callable[[], list],
     both stages' spans carry ``tags`` (which rows), ``slices`` (the
     padded slice count), ``bytes`` (what the host hands the device) and
     ``path``: ``sparse`` or ``dense``; ``pack``'s also ``containers``,
-    how many the pass took."""
+    how many the pass took, and ``native``, whether the pass was one
+    call into the native library (``storage.native``)."""
     from . import mesh as mesh_mod
     mode = mesh_mod.densify_mode()
     tags["slices"] = lead_shape[0]
-    with sched_context.stage("pack", **tags) as pack:
-        sparse, block, taken = packed.pack_slab(
-            collect(), sparse=mode is not None)
-        if sparse is None:
-            block = block.reshape(lead_shape + block.shape[1:])
-            device_cache().note_dense_fill()
-            tags.update(bytes=block.nbytes, path="dense")
-        else:
-            sparse = [a.reshape(lead_shape + a.shape[1:]) for a in sparse]
-            tags.update(bytes=sum(a.nbytes for a in sparse), path="sparse")
-        pack.tag(containers=taken, **tags)
-    with sched_context.stage("upload", **tags):
-        if sparse is not None:
-            return mesh_mod.densify_sharded(
-                mesh, *sparse, interpret=(mode == "interpret"))
-        return mesh_mod.shard_slices(mesh, block)
+    with sched_context.stage("fill_slot"):
+        _FILL_SLOTS.acquire()
+    try:
+        with sched_context.stage("pack", **tags) as pack:
+            on_native = native.available()
+            sparse, block, taken = packed.pack_slab(
+                collect(), sparse=mode is not None)
+            if sparse is None:
+                block = block.reshape(lead_shape + block.shape[1:])
+                tags.update(bytes=block.nbytes, path="dense")
+            else:
+                sparse = [a.reshape(lead_shape + a.shape[1:])
+                          for a in sparse]
+                tags.update(bytes=sum(a.nbytes for a in sparse),
+                            path="sparse")
+            device_cache().note_pack(sparse is None, on_native)
+            pack.tag(containers=taken, native=on_native, **tags)
+        with sched_context.stage("upload", **tags):
+            if sparse is not None:
+                return mesh_mod.densify_sharded(
+                    mesh, *sparse, interpret=(mode == "interpret"))
+            return mesh_mod.shard_slices(mesh, block)
+    finally:
+        _FILL_SLOTS.release()
 
 
 def leaf_slab(mesh, key: tuple, frags, row_id: int,

@@ -7,6 +7,12 @@ is built on first use with g++ and cached under ``utils.cache_dir()``; if the
 build fails every entry point falls back to vectorized numpy, the failure is
 logged as an error and ``available()`` (the ``/status`` ``build.native`` flag)
 says so.
+
+The bulk entry points take a whole table of containers in one crossing, so
+the interpreter is let go of once a call: ``batch_add`` / ``batch_remove``
+(a write batch), ``write_snapshot_fd`` (a snapshot),
+``bitmap_intersection_count`` (a host count) and ``pack_slab`` (the pack
+of a residency fill, ops.packed).
 """
 
 from __future__ import annotations
@@ -119,6 +125,8 @@ def _declare(lib):
     lib.bitmap_intersection_count.restype = i64
     lib.parse_csv_u64_pairs.argtypes = [ctypes.c_char_p, i64, vp, vp, i64]
     lib.parse_csv_u64_pairs.restype = i64
+    lib.pack_slab_u32.argtypes = [i64, vp, i64, i64, vp, vp]
+    lib.pack_slab_u32.restype = i64
 
 
 def _ptr(a: np.ndarray) -> int:
@@ -350,6 +358,34 @@ def bitmap_intersection_count(keys_a, types_a, ptrs_a, ns_a,
         len(keys_a), _ptr(keys_a), _ptr(types_a), _ptr(ptrs_a),
         _ptr(ns_a), len(keys_b), _ptr(keys_b), _ptr(types_b),
         _ptr(ptrs_b), _ptr(ns_b)))
+
+
+def pack_slab(table: np.ndarray, pool: np.ndarray, out: np.ndarray,
+              max_slots: int) -> int:
+    """A residency fill's whole pack in ONE crossing (bitops.cpp
+    pack_slab_u32). ``table`` is C-contiguous int64 ``[n, 4]``, a row a
+    non-empty container: (slab slot, kind 0 / 1 / 2 = array / bitmap /
+    run, buffer address, length in the buffer's own elements), or kind
+    3, an array whose values begin at that element of ``pool`` (u32);
+    the caller keeps the buffers alive until this returns. ``out`` is
+    u32 ``[n_rows, 32768]``. Returns G > 0 where the gate (``max_slots``
+    > 0) passed: ``out`` begins with the lanes, then the values, each
+    ``[n_rows * 256, G]``; 0 where ``out`` is the dense block. Raises if
+    the native library is unavailable (ops.packed keeps the numpy
+    pass)."""
+    lib = _load()
+    if lib is None:
+        raise RuntimeError("native library unavailable")
+    if (out.dtype != _U32 or not out.flags.c_contiguous or out.ndim != 2
+            or out.shape[1] != 32768):
+        raise ValueError("pack_slab: out must be C-contiguous u32[n, 32768]")
+    if table.dtype != np.int64 or table.shape[1:] != (4,) or \
+            not table.flags.c_contiguous:
+        raise ValueError("pack_slab: table must be C-contiguous int64[n, 4]")
+    if pool.dtype != _U32 or not pool.flags.c_contiguous:
+        raise ValueError("pack_slab: pool must be C-contiguous u32")
+    return int(lib.pack_slab_u32(len(table), _ptr(table), out.shape[0],
+                                 max_slots, _ptr(pool), _ptr(out)))
 
 
 def bench_setbit(path: str, positions: np.ndarray,

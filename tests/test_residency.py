@@ -18,6 +18,25 @@ def _arr(n_bytes: int):
     return jax.device_put(np.zeros(n_bytes // 4, dtype=np.uint32))
 
 
+class _Slab:
+    """A 1 KiB slab that notes, when it is freed, whether its cache's
+    lock was held."""
+    shape, dtype = (256,), np.dtype(np.uint32)
+
+    def __init__(self, cache, log):
+        self.cache, self.log = cache, log
+
+    def __del__(self):
+        self.log.append(self.cache._mu.locked())
+
+
+class _View:
+    uid = 7
+
+    def row_token(self, row_id):
+        return (0, row_id)
+
+
 class TestDeviceBlockCache:
     def test_hit_returns_same_array(self):
         c = DeviceBlockCache(budget_bytes=1 << 20)
@@ -44,6 +63,51 @@ class TestDeviceBlockCache:
         c.get_or_build(("big",), lambda: _arr(4096))
         assert c.used_bytes == 512  # big stayed one-shot
         c.get_or_build(("small",), lambda: pytest.fail("small evicted"))
+
+    def test_slabs_are_freed_out_of_the_lock(self):
+        """Freeing a device buffer lets go of the interpreter: an evicted
+        or retired slab is let go of after the cache's lock is released,
+        or every read's lookup would queue behind the free."""
+        freed, view = [], _View()
+        c = DeviceBlockCache(budget_bytes=2048)
+        for r in range(4):
+            c.get_or_build(("s", r), lambda: _Slab(c, freed),
+                           (view, r, view.row_token(r)))
+        assert c.evictions == 2 and freed == [False, False]
+        assert c.retire_rows(view.uid, [2]) == 1
+        assert c.retire_view(view.uid) == 1
+        assert freed == [False] * 4 and c.used_bytes == 0
+
+    def test_at_most_two_fills_pack_and_upload_at_once(self, monkeypatch):
+        """A fill past the two slots waits for one (stage ``fill_slot``)
+        before it packs, and every fill still answers."""
+        import threading
+        import time
+
+        from pilosa_tpu.parallel import mesh as mesh_mod
+        monkeypatch.setenv("PILOSA_TPU_SPARSE_UPLOAD", "0")
+        mesh = mesh_mod.make_mesh(1)
+        mu, inside, most, got = threading.Lock(), [0], [0], []
+
+        def collect():
+            with mu:
+                inside[0] += 1
+                most[0] = max(most[0], inside[0])
+            time.sleep(0.05)
+            with mu:
+                inside[0] -= 1
+            return [None]
+
+        def fill():
+            got.append(residency._fill(mesh, (1,), collect, row=0))
+
+        threads = [threading.Thread(target=fill) for _ in range(5)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(30)
+        assert most[0] == 2 and len(got) == 5
+        assert all(int(np.asarray(a).sum()) == 0 for a in got)
 
     def test_snapshot(self):
         c = DeviceBlockCache(budget_bytes=1 << 20)
