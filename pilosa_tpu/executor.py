@@ -368,6 +368,11 @@ class Executor:
         # ``node`` pool (pooled). No lock: two threads may lose a
         # count between them, and a reader takes shares of these.
         self.legs = {"inline": 0, "pooled": 0}
+        # /debug/vars.topn: with-source TopN reads by where their
+        # counts came from (one device program, or the host walk), and
+        # the candidate rows their first phase gave, summed. No lock,
+        # like ``legs``.
+        self.topn_src = {"srcDevice": 0, "srcHost": 0, "candidates": 0}
         # Per-fingerprint plan store behind GET /debug/plans (the
         # handler records finished coordinator queries into it).
         self.plan_store = plan_store.PlanStore()
@@ -2765,6 +2770,16 @@ class Executor:
             # partials for the two-phase merge.
             return self._topn_exact_partial(index, c, slices, opt)
 
+        with_src = not opt.remote and not row_ids and len(c.children) == 1
+        if with_src:
+            dev = self._topn_src_device(index, c, slices)
+            obs_accounting.note_topn_src("device" if dev is not None
+                                         else "host")
+            if dev is not None:
+                self.topn_src["srcDevice"] += 1
+                return dev
+            self.topn_src["srcHost"] += 1
+
         fast = self._topn_host_single_pass(index, c, slices, opt)
         if fast is not None:
             return fast
@@ -2775,6 +2790,8 @@ class Executor:
                 return dist
 
         pairs = self._top_n_slices(index, c, slices, opt)
+        if with_src:
+            self.topn_src["candidates"] += len(pairs)
         # Only the originating node refetches exact counts for candidates.
         if not pairs or row_ids or opt.remote:
             return pairs
@@ -2787,6 +2804,107 @@ class Executor:
         if n and n < len(trimmed):
             trimmed = trimmed[:n]
         return trimmed
+
+    def _topn_src_device(self, index: str, c: Call,
+                         slices: list[int]) -> Optional[list[Pair]]:
+        """The with-source TopN — ``TopN(<bitmap>, frame=f, n=k)`` — with
+        both phases (executor.go:271-396) read off ONE device program,
+        or None for today's paths. The frame's standard view is held
+        on the device sized to its bits (residency.frame_positions);
+        the program gives every row's source-intersection count in
+        every slice (mesh.topn_src_counts). Phase 1 is each slice's
+        walk of its rank cache fed those counts in place of the host
+        count map (Fragment.top_src: the superset rule, the threshold,
+        the cache's trim and tie order as ``Fragment.top`` has them);
+        phase 2's exact count of a candidate is the sum of the same
+        counts over the slices: no second dispatch, no candidate
+        block. The plain form only (n > 0, threshold ≤ 1, no Tanimoto,
+        no attribute filter) on a node that owns every slice, off a
+        pod; a frame form larger than half the residency budget leaves
+        the read to today's paths."""
+        if (not self.use_mesh or self.pod is not None
+                or self._mesh_backoff_active()):
+            return None
+        (frame_name, n, field, _ids, min_threshold, filters,
+         tanimoto) = self._topn_args(c)
+        if (not n or (field and filters) or tanimoto > 0
+                or min_threshold > 1
+                or len(slices) < self.mesh_min_slices
+                or not self._owns_all_slices(index, slices)):
+            return None
+        view = self.holder.view(index, frame_name, VIEW_STANDARD)
+        if view is None:
+            return None
+        mesh = self._mesh_or_none()
+        if mesh is None:
+            return None
+        from .ops.packed import WORDS_PER_SLICE
+        from .parallel import mesh as mesh_mod
+        from .parallel import residency
+        if len(slices) > mesh_mod.slice_chunk_bound(
+                mesh.shape[mesh_mod.AXIS_SLICES]):
+            return None
+        leaves: list[tuple] = []
+        expr = self._compile_device_expr(index, c.children[0], leaves)
+        if expr is None:
+            return None
+        with sched_context.stage("route"):
+            token = view.frame_token()      # before anything is resolved
+            key = ("topnframe", id(self.holder), index, frame_name,
+                   self._slices_key(slices), view.uid, token,
+                   mesh.shape[mesh_mod.AXIS_SLICES])
+            frags = self._leaf_frags(index, frame_name, VIEW_STANDARD,
+                                     slices)
+            if any(f is not None and not hasattr(f.cache, "top_arrays")
+                   for f in frags):
+                return None
+            slice_bits = [0 if f is None else f._cached_total_bits()
+                          for f in frags]
+            bits = sum(slice_bits)
+            n_rows = 1 + max((f.max_row_id() for f in frags
+                              if f is not None), default=0)
+            form_bytes = residency.frame_form_bytes(mesh, slice_bits,
+                                                    n_rows)
+            if form_bytes > self._topn_resident_bytes():
+                return None
+            keys, found, _cold = self._leaf_lookup(mesh, index, leaves,
+                                                   slices)
+            row_bytes = len(slices) * WORDS_PER_SLICE * 4
+            # The host walk reads every set bit as a u64 position per
+            # source (Fragment._host_src_count_map); the device reads
+            # the form once. Both are kept once built: no fill priced.
+            if not self._device_pays(
+                    mesh, -(-form_bytes // row_bytes) + len(leaves),
+                    len(slices), host_rows=-(-8 * bits // row_bytes)):
+                return None
+        try:
+            arrs = self._leaf_device_arrays(mesh, index, leaves, slices,
+                                            (keys, found))
+            form = residency.frame_positions(
+                mesh, key, frags, n_rows,
+                owner=(view, None, token))
+            with sched_context.span(
+                    "topn_src_counts", rows=form[0].shape[1] - 1,
+                    slices=len(slices), positions=bits,
+                    bytes=form[0].nbytes + form[1].nbytes):
+                counts = mesh_mod.topn_src_counts(mesh, expr, form, arrs)
+        except Exception as e:  # noqa: BLE001 - device trouble ≠ node down
+            self._note_device_fallback("topn_src_counts", e)
+            return None
+        with sched_context.stage("topn_replay"):
+            parts = []
+            for si, frag in enumerate(frags):
+                if frag is not None:
+                    ids, _ = frag.top_src(counts[si], n, min_threshold)
+                    parts.append(ids)
+            cand = (np.unique(np.concatenate(parts)) if parts
+                    else np.empty(0, dtype=np.int64))
+            self.topn_src["candidates"] += len(cand)
+            sums = counts[:len(slices), cand].sum(axis=0, dtype=np.int64)
+            order = np.lexsort((cand, -sums))[:n]
+            return [Pair(i, cnt) for i, cnt in
+                    zip(cand[order].tolist(), sums[order].tolist())
+                    if cnt > 0]
 
     def _topn_device_topk(self, index: str, c: Call,
                           slices: list[int],

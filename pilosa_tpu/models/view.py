@@ -82,6 +82,10 @@ class View:
         # reopen) and of the last write to the row.
         self.epoch = 0
         self.row_epochs: dict[int, int] = {}
+        # The generation of the last write to any row: with ``epoch``,
+        # what a whole-frame device form is keyed on (``frame_token``)
+        # — a snapshot moves ``generation`` and changes no bit.
+        self.last_row_write = 0
 
     def bump(self) -> None:
         """Move the token. Writers call this AFTER changing the data
@@ -97,13 +101,19 @@ class View:
         (the executor reads the two fields inline)."""
         return (self.epoch, self.row_epochs.get(row_id, 0))
 
+    def frame_token(self) -> tuple[int, int]:
+        """What the view's whole-frame device form is keyed on beside
+        the uid: moved by every write to any row and every retirement
+        of the whole view, not by a snapshot."""
+        return (self.epoch, self.last_row_write)
+
     def retire_rows(self, row_ids) -> int:
         """A write changed bits of ``row_ids``: move the token and those
         rows' tokens, then drop their resident slabs (the others stay).
         Same order as ``bump``: after the data, before the ack."""
         with self._token_mu:
             self.generation += 1
-            gen = self.generation
+            gen = self.last_row_write = self.generation
             epochs = self.row_epochs
             for r in row_ids:
                 epochs[r] = gen

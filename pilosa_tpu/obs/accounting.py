@@ -78,7 +78,7 @@ class QueryCost:
     __slots__ = ("node", "container_ops", "words_scanned",
                  "bits_written", "device_programs", "device_bytes",
                  "mesh_devices", "cold_leaves", "fill_waits",
-                 "retired_slabs", "compile_s",
+                 "retired_slabs", "topn_src", "compile_s",
                  "wal_wait_s", "result_cache_hits", "rpc", "children",
                  "_mu")
 
@@ -102,6 +102,9 @@ class QueryCost:
         # Resident slabs this query's writes took out of the device
         # cache (a write retires its own row's slabs).
         self.retired_slabs = 0
+        # Where a with-source TopN's counts came from: "device" (one
+        # program over the resident frame form) or "host"; "" for none.
+        self.topn_src = ""
         self.compile_s = 0.0
         # Seconds this query's threads spent blocked in WAL group
         # commit (waiting for a leader's flush to cover their records)
@@ -202,6 +205,8 @@ class QueryCost:
             out["fillWaits"] = self.fill_waits
         if self.retired_slabs:
             out["retiredSlabs"] = self.retired_slabs
+        if self.topn_src:
+            out["topnSrc"] = self.topn_src
         if self.wal_wait_s:
             out["walWaitMs"] = round(self.wal_wait_s * 1e3, 3)
         if self.result_cache_hits:
@@ -239,6 +244,8 @@ class QueryCost:
             out["fillWaits"] = self.fill_waits
         if self.retired_slabs:
             out["retiredSlabs"] = self.retired_slabs
+        if self.topn_src:
+            out["topnSrc"] = self.topn_src
         if self.wal_wait_s:
             out["walWaitMs"] = round(self.wal_wait_s * 1e3, 3)
         if self.result_cache_hits:
@@ -350,6 +357,12 @@ def note_retired_slabs(n: int) -> None:
     cost = current_cost()
     if cost is not None:
         cost.note_retired_slabs(n)
+
+
+def note_topn_src(where: str) -> None:
+    cost = current_cost()
+    if cost is not None:
+        cost.topn_src = where
 
 
 def note_compile(seconds: float) -> None:

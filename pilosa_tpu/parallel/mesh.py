@@ -863,6 +863,22 @@ def topn_topk_sharded(mesh: Mesh, expr, rows: jax.Array,
     return counts, out[2].tolist()
 
 
+@_fair_dispatch
+def topn_src_counts(mesh: Mesh, expr, form: tuple,
+                    leaf_arrays: list[jax.Array]) -> np.ndarray:
+    """int32 ``[n_slices, R]``: the source ``expr`` over the leaf slabs
+    intersected with every row of a resident frame form
+    (``residency.frame_positions``: offsets and positions) in every
+    slice, in ONE program (programs.topn_src_counts_program) — what
+    both phases of a with-source TopN read."""
+    _dispatch_gate()
+    from . import programs as programs_mod
+    fn = programs_mod.topn_src_counts_program(mesh, expr,
+                                              len(leaf_arrays))
+    _note_dispatch(mesh, *form, *leaf_arrays)
+    return _run(fn, *form, *leaf_arrays)
+
+
 def shard_slices_axis1(mesh: Mesh, arr: np.ndarray) -> jax.Array:
     """Place ``[L, n_slices, ...]`` on the mesh, sharded over axis 1."""
     spec = [None] * arr.ndim

@@ -66,6 +66,7 @@ CATALOGUE = (
     "topn_exact",          # TopN exact-count block, psum'd in-program
     "topn_filtered",       # per-slice threshold/Tanimoto pruning form
     "topn_topk",           # sourceless TopN: top-k selected IN-PROGRAM
+    "topn_src_counts",     # with-source TopN: every row's count a slice
     "materialize",         # dense expression words, sharded output
     "bsi_compare_select",  # BSI comparison circuit over bit-planes
     "fused_tree",          # Counts + TopN blocks in ONE computation
@@ -281,6 +282,40 @@ def topn_topk_program(mesh, expr, n_leaves: int, k: int):
     return mesh_mod._finalize_program(fn, f"topn_topk_k{n_leaves}_top{k}")
 
 
+@functools.lru_cache(maxsize=64)
+def topn_src_counts_program(mesh, expr, n_leaves: int):
+    """Every row's source-intersection count in every slice, in one
+    program over a frame's resident form
+    (``residency.frame_positions``): ``offsets`` int32 ``[n_slices, R +
+    1]`` and ``positions`` int32 ``[devices, P]``, both sharded over
+    the slice axis — on its own device, slice s, row r spans positions
+    ``[offsets[s, r], offsets[s, r + 1])``, each ``local slice << 20 |
+    column``. The source is ``expr`` over the ``n_leaves`` leaf slabs
+    ``[n_slices, W]``. On each device, each position reads its source
+    bit (one gather), the bits are prefix-summed, and the counts are
+    differences of the prefix sums at the offsets → int32
+    ``[n_slices, R]``, sharded as its slices are, with no collective:
+    the device twin of ``Fragment._host_src_count_map`` over every
+    slice at once."""
+    spec = P(mesh_mod.AXIS_SLICES)
+
+    def per_device(offsets, positions, *leaf_shards):
+        src = mesh_mod._eval_expr(expr, jnp.stack(leaf_shards)
+                                  ).reshape(-1)
+        pos = positions.reshape(-1)
+        words = src.at[pos >> 5].get(mode="promise_in_bounds")
+        hits = ((words >> (pos & 31).astype(jnp.uint32))
+                & 1).astype(jnp.int32)
+        prefix = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                  jnp.cumsum(hits)])
+        at = prefix.at[offsets].get(mode="promise_in_bounds")
+        return at[:, 1:] - at[:, :-1]
+
+    return mesh_mod._finalize_program(jax.shard_map(
+        per_device, mesh=mesh, in_specs=(spec,) * (2 + n_leaves),
+        out_specs=spec, check_vma=False), f"topn_src_counts_k{n_leaves}")
+
+
 @functools.lru_cache(maxsize=256)
 def materialize_program(mesh, expr, n_leaves: int):
     """Dense [S_b, W] words of the expression bitmap over resident leaf
@@ -368,6 +403,7 @@ def fused_program(mesh, count_exprs: tuple, topn_exprs: tuple,
 # aggregates hits/misses over the catalogue too.
 PROGRAM_CACHES = (
     count_exprs_program, count_exprs_block_program, topn_program,
-    topn_block_program, topn_topk_program, materialize_program,
+    topn_block_program, topn_topk_program, topn_src_counts_program,
+    materialize_program,
     bsi_range_program, fused_program,
 )

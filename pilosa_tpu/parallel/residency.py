@@ -95,6 +95,12 @@ class _Fill:
         self.error: Optional[BaseException] = None
 
 
+def _arrays(entry) -> tuple:
+    """The device arrays of a cached entry: a slab or block is one, a
+    frame form (``frame_positions``) is a pair."""
+    return entry if isinstance(entry, tuple) else (entry,)
+
+
 class DeviceBlockCache:
     """Budgeted process-wide LRU of device-resident arrays.
 
@@ -141,10 +147,15 @@ class DeviceBlockCache:
         self.retired_all = 0
         self.refills = 0
         self._refill_due: OrderedDict[tuple[int, int], None] = OrderedDict()
+        # Whole-frame forms built (``frame_positions``), and dropped by
+        # a write to their view.
+        self.frame_builds = 0
+        self.frames_retired = 0
 
     @staticmethod
     def _nbytes(arr) -> int:
-        return int(np.prod(arr.shape)) * arr.dtype.itemsize
+        return sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                   for a in _arrays(arr))
 
     def get_or_build(self, key: tuple, build: Callable[[], jax.Array],
                      owner: Optional[tuple] = None) -> jax.Array:
@@ -167,7 +178,9 @@ class DeviceBlockCache:
         token, checked under this cache's lock — a build whose key was
         taken before a write to its row hands its array to its waiters
         and keeps nothing — and ``retire_rows`` / ``retire_view`` drop
-        it."""
+        it. ``row_id`` None marks the view's whole-frame form
+        (``frame_positions``), whose token is ``view.frame_token()``
+        and which a write to any row drops."""
         with self._mu:
             arr = self._lru.get(key)
             if arr is not None:
@@ -205,7 +218,8 @@ class DeviceBlockCache:
                         self._insert(key, mine.arr, evicted)
                     else:
                         view, row_id, token = owner
-                        if view.row_token(row_id) == token:
+                        if token == (view.frame_token() if row_id is None
+                                     else view.row_token(row_id)):
                             self._insert(key, mine.arr, evicted,
                                          (view.uid, row_id))
             mine.done.set()
@@ -272,6 +286,10 @@ class DeviceBlockCache:
         with self._mu:
             rows = self._owned.get(uid)
             if rows:
+                frames = rows.pop(None, None)
+                if frames:
+                    self.frames_retired += len(frames)
+                    self._drop(frames, dropped)
                 due = self._refill_due
                 for r in row_ids:
                     keys = rows.pop(r, None)
@@ -306,6 +324,18 @@ class DeviceBlockCache:
             self.retired_all += n
         del dropped
         return n
+
+    def frames(self) -> tuple[int, int]:
+        """(whole-frame forms resident, their bytes)."""
+        with self._mu:
+            keys = [k for k, (_, row) in self._owner_of.items()
+                    if row is None]
+            return len(keys), sum(self._nbytes(self._lru[k])
+                                  for k in keys)
+
+    def note_frame_build(self) -> None:
+        with self._mu:
+            self.frame_builds += 1
 
     def note_pack(self, dense: bool, on_native: bool) -> None:
         """The filling thread's word on its pack: whether it ships a
@@ -361,8 +391,9 @@ class DeviceBlockCache:
             # axis give each device usedBytes / n; a slab that landed
             # whole on one device, or replicated on all, shows here.
             per_device: dict[str, int] = {}
-            for arr in self._lru.values():
-                for shard in arr.addressable_shards:
+            for entry in self._lru.values():
+                for shard in (s for arr in _arrays(entry)
+                              for s in arr.addressable_shards):
                     dev = str(shard.device.id)
                     per_device[dev] = (per_device.get(dev, 0)
                                        + shard.data.nbytes)
@@ -511,6 +542,97 @@ def candidate_block(mesh, key: tuple, frags,
         return _fill(mesh, (n, len(row_ids)), collect, rows=len(row_ids))
 
     return device_cache().get_or_build(key, build)
+
+
+def _frame_layout(mesh, n_slices: int,
+                  n_rows: int) -> tuple[int, int, int]:
+    """(padded slices, devices on the slice axis, R) of a frame form."""
+    from . import mesh as mesh_mod
+    r = 1 << max(0, int(n_rows) - 1).bit_length()
+    return (_bucketed_slices(mesh, n_slices),
+            mesh.shape[mesh_mod.AXIS_SLICES], r)
+
+
+def _positions_bucket(n: int) -> int:
+    """``n`` positions rounded up to 64 steps an octave: at most ~3 %
+    padding for the program to read."""
+    n = max(1, n)
+    step = max(1, (1 << (n - 1).bit_length()) // 64)
+    return -(-n // step) * step
+
+
+def frame_form_bytes(mesh, slice_bits: list[int], n_rows: int) -> int:
+    """What ``frame_positions`` holds for a frame of ``n_rows`` rows
+    whose slices hold ``slice_bits`` set bits each."""
+    n, d, r = _frame_layout(mesh, len(slice_bits), n_rows)
+    per = n // d
+    most = max(sum(slice_bits[i:i + per])
+               for i in range(0, n, per))
+    return (n * (r + 1) + d * _positions_bucket(most)) * 4
+
+
+def frame_positions(mesh, key: tuple, frags, n_rows: int,
+                    owner: Optional[tuple] = None
+                    ) -> tuple[jax.Array, jax.Array]:
+    """A ranked frame's standard view held on the device in a form
+    sized to its bits, under ``key`` in the budgeted HBM cache: two
+    int32 arrays, both sharded over the mesh's slice axis as the leaf
+    slabs are. ``offsets`` ``[n_slices, R + 1]`` (``n_slices``
+    bucket-padded, R = ``n_rows`` rounded up to a power of two) and
+    ``positions`` ``[devices, P]``: slice s, row r holds the positions
+    ``positions[d, offsets[s, r]:offsets[s, r + 1]]`` of the device d
+    that holds slice s, each ``local slice << 20 | column``, sorted by
+    (slice, row, column), zero past the device's last offset — 4 bytes
+    a set bit where a dense slab is 128 KiB a row a slice, and each
+    device holds and reads only its own slices. P, the most positions
+    a device holds, is rounded up to 64 steps an octave, so a frame
+    that grows by a write keeps its compiled program
+    (``programs.topn_src_counts_program``). ``frags`` is one fragment a
+    slice (None = absent) or a callable that resolves them;
+    ``owner`` = ``(view, None, view.frame_token())`` lets a write to any
+    row of the view drop the form (``DeviceBlockCache.retire_rows``).
+    Built once per token: positions read under each fragment's lock
+    (``Fragment.positions``), the layout in numpy, one transfer."""
+    from . import mesh as mesh_mod
+
+    def build(frags=frags):
+        if callable(frags):
+            frags = frags()
+        n, d, r = _frame_layout(mesh, len(frags), n_rows)
+        per = n // d
+        with sched_context.stage("pack", frame=len(frags),
+                                 slices=n) as pack:
+            offsets = np.empty((n, r + 1), dtype=np.int32)
+            parts = [[] for _ in range(d)]
+            held = [0] * d
+            for si in range(n):
+                dev, local = divmod(si, per)
+                frag = frags[si] if si < len(frags) else None
+                pos = (frag.positions() if frag is not None
+                       else np.empty(0, dtype=np.uint64))
+                rows = pos >> np.uint64(20)
+                offsets[si] = held[dev] + np.searchsorted(
+                    rows, np.arange(r + 1, dtype=np.uint64))
+                parts[dev].append((np.uint64(local << 20)
+                                   | (pos & np.uint64((1 << 20) - 1))
+                                   ).astype(np.int32))
+                held[dev] += len(pos)
+            positions = np.zeros((d, _positions_bucket(max(held))),
+                                 dtype=np.int32)
+            for dev in range(d):
+                if held[dev]:
+                    positions[dev, :held[dev]] = np.concatenate(
+                        parts[dev])
+            nbytes = offsets.nbytes + positions.nbytes
+            pack.tag(positions=sum(held), bytes=nbytes)
+        with sched_context.stage("upload", frame=len(frags),
+                                 bytes=nbytes):
+            form = (mesh_mod.shard_slices(mesh, offsets),
+                    mesh_mod.shard_slices(mesh, positions))
+        device_cache().note_frame_build()
+        return form
+
+    return device_cache().get_or_build(key, build, owner)
 
 
 def next_uid() -> int:

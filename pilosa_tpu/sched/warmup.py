@@ -6,8 +6,8 @@ program. At server start this lane compiles
 the **unified program catalogue** (parallel.programs.CATALOGUE — the
 count fold, the batched multi-Count form, TopN exact + filtered, the
 materializing fold, the BSI comparison circuit, and the fused
-multi-op-tree program) against dummy all-zero slabs on a daemon
-thread.
+multi-op-tree program, the with-source TopN's count program) against
+dummy all-zero slabs on a daemon thread.
 
 Shapes are keyed by the holder's ACTUAL max-slice bucket at fragment
 load (parallel.programs.slice_bucket over the open indexes), not a
@@ -217,6 +217,15 @@ class Warmup:
                     mesh, ("leaf", 0), rows_block(), [a], threshold=2),
                 "topn_topk": lambda: mesh_mod.topn_topk_sharded(
                     mesh, None, rows_block(), [], k=2),
+                # A frame form of 1,024 rows and eight positions a
+                # device: a real frame's shape compiles on its first read.
+                "topn_src_counts": lambda: mesh_mod.topn_src_counts(
+                    mesh, ("leaf", 0), (
+                        mesh_mod.shard_slices(
+                            mesh, np.zeros((S, 1025), np.int32)),
+                        mesh_mod.shard_slices(mesh, np.zeros(
+                            (mesh.shape[mesh_mod.AXIS_SLICES], 8),
+                            np.int32))), [a]),
                 "materialize": lambda: mesh_mod.materialize_expr_sharded(
                     mesh, ("or", ("leaf", 0), ("leaf", 1)), [a, b]),
                 "bsi_compare_select": lambda: mesh_mod.bsi_range_sharded(

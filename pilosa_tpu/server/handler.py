@@ -603,6 +603,17 @@ class Handler:
             # local leg on the calling thread, or the pool
             # (docs/OBSERVABILITY.md).
             snap["legs"] = dict(legs)
+        topn = getattr(self.executor, "topn_src", None)
+        if topn is not None:
+            # With-source TopN reads by where their counts came from,
+            # and the whole-frame device forms they read
+            # (docs/OBSERVABILITY.md).
+            cache = residency.device_cache()
+            frames, frame_bytes = cache.frames()
+            snap["topn"] = dict(topn, residentFrames=frames,
+                                residentFrameBytes=frame_bytes,
+                                builds=cache.frame_builds,
+                                retired=cache.frames_retired)
         planner = getattr(self.executor, "planner", None)
         if planner is not None:
             # Planned reads by how their statement shape was found:

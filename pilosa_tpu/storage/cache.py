@@ -79,7 +79,9 @@ class RankCache:
     Semantics follow reference cache.go:126-275: adds below thresholdValue
     are ignored; rankings are recomputed at most every 10 s (except via
     recalculate()); when the entry map outgrows maxEntries*1.1 it is trimmed
-    to entries above the threshold.
+    to the rows just ranked: those above the threshold and those tied at
+    it that the ranking holds. cache.go keeps those above it only, so its
+    next recalculation ranks fewer rows than the one that trimmed.
     """
 
     def __init__(self, max_entries: int = DEFAULT_CACHE_SIZE):
@@ -133,8 +135,13 @@ class RankCache:
         self.rankings = None  # Pair list built lazily by top()
         self._update_time = time.monotonic()
         if len(self.entries) > self.threshold_buffer:
-            self.entries = {i: c for i, c in self.entries.items()
-                            if c > self.threshold_value}
+            # The ranked rows tied at the threshold stay (cache.go drops
+            # them, and its next recalculation over the same counts — at
+            # most 10 s on, from any read — ranks fewer rows than this
+            # one); the unranked ties go. So every recalculation of
+            # unchanged counts gives this ranking again, and the map
+            # keeps at most maxEntries rows however many tie.
+            self.entries = dict(zip(ids.tolist(), counts.tolist()))
 
     def top_arrays(self) -> tuple[np.ndarray, np.ndarray]:
         """(ids, counts) in rank order, no per-entry objects."""

@@ -1490,6 +1490,17 @@ class Fragment:
             return self.storage.offset_range(0, row_id * SLICE_WIDTH,
                                              (row_id + 1) * SLICE_WIDTH)
 
+    def positions(self) -> np.ndarray:
+        """Every set position (row * SLICE_WIDTH + column) as one sorted
+        u64 vector, read under the lock with ``top``'s read checks: what
+        a whole-frame device form packs
+        (parallel.residency.frame_positions)."""
+        with self._mu:
+            self._verify_on_read()
+            if self.tier is not None:
+                self._tier_gate(full=True)
+            return self.storage.all_positions()
+
     def _cached_total_bits(self) -> int:
         """storage.count() walks every container in Python (~115 ms
         across 256 c5 fragments); cache per mutation epoch."""
@@ -1944,9 +1955,46 @@ class Fragment:
             out.reverse()
             return out
 
+    def top_src(self, src_counts: np.ndarray, n: int,
+                min_threshold: int) -> tuple[np.ndarray, np.ndarray]:
+        """``top``'s plain with-source walk (n > 0, no attribute
+        filter, no Tanimoto) fed ``src_counts`` — the source's
+        intersection count with every row of this slice, by row id, as
+        the device computed them (``Executor._topn_src_device``) — in
+        place of ``_host_src_count_map``: the rank cache's candidates,
+        the zero-overlap ones dropped as ``top`` drops them, then the
+        same replay (``_top_src_arrays``). Returns (ids, counts) in
+        (count desc, id asc) order."""
+        with self._mu:
+            self._verify_on_read()
+            if self.tier is not None:
+                self._tier_gate(full=True)
+            self.cache.invalidate()
+            cand_ids, cand_counts = self.cache.top_arrays()
+        cand_ids = cand_ids.astype(np.int64)
+        scnt = np.zeros(len(cand_ids), dtype=np.int64)
+        inside = cand_ids < len(src_counts)
+        scnt[inside] = src_counts[cand_ids[inside]]
+        keep = scnt > 0
+        if not keep.any():
+            return self._EMPTY_COUNTS
+        return self._top_src_arrays(cand_ids[keep],
+                                    np.asarray(cand_counts)[keep],
+                                    scnt[keep], n, min_threshold)
+
     @staticmethod
     def _top_src_vectorized(cand_ids, cand_counts, scnt, n: int,
                             min_threshold: int) -> list[Pair]:
+        """``_top_src_arrays`` as Pairs."""
+        ids, cnts = Fragment._top_src_arrays(cand_ids, cand_counts, scnt,
+                                             n, min_threshold)
+        return [Pair(int(i), int(c))
+                for i, c in zip(ids.tolist(), cnts.tolist())]
+
+    @staticmethod
+    def _top_src_arrays(cand_ids, cand_counts, scnt, n: int,
+                        min_threshold: int
+                        ) -> tuple[np.ndarray, np.ndarray]:
         """Vectorized replay of the heap walk for the plain-src shape
         (gathered src counts in hand, no tanimoto, no attr filter,
         n>0). Exactly reproduces the loop's visit-order semantics,
@@ -1981,9 +2029,7 @@ class Fragment:
         ids = cand_ids[take]
         cnts = scnt[take]
         order = np.lexsort((ids, -cnts))
-        return [Pair(int(i), int(c))
-                for i, c in zip(ids[order].tolist(),
-                                cnts[order].tolist())]
+        return ids[order], cnts[order]
 
     def recalculate_cache(self) -> None:
         """Rebuild the rank cache regardless of the invalidate rate limit

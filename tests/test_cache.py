@@ -26,6 +26,39 @@ class TestRankCache:
         c.add(99, 1)
         assert len(c) == before
 
+    def test_trim_keeps_the_ranked_ties_and_no_more(self):
+        """Rows tied at the threshold: the trim keeps those the ranking
+        holds and drops the rest, so the map stays within maxEntries
+        and a second ranking of the same counts is the first."""
+        c = RankCache(max_entries=50)
+        for i in range(1000):
+            c.bulk_add(i, 7 if i % 10 else 9)    # 100 nines, 900 sevens
+        c.recalculate()
+        first = [(p.id, p.count) for p in c.top()]
+        assert first == ([(i, 9) for i in range(0, 500, 10)])
+        assert len(c) == 50
+        c = RankCache(max_entries=150)
+        for i in range(1000):
+            c.bulk_add(i, 7 if i % 10 else 9)
+        c.recalculate()
+        first = [(p.id, p.count) for p in c.top()]
+        assert first[:100] == [(i, 9) for i in range(0, 1000, 10)]
+        assert first[100:] == [(i, 7) for i in range(1, 56) if i % 10]
+        assert len(c) == 150
+        c.recalculate()
+        assert [(p.id, p.count) for p in c.top()] == first
+        assert len(c) == 150
+
+    def test_trim_bounds_an_all_tied_map(self):
+        c = RankCache(max_entries=100)
+        for i in range(10_000):
+            c.bulk_add(i, 4)
+        c.recalculate()
+        assert len(c) == 100
+        assert [p.id for p in c.top()] == list(range(100))
+        c.recalculate()
+        assert [p.id for p in c.top()] == list(range(100))
+
     def test_ids_sorted(self):
         c = RankCache()
         for i in (5, 1, 9):

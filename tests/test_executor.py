@@ -739,21 +739,34 @@ class TestDeviceTopNPath:
         assert fast.device_fallbacks == 0
 
     def test_exact_phase_engages(self, holder, monkeypatch):
+        """The exact phase (explicit ids) runs the mesh's exact-count
+        program; the plain with-source form takes both its phases from
+        ONE count program over the frame's resident form."""
         self._fill(holder)
         ex = Executor(holder, host="local", use_mesh=True,
                       mesh_min_slices=1)
-        calls = []
+        calls, src_calls = [], []
         from pilosa_tpu.parallel import mesh as mesh_mod
         orig = mesh_mod.topn_exact_sharded
+        orig_src = mesh_mod.topn_src_counts
 
         def spy(mesh, expr, rows, leaves):
             calls.append((expr, rows.shape))
             return orig(mesh, expr, rows, leaves)
 
+        def spy_src(mesh, expr, frame, leaves):
+            src_calls.append(expr)
+            return orig_src(mesh, expr, frame, leaves)
+
         monkeypatch.setattr(mesh_mod, "topn_exact_sharded", spy)
-        res = ex.execute("i", 'TopN(Bitmap(rowID=0, frame=f), frame=f, n=3)')
+        monkeypatch.setattr(mesh_mod, "topn_src_counts", spy_src)
+        res = ex.execute("i", 'TopN(Bitmap(rowID=0, frame=f), frame=f,'
+                              ' ids=[0, 1, 2, 3])')
         assert calls, "TopN exact phase did not use the mesh path"
         assert calls[-1][0] == ("leaf", 0)
+        assert not src_calls
+        res = ex.execute("i", 'TopN(Bitmap(rowID=0, frame=f), frame=f, n=3)')
+        assert src_calls == [("leaf", 0)] and len(calls) == 1
         assert len(res[0]) == 3
 
     def test_filters_fall_back(self, holder, monkeypatch):

@@ -33,9 +33,10 @@ QUERIES = {
     "Count": ("Count(Intersect(Bitmap(frame=f, rowID=0),"
               " Bitmap(frame=f, rowID=1), Bitmap(frame=f, rowID=2)))"),
     "Bitmap": "Union(Bitmap(frame=f, rowID=1), Bitmap(frame=f, rowID=3))",
-    # with a source row: the sourceless form has a single pass of its
-    # own on one node and never fans out
-    "TopN": "TopN(Bitmap(frame=f, rowID=0), frame=f, n=3)",
+    # with a source row and a threshold: the sourceless form has a
+    # single pass of its own on one node and the plain with-source form
+    # one device program (Executor._topn_src_device); neither fans out
+    "TopN": "TopN(Bitmap(frame=f, rowID=0), frame=f, n=3, threshold=2)",
     "Sum": "Sum(frame=f, field=v)",
 }
 Q = QUERIES["Count"]
